@@ -48,7 +48,7 @@ def main():
               f"in {tdir}")
     else:
         # trace() degrades to a logged no-op on backends without profiler
-        # support (e.g. some tunneled TPU runtimes) — the fit still ran
+        # support — the fit still ran
         print("trace unavailable on this backend; fit ran untraced")
 
     # 2. custom region annotations around scoring work

@@ -262,7 +262,7 @@ class _LightGBMParams(HasLabelCol, HasFeaturesCol, HasWeightCol, HasInitScoreCol
         "fits only; sharded fits keep full-width passes regardless. "
         "True | False | 'auto' (default): auto engages it on non-TPU "
         "backends, where halving histogram rows is a measured win, and "
-        "keeps full-width MXU passes on TPU (docs/tpu_capture_r05)",
+        "keeps full-width MXU passes on TPU",
         "auto", _to_tristate_bool)
     compactSelector = Param(
         "compactSelector", "Row-compaction selector for histSubtraction: "

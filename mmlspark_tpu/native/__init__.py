@@ -39,8 +39,13 @@ _SOURCE = next((p for p in _SOURCE_CANDIDATES if os.path.exists(p)),
                _SOURCE_CANDIDATES[0])
 # wheels built on a host with a toolchain ship the compiled library too
 _PREBUILT = os.path.join(_PKG_DIR, "mmlspark_native_prebuilt.so")
+# a checkout (the repo-layout source exists) always builds from the source
+# git tracks: an untracked prebuilt left in the package dir by some earlier
+# build is not what a clean copy of the same commit would run
+_IN_CHECKOUT = os.path.exists(_SOURCE_CANDIDATES[0])
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
+_lib_path: Optional[str] = None
 _lib_tried = False
 
 
@@ -131,12 +136,14 @@ def _prebuilt_current(lib: ctypes.CDLL) -> bool:
 
 
 def _load() -> Optional[ctypes.CDLL]:
+    global _lib_path
     if os.environ.get("MMLSPARK_TPU_DISABLE_NATIVE"):
         return None
-    if os.path.exists(_PREBUILT):
+    if not _IN_CHECKOUT and os.path.exists(_PREBUILT):
         try:
             lib = ctypes.CDLL(_PREBUILT)
             if _prebuilt_current(lib):
+                _lib_path = _PREBUILT
                 return lib
             # stale prebuilt (old symbols or old behavior): recompile
         except OSError:
@@ -145,9 +152,16 @@ def _load() -> Optional[ctypes.CDLL]:
     if so is None:
         return None
     try:
-        return ctypes.CDLL(so)
+        lib = ctypes.CDLL(so)
     except OSError:
         return None
+    _lib_path = so
+    return lib
+
+
+def lib_path() -> Optional[str]:
+    """Path of the loaded native library (None: Python fallbacks in use)."""
+    return _lib_path if get_lib() is not None else None
 
 
 def _bind(lib: ctypes.CDLL) -> None:

@@ -205,11 +205,6 @@ REGISTRY: Tuple[EnvVar, ...] = (
            doc="cap the default mesh to the first N devices (scaling A/B "
                "legs, placement debugging); explicit `make_mesh` "
                "shape/devices arguments are honored as given"),
-    EnvVar(name="MMLSPARK_TPU_COMPILE_CACHE_DIR", default="(off)",
-           section="performance",
-           doc="wires jax's persistent compilation cache to this "
-               "directory (read once per process, first call wins; "
-               "compile flight events carry the active value)"),
     EnvVar(name="MMLSPARK_TPU_DISABLE_FUSED_VALID", default="(off)",
            section="performance",
            doc="set to force the host round loop instead of the fused "

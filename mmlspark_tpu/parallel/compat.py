@@ -1,73 +1,13 @@
-"""jax version-compat funnel for ``shard_map``.
+"""The one import site for ``shard_map`` and ``axis_size``.
 
-The codebase targets the modern spelling ``jax.shard_map(f, mesh=...,
-in_specs=..., out_specs=..., check_vma=False)``. Older jax releases (e.g.
-0.4.x, the version baked into some runtime images) only ship
-``jax.experimental.shard_map.shard_map`` and call the replication-check
-kwarg ``check_rep``. This module is THE one place that difference is
-resolved: every shard_map call site in the framework routes through
-:func:`shard_map` below (lint-enforced — ``tests/test_lint.py`` rejects
-bare ``jax.shard_map(`` anywhere else), so a jax upgrade or downgrade is a
-one-file concern.
-
-Resolution order:
-  1. ``jax.shard_map`` (jax >= 0.6 spelling) when present;
-  2. ``jax.experimental.shard_map.shard_map`` otherwise.
-The ``check_vma=`` kwarg is translated to whichever of ``check_vma`` /
-``check_rep`` the resolved implementation accepts (dropped when neither
-exists).
+Every shard_map call site in the framework routes through the two names
+below (lint-enforced — graftlint rejects bare ``jax.shard_map(`` anywhere
+else), so the day jax moves either symbol again is a one-file change. The
+installed jax (pinned in pyproject.toml) ships both under their modern
+names; nothing here adapts to older releases.
 """
 
 from __future__ import annotations
 
-import inspect
-
-import jax
-
-_IMPL = None
-_PARAMS: "frozenset[str] | None" = None
-
-
-def _resolve():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        params = frozenset(inspect.signature(fn).parameters)
-    except (TypeError, ValueError):  # C-accelerated / exotic wrappers
-        params = frozenset({"check_vma"})
-    return fn, params
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """Drop-in for ``jax.shard_map`` that works on every supported jax.
-
-    Accepts the modern kwarg spelling; ``check_vma`` is renamed to
-    ``check_rep`` for implementations that predate the VMA terminology.
-    Extra kwargs pass through untouched (they must exist in the resolved
-    implementation, same as calling it directly).
-    """
-    global _IMPL, _PARAMS
-    if _IMPL is None:
-        _IMPL, _PARAMS = _resolve()
-    if check_vma is not None:
-        if "check_vma" in _PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _PARAMS:
-            kwargs["check_rep"] = check_vma
-        # neither: the implementation has no replication check to relax
-    return _IMPL(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 **kwargs)
-
-
-def axis_size(axis_name) -> int:
-    """``lax.axis_size`` compat: static mesh-axis size inside shard_map.
-
-    jax versions without ``lax.axis_size`` constant-fold ``psum(1, axis)``
-    to the (static) shard count during tracing, so both branches return a
-    Python int usable in host control flow (loop trip counts etc.)."""
-    from jax import lax
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+from jax import shard_map  # noqa: F401 — re-exported funnel
+from jax.lax import axis_size  # noqa: F401 — re-exported funnel

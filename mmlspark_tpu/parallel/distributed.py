@@ -14,28 +14,17 @@ import os
 from typing import Optional
 
 import jax
-
-# Fallback double-init guard owned by this module, used only when the private
-# JAX coordination state below is unreadable (e.g. after a JAX upgrade moves
-# jax._src.distributed). The private path was verified against jax 0.4/0.5/0.6.
-_initialized_here = False
+from jax._src import distributed as _jdist
 
 
 def _coordination_client():
-    """Best-effort read of JAX's private distributed coordination client.
-
-    Returns ``(readable, client)``. ``readable=False`` means the private API
-    (``jax._src.distributed.global_state.client``) is gone or renamed; callers
-    must then fall back to ``_initialized_here``. We read internal state at all
-    because the public alternatives (``jax.process_count()``) initialize the
-    XLA backend, and ``jax.distributed.initialize`` must run before any
-    backend touch — see the ordering notes at the call sites.
-    """
-    try:
-        from jax._src import distributed as _jdist
-        return True, getattr(_jdist.global_state, "client", None)
-    except Exception:
-        return False, None
+    """JAX's distributed coordination client, or None before
+    ``jax.distributed.initialize``. Read from private state
+    (``jax._src.distributed.global_state``, where the installed jax keeps
+    it) because the public alternatives (``jax.process_count()``)
+    initialize the XLA backend, and ``jax.distributed.initialize`` must run
+    before any backend touch — see the ordering notes at the call sites."""
+    return _jdist.global_state.client
 
 
 def initialize(coordinator_address: Optional[str] = None,
@@ -51,25 +40,19 @@ def initialize(coordinator_address: Optional[str] = None,
     # Guard against double-init WITHOUT touching the XLA backend:
     # jax.process_count() would initialize it, and jax.distributed must run
     # first (this exact ordering bug is why the guard reads internal state).
-    global _initialized_here
-    readable, client = _coordination_client()
-    if readable:
-        if client is not None:
-            # already initialized (possibly directly or by another
-            # framework): still stamp the process index, or multi-host
-            # trace events fall back to os.getpid(), which can collide
-            # across hosts and interleave merged dumps into one pid track
-            _tag_spans_with_process_index()
-            return
-    elif _initialized_here:
-        return  # private state unreadable; trust our own flag
+    if _coordination_client() is not None:
+        # already initialized (possibly directly or by another
+        # framework): still stamp the process index, or multi-host
+        # trace events fall back to os.getpid(), which can collide
+        # across hosts and interleave merged dumps into one pid track
+        _tag_spans_with_process_index()
+        return
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
             process_id=process_id,
         )
-        _initialized_here = True
     except Exception:
         if coordinator_address is not None or num_processes is not None or \
                 "JAX_COORDINATOR_ADDRESS" in os.environ:
@@ -131,16 +114,7 @@ def barrier(name: str = "barrier") -> None:
     # initialize the XLA backend: a pre-init backend touch here would both
     # no-op the barrier and poison a later initialize() (same ordering
     # hazard as in initialize() above).
-    readable, client = _coordination_client()
-    if not readable:
-        # Raise BEFORE any jax.* call: jax.process_count() would initialize
-        # the XLA backend, silently no-op this barrier, and poison a later
-        # initialize(). The old import raised loudly here too.
-        raise RuntimeError(
-            "jax._src.distributed moved in this JAX version; the host "
-            "barrier cannot reach the coordination service. Pin a JAX "
-            "version with jax._src.distributed.global_state.client or "
-            "update mmlspark_tpu.parallel.distributed.")
+    client = _coordination_client()
     if client is None:
         if jax.process_count() == 1:
             return                      # single process: barrier is a no-op

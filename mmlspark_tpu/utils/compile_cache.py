@@ -4,19 +4,24 @@ Training a booster compiles multi-second XLA programs (the fused
 multi-iteration scan, the per-round step, the device predictor). Within a
 process those are amortized by the in-memory program caches
 (``_STEP_CACHE`` / ``_PREDICT_CACHE``), but every NEW process — a serving
-worker fleet, repeat CLI fits, a bench warmup — pays the cold compile
-again. jax's persistent compilation cache keys compiled executables on
-(HLO, compile options, backend version) and stores them on disk, so
-identical programs skip XLA entirely across processes.
+worker fleet, repeat CLI fits, a chip run — pays the cold compile again.
+jax's persistent compilation cache keys compiled executables on (HLO,
+compile options, backend version) and stores them on disk, so identical
+programs skip XLA entirely across processes.
 
-``MMLSPARK_TPU_COMPILE_CACHE_DIR=<dir>`` opts in; :func:`ensure` is the
-ONLY place the knob is read (booster fit/predict paths and ``bench.py``
-all call it). Safe no-op when the env var is unset or the running jax
-lacks the config flags. Cache *hits* are surfaced as the
-``persistent_compile_cache_hits_total`` counter (fed by jax's own
-monitoring events), and every compile/program_build flight event records
-the active ``persistent_cache`` dir — that is what the warm-start test
-asserts on.
+One rule, in :func:`ensure` (booster fit/predict paths, bundles, ``bench.py``
+and ``chip_smoke.py`` all call it): where ``JAX_COMPILATION_CACHE_DIR`` is
+set, jax already uses that directory and nothing here sets another; where
+it is not, a checkout keeps its cache at the fixed ``<checkout>/.jax_cache``
+— never a temp name, pid or timestamp, because the path is part of what
+makes two processes find each other's entries. An installed package
+(nothing of the checkout beside it — typically a read-only
+``site-packages``) gets no directory from here: place the cache with the
+variable. Cache *hits* and *misses* are surfaced as the
+``persistent_compile_cache_hits_total`` / ``..._misses_total`` counters (fed
+by jax's own monitoring events), and every compile/program_build flight
+event records the active ``persistent_cache`` dir — that is what the
+warm-start test asserts on.
 """
 
 from __future__ import annotations
@@ -25,94 +30,96 @@ import os
 import threading
 from typing import Optional
 
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+# <checkout>/.jax_cache (git-ignored), derived from the package location;
+# None for an installed package, whose parent directory is not a checkout
+DEFAULT_DIR: Optional[str] = (
+    os.path.join(_ROOT, ".jax_cache")
+    if os.path.isfile(os.path.join(_ROOT, "pyproject.toml")) else None)
+
 _LOCK = threading.Lock()
 _INITIALIZED = False
 _DIR: Optional[str] = None
+_SOURCE: Optional[str] = None
 
 
 def cache_dir() -> Optional[str]:
-    """The active persistent-cache directory, or None (after :func:`ensure`
-    has run; before it, reflects only a previous successful init)."""
+    """The active persistent-cache directory (None before :func:`ensure`,
+    and after it when no directory applies)."""
     return _DIR
 
 
+def cache_source() -> Optional[str]:
+    """Where the active directory came from: ``env:JAX_COMPILATION_CACHE_DIR``,
+    ``default`` (the in-checkout path), ``fallback`` (a caller-supplied
+    directory, e.g. a serving bundle's ``xla_cache/``) or ``unset`` (an
+    installed package without the variable: no persistent cache)."""
+    return _SOURCE
+
+
 def ensure(fallback_dir: Optional[str] = None) -> Optional[str]:
-    """Idempotently wire jax's persistent compilation cache.
+    """Idempotently wire jax's persistent compilation cache; returns the
+    active directory. First call wins — jax reads the flag at compile time,
+    so flipping it mid-process would split programs across caches.
 
-    Reads ``MMLSPARK_TPU_COMPILE_CACHE_DIR`` once per process (first call
-    wins — jax reads the flag at compile time, so flipping it mid-process
-    would silently apply to some programs and not others). Returns the
-    active cache dir, or None when disabled/unsupported.
-
-    ``fallback_dir`` engages only when the env knob is unset: the
-    serving-bundle paths (``mmlspark_tpu/bundles``) pass the bundle's own
-    ``xla_cache/`` so bundle build populates it and bundle prewarm reads
-    it, without overriding an operator's explicit cache choice. The
-    first-call-wins rule is unchanged.
+    ``fallback_dir`` replaces the in-checkout default when (and only when)
+    ``JAX_COMPILATION_CACHE_DIR`` is unset: the serving-bundle paths
+    (``mmlspark_tpu/bundles``) pass the bundle's own ``xla_cache/`` so
+    bundle build populates it and bundle prewarm reads it, without
+    overriding a cache placed from outside.
     """
-    global _INITIALIZED, _DIR
+    global _INITIALIZED, _DIR, _SOURCE
     with _LOCK:
         if _INITIALIZED:
             return _DIR
+        # jax stays a lazy import: `bundles inspect` reads manifests on
+        # boxes without an accelerator runtime
+        import jax
+        from jax import monitoring
+        from jax._src import compilation_cache as _jcc
         _INITIALIZED = True
-        d = (os.environ.get("MMLSPARK_TPU_COMPILE_CACHE_DIR") or "").strip()
-        if not d:
-            d = (fallback_dir or "").strip()
-        if not d:
-            return None
-        try:
-            import jax
-            jax.config.update("jax_compilation_cache_dir", d)
-        except Exception:  # noqa: BLE001 — jax without the cache: no-op
-            return None
-        # optional tuning flags are each individually best-effort: a jax
-        # that lacks one must not leave the cache half-configured (dir
-        # active but _DIR None would mis-stamp every compile event as
-        # uncached and never register the hit listener)
-        for flag, val in (
-                # cache every program: the default 1 s floor would skip
-                # most of the small per-shape programs that dominate
-                # cold-start count
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(flag, val)
-            except Exception:  # noqa: BLE001 — flag absent on this jax
-                pass
+        if (os.environ.get(_ENV) or "").strip():
+            # jax read the variable into its own config at import; report
+            # what jax will actually use and set no directory in code
+            _DIR = jax.config.jax_compilation_cache_dir
+            _SOURCE = f"env:{_ENV}"
+        elif (fallback_dir or "").strip():
+            _DIR, _SOURCE = fallback_dir.strip(), "fallback"
+        else:
+            _DIR, _SOURCE = DEFAULT_DIR, "default" if DEFAULT_DIR else "unset"
+        if _SOURCE in ("fallback", "default"):
+            jax.config.update("jax_compilation_cache_dir", _DIR)
+        # cache every program: the default 1 s floor would skip most of
+        # the small per-shape programs that dominate cold-start count
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         # jax memoizes "is the cache used?" at the FIRST compile of the
         # process (compilation_cache._cache_checked); anything that
         # compiled before this funnel ran — framework import side effects,
         # a warmup op — would have frozen the answer at False and every
         # later compile would silently skip the dir. Reset the memo so the
         # cache engages from here on.
-        try:
-            from jax._src import compilation_cache as _jcc
-            _jcc.reset_cache()
-        except Exception:  # noqa: BLE001 — internal API drift: the cache
-            pass           # still works when nothing compiled pre-ensure
-        _DIR = d
-        _register_hit_listener()
+        _jcc.reset_cache()
+        monitoring.register_event_listener(_on_event)
         return _DIR
 
 
-def _register_hit_listener() -> None:
-    """Feed jax's cache-hit monitoring event into the metrics registry:
-    ``persistent_compile_cache_hits_total`` is the deterministic signal
-    that a warm cache dir actually skipped recompilation (wall-time
-    comparisons are flaky on loaded CI boxes)."""
-    try:
-        from jax import monitoring
+_EVENT_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "persistent_compile_cache_hits_total",
+    "/jax/compilation_cache/cache_misses":
+        "persistent_compile_cache_misses_total",
+}
 
-        def _on_event(event: str, **kwargs) -> None:
-            if event != "/jax/compilation_cache/cache_hits":
-                return
-            try:
-                from ..observability import metrics as _metrics
-                _metrics.safe_counter(
-                    "persistent_compile_cache_hits_total").inc()
-            except Exception:  # noqa: BLE001 — telemetry must never raise
-                pass
 
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # noqa: BLE001 — monitoring API absent: hits simply
-        pass           # go uncounted; the cache itself still works
+def _on_event(event: str, **kwargs) -> None:
+    """Feed jax's cache hit/miss monitoring events into the metrics
+    registry: ``persistent_compile_cache_hits_total`` is the deterministic
+    signal that a warm cache dir actually skipped recompilation (wall-time
+    comparisons are flaky on loaded boxes), ``..._misses_total`` the count
+    of programs this process had to compile cold."""
+    counter = _EVENT_COUNTERS.get(event)
+    if counter:
+        from ..observability import metrics as _metrics
+        _metrics.safe_counter(counter).inc()

@@ -55,12 +55,60 @@ class TestResolver:
         _force_engine(monkeypatch, engine)
         assert resolve_engine() == engine
 
-    def test_disable_pallas_degrades(self, monkeypatch):
-        # the test/debug kill switch outranks an explicit pallas request:
-        # where the kernel cannot lower, degrade instead of failing Mosaic
+    def test_explicit_pallas_that_cannot_lower_raises(self, monkeypatch):
+        # a pinned engine never silently becomes another one: no TPU
+        # backend and no interpreter here, so the kernel cannot lower
         monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "pallas")
+        monkeypatch.delenv("MMLSPARK_TPU_PALLAS_INTERPRET", raising=False)
+        monkeypatch.delenv("MMLSPARK_TPU_DISABLE_PALLAS_HIST", raising=False)
+        with pytest.raises(RuntimeError, match="cannot lower"):
+            resolve_engine()
+        # ... and the kill switch contradicts the pin instead of
+        # outranking it
+        monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
         monkeypatch.setenv("MMLSPARK_TPU_DISABLE_PALLAS_HIST", "1")
-        assert resolve_engine() in ("onehot", "scatter")
+        with pytest.raises(RuntimeError, match="DISABLE_PALLAS_HIST"):
+            resolve_engine()
+
+    def test_disable_pallas_under_auto_picks_another_engine(self,
+                                                            monkeypatch):
+        monkeypatch.delenv("MMLSPARK_TPU_HIST_ENGINE", raising=False)
+        monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setenv("MMLSPARK_TPU_DISABLE_PALLAS_HIST", "1")
+        assert resolve_engine() == "scatter"
+
+    def test_interpret_mode_on_a_tpu_backend_is_an_error(self, monkeypatch):
+        # on the chip the interpreter would stand in for the Mosaic kernel
+        # unnoticed — the variable is refused there, at resolution and at
+        # the pallas_call
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("MMLSPARK_TPU_HIST_ENGINE", raising=False)
+        monkeypatch.delenv("MMLSPARK_TPU_PALLAS_INTERPRET", raising=False)
+        assert resolve_engine() == "pallas"
+        monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
+        with pytest.raises(RuntimeError, match="PALLAS_INTERPRET"):
+            resolve_engine()
+        with pytest.raises(RuntimeError, match="PALLAS_INTERPRET"):
+            H._interpret_mode()
+
+    def test_tpu_shape_gate_fall_is_loud(self, monkeypatch):
+        # a shape the kernel cannot tile falls to onehot — on a TPU with a
+        # flight event and a warning naming the shape, never silently
+        import jax
+
+        from mmlspark_tpu.observability import flight
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("MMLSPARK_TPU_HIST_ENGINE", raising=False)
+        monkeypatch.delenv("MMLSPARK_TPU_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(H, "_vmem_budget", lambda: 1 << 20)
+        flight.clear()
+        assert H._select_engine(4096, 28, 93, 255, fused_w=31) == "onehot"
+        ev = [e for e in flight.events() if e["kind"] == "hist_engine"]
+        assert len(ev) == 1 and ev[0]["event"] == "pallas_shape_gate"
+        assert (ev[0]["n"], ev[0]["F"], ev[0]["S"], ev[0]["B"],
+                ev[0]["W"]) == (4096, 28, 93, 255, 31)
+        assert ev[0]["vmem_budget_bytes"] == 1 << 20
 
     def test_bad_value_raises(self, monkeypatch):
         monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "mxu")
@@ -178,6 +226,64 @@ class TestCrossEngineEquivalence:
         got = np.asarray(node_histogram(binned_t, pos, base, W, B))
         np.testing.assert_array_equal(got[:, 2::3, :], want[:, 2::3, :])
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+class TestTotalsMatchHistogramRounding:
+    """Split search derives each right child as ``total - left`` with
+    ``left`` a histogram prefix sum, so node totals must sum the SAME
+    bf16-rounded stats the histograms sum. Totals of raw f32 stats hand the
+    whole dataset's rounding residue to one small leaf (found by
+    chip_smoke.py at 1M rows: leaf hessians near zero, leaf values in the
+    thousands, while 50k-row fits looked healthy)."""
+
+    @pytest.mark.parametrize("engine", ENGINES)
+    @pytest.mark.parametrize("blocks", [0, 4])
+    def test_stat_totals_equal_root_histogram_sums(self, engine, blocks,
+                                                   monkeypatch):
+        import jax
+
+        from mmlspark_tpu.models.gbdt.growth import _stat_totals
+        _force_engine(monkeypatch, engine)
+        n, F, B = 20_000, 3, 63
+        rng = np.random.default_rng(0)
+        binned_t = jnp.asarray(rng.integers(0, B, size=(F, n),
+                                            dtype=np.int32))
+        # hessians clustered at one value that bf16 rounds DOWN: the
+        # residue is systematic, ~4e-4 per row (the early-iteration
+        # binary-logloss regime)
+        base = jnp.asarray(np.stack([
+            rng.normal(size=n).astype(np.float32),
+            np.full(n, 0.2475, np.float32),
+            np.ones(n, np.float32)]))
+        tot = np.asarray(jax.jit(lambda b: _stat_totals(
+            b, None, None, blocks, n // blocks if blocks else 0))(base))
+        hist = np.asarray(node_histogram(
+            binned_t, jnp.zeros(n, jnp.int32), base, 1, B))     # [F, 3, B]
+        for f in range(F):
+            np.testing.assert_allclose(tot, hist[f].sum(axis=-1),
+                                       rtol=2e-6, atol=1e-3)
+        # the unrounded total is what the parent commit returned: off by
+        # the residue that used to reach a leaf
+        assert abs(float(np.asarray(base[1], np.float64).sum()) - tot[1]) > 5
+
+
+    def test_scatter_rounding_is_one_xla_cannot_elide(self, monkeypatch):
+        # the scatter engine accumulates in f32: an astype(bf16) ahead of it
+        # is an f32 -> bf16 -> f32 round trip, which XLA on TPU elides
+        # inside one program — the engine then sums UNROUNDED stats against
+        # rounded totals. Only the chip shows the elision (chip_smoke.py's
+        # kernel phase runs this path under jit there); here, pin the form.
+        import jax
+        _force_engine(monkeypatch, "scatter")
+        b = jnp.zeros((2, 64), jnp.int32)
+        base = jnp.ones((3, 64), jnp.float32)
+        for fn, args in (
+                (lambda b, s: node_histogram(b, jnp.zeros(64, jnp.int32), s,
+                                             1, 15), (b, base)),
+                (lambda b, s: histogram_cols(b, s, 15), (b, base))):
+            text = jax.jit(fn).lower(*args).as_text()
+            assert "reduce_precision" in text
+            assert "bf16" not in text
 
 
 class TestTrainLevelEquivalence:

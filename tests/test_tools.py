@@ -108,8 +108,8 @@ def test_serving_main_worker_and_gateway(tmp_path):
 class TestBenchRegression:
     """tools/bench_regression.py gates the newest BENCH_r*.json against
     the median of up to the 3 preceding rounds (>20% throughput drops) —
-    exercised on synthetic fixtures (the real rounds carry relay jitter
-    and must not gate the suite)."""
+    exercised on synthetic fixtures (real rounds are measurements and
+    must not gate the suite)."""
 
     def _write_round(self, d, n, line):
         # the driver wrapper shape: bench stdout lives in "tail", last
@@ -146,9 +146,9 @@ class TestBenchRegression:
     def test_value_gated_only_on_matching_metric(self, tmp_path):
         self._write_round(tmp_path, 1, {
             "metric": "gbdt_trees_per_sec_1M_rows_28f", "value": 30.0})
-        # a CPU-fallback round must not gate against a TPU round's value
+        # a toy CPU round must not gate against a TPU round's value
         self._write_round(tmp_path, 2, {
-            "metric": "gbdt_trees_per_sec_50k_rows_28f_CPU_FALLBACK",
+            "metric": "gbdt_trees_per_sec_50k_rows_28f",
             "value": 3.0})
         r = self._run(tmp_path)
         assert r.returncode == 0, r.stdout + r.stderr

@@ -6,10 +6,9 @@ directory given as the first argument) against the MEDIAN of up to the
 three rounds preceding it: each file is a driver wrapper object whose
 ``tail`` holds the bench run's stdout, where the LAST JSON line is the
 round's metrics (bench.py's last-line-wins convention; a bare JSON-line
-file is accepted too). A single-round baseline is one relay-jitter
-sample away from a false flag (r04->r05 flagged quantized_* secondaries
-~30% "down" on jitter alone); the median of a short window absorbs one
-outlier round in either direction. On an even window the LOWER middle
+file is accepted too). A single-round baseline is one noisy sample away
+from a false flag; the median of a short window absorbs one outlier round
+in either direction. On an even window the LOWER middle
 value is taken — ties break toward not flagging. Throughput keys shared
 by the baseline and the newest round — ``value`` (when every baseline
 round and the newest report the same ``metric`` name) and every

@@ -264,7 +264,7 @@ FUNNEL_RULES: Tuple[FunnelRule, ...] = (
         description="shard_map only via parallel/compat.py (the "
                     "version-skew funnel)",
         scope=("mmlspark_tpu", "tests", "tools", "__graft_entry__.py",
-               "bench.py", "graft_test_env.py"),
+               "bench.py", "chip_smoke.py"),
         allow=("mmlspark_tpu/parallel/compat.py",),
         match=_match_shard_map,
         remedy="import shard_map from mmlspark_tpu.parallel.compat",

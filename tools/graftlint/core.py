@@ -53,7 +53,7 @@ PACKAGE = "mmlspark_tpu"
 #: default scan set: the package, its tests/tools, and the root-level
 #: entrypoints (the shard_map funnel historically guarded all of these)
 DEFAULT_SCAN = ("mmlspark_tpu", "tests", "tools",
-                "__graft_entry__.py", "bench.py", "graft_test_env.py")
+                "__graft_entry__.py", "bench.py", "chip_smoke.py")
 
 
 @dataclass
