@@ -14,6 +14,8 @@ arrays per iteration and are stacked into the Booster.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import os
 import threading
@@ -33,7 +35,6 @@ from ...observability import metrics as _metrics
 from ...observability import roofline as _roofline
 from ...observability import spans as _spans
 from ...observability import watchdog as _watchdog
-from ...observability.logging import console as _console
 from ...robustness.failpoints import fault_point as _failpoint
 from ... import tuning as _tuning
 from ...utils import compile_cache as _compile_cache
@@ -88,24 +89,44 @@ def _cached_program(key, build):
     return prog
 
 
-class _PhaseTimer:
-    """Opt-in wall-time phase breakdown of a train_booster call
-    (MMLSPARK_TPU_TIMING=1) — the TPU analog of the reference's per-phase
-    TrainingStats diagnostics (vw/VowpalWabbitBase.scala:27-46)."""
+def _hit_or_built(key) -> str:
+    """Whether the step cache already holds ``key``'s program: the
+    ``program`` attribute of a ``gbdt_fit`` span."""
+    return "hit" if key in _STEP_CACHE else "built"
 
-    def __init__(self):
-        import os
-        self.on = bool(os.environ.get("MMLSPARK_TPU_TIMING"))
-        self._t = time.perf_counter() if self.on else 0.0
 
-    def mark(self, name: str) -> None:
-        if self.on:
-            now = time.perf_counter()
-            # console, not the JSON funnel: MMLSPARK_TPU_TIMING=1 is an
-            # explicit operator request that must print regardless of the
-            # telemetry kill switch
-            _console(f"[gbdt-timing] {name}: {now - self._t:.3f}s")
-            self._t = now
+class _Phases(contextlib.ExitStack):
+    """Back-to-back child spans of ``parent`` — the TPU analog of the
+    reference's per-phase TrainingStats diagnostics
+    (vw/VowpalWabbitBase.scala:27-46). ``enter(name)`` ends the phase that
+    is open and starts the next, so the phases tile their parent without a
+    ``with`` level each; leaving the stack ends the last one. Names are
+    fixed: what varies goes into the parent's attributes."""
+
+    def __init__(self, parent):
+        super().__init__()
+        self.parent = parent
+
+    def enter(self, name: str):
+        self.close()
+        return self.enter_context(_spans.span(name))  # graftlint: disable=resource-leak (the stack is its own with-context at every use and closes the span on all paths)
+
+    def program(self, path: str, key, build):
+        """The ``gbdt_fit_program`` phase: get-or-build the fit's program
+        and say on the parent which it was."""
+        self.enter("gbdt_fit_program")
+        self.parent.set(path=path, program=_hit_or_built(key))
+        return _cached_program(key, build)
+
+
+def _in_fit_span(train):
+    """Run ``train`` inside a ``gbdt_fit`` span, its phases handed in as
+    ``_phases`` (docs/observability.md, "Spans and device traces")."""
+    @functools.wraps(train)
+    def wrapped(*args, **kwargs):
+        with _spans.span("gbdt_fit") as fit, _Phases(fit) as phases:
+            return train(*args, _phases=phases, **kwargs)
+    return wrapped
 
 
 # --- single-buffer tree transfer -------------------------------------------
@@ -670,8 +691,7 @@ class LightGBMDataset:
                   row_valid: Optional[np.ndarray] = None,
                   bin_dtype=None, path=None, label_path=None,
                   weight_path=None, chunk_rows: Optional[int] = None,
-                  max_bin_by_feature=None,
-                  _timer: Optional[_PhaseTimer] = None) -> "LightGBMDataset":
+                  max_bin_by_feature=None) -> "LightGBMDataset":
         if path is None and (label_path is not None
                              or weight_path is not None
                              or chunk_rows is not None):
@@ -709,7 +729,6 @@ class LightGBMDataset:
             raise ValueError(
                 "construct needs in-memory arrays (X, y) or file shards "
                 "(path=..., label_path=...)")
-        tw = _timer or _PhaseTimer()
         mesh = mesh or meshlib.get_default_mesh()
         X = np.asarray(X, dtype=np.float32)
         y = np.asarray(y, dtype=np.float32)
@@ -722,53 +741,58 @@ class LightGBMDataset:
                 f"{F} features")
         bd = _validate_bin_dtype("int32" if bin_dtype is None else bin_dtype,
                                  max_bin)
-        binner = QuantileBinner(max_bin, bin_sample_count, seed,
-                                categorical_features,
-                                max_bin_by_feature).fit(X)
-        tw.mark("binner_fit")
-        # placement decision (observable): dataset rows are batch-dim
-        # sharded over the mesh's data axis when it has >1 shard; the
-        # note carries the binned matrix's storage dtype so the flight
-        # ring shows how wide the HBM-resident dataset landed
-        placement.plan_for("gbdt.ingest", mesh=mesh, rows=n, dtype=bd.name)
-        # Binning runs ON DEVICE, producing the column-major [F, n_local]
-        # layout tree growth consumes (the host searchsorted pass measured
-        # 1.6 s at the 1Mx28 bench shape vs ~ms of VPU compare-sums; raw and
-        # binned rows are the same byte count so the transfer is unchanged).
-        # Padding rows bin to garbage but carry vmask 0 downstream.
-        X_d, _ = placement.shard_rows(X, mesh)
-        if tw.on:
-            X_d.block_until_ready()
-            tw.mark("xfer_X")
-        bin_fn = _bin_program(X_d.shape, max_bin, mesh, bin_dtype=bd)
-        n_pad = X_d.shape[0]
-        Xbt_d = bin_fn(X_d, jnp.asarray(binner.upper_bounds))
-        # the raw copy served only to produce the binned matrix: free its
-        # HBM now or both dataset-sized buffers stay live for the whole run
-        Xbt_d.block_until_ready()
-        tw.mark("bin_device")
-        X_d.delete()
-        del X_d
-        y_d, _ = placement.shard_rows(y, mesh)
-        if row_valid is not None:
-            # in-group padding rows (ranker) are dead for counts/histograms
-            vmask = meshlib.validity_mask(n, n_pad)
-            vmask[:n] *= np.asarray(row_valid, np.float32)
-            vmask_d, _ = placement.shard_rows(vmask, mesh)
-        else:
-            vmask_d = _device_validity_mask(n, n_pad, mesh)
-        if weight is not None:
-            w_d, _ = placement.shard_rows(
-                np.asarray(weight, np.float32), mesh)
-        else:
-            # default unit weights with zeros on padding rows — exactly the
-            # validity mask, so no second array is synthesized or stored
-            w_d = vmask_d
-        if tw.on:
-            jax.block_until_ready((y_d, w_d, vmask_d))
-            tw.mark("aux_shards")
-        return cls(binner, Xbt_d, y_d, w_d, vmask_d, n, n_pad, mesh,
-                   max_bin, categorical_features)
+        with _spans.span("gbdt_dataset", rows=n, features=F) as ds, \
+                _Phases(ds) as phases:
+            phases.enter("gbdt_binner_fit")
+            binner = QuantileBinner(max_bin, bin_sample_count, seed,
+                                    categorical_features,
+                                    max_bin_by_feature).fit(X)
+            # transfers are asynchronous: what of the raw matrix's upload
+            # outlasts this phase shows in gbdt_dataset_bin, which waits
+            phases.enter("gbdt_dataset_xfer")
+            # placement decision (observable): dataset rows are batch-dim
+            # sharded over the mesh's data axis when it has >1 shard; the
+            # note carries the binned matrix's storage dtype so the flight
+            # ring shows how wide the HBM-resident dataset landed
+            placement.plan_for("gbdt.ingest", mesh=mesh, rows=n,
+                               dtype=bd.name)
+            # Binning runs ON DEVICE, producing the column-major
+            # [F, n_local] layout tree growth consumes (the host
+            # searchsorted pass measured 1.6 s at the 1Mx28 bench shape vs
+            # ~ms of VPU compare-sums; raw and binned rows are the same byte
+            # count so the transfer is unchanged). Padding rows bin to
+            # garbage but carry vmask 0 downstream.
+            X_d, _ = placement.shard_rows(X, mesh)
+            phases.enter("gbdt_dataset_bin")
+            bin_fn = _bin_program(X_d.shape, max_bin, mesh, bin_dtype=bd)
+            n_pad = X_d.shape[0]
+            Xbt_d = bin_fn(X_d, jnp.asarray(binner.upper_bounds))
+            # the raw copy served only to produce the binned matrix: free
+            # its HBM now or both dataset-sized buffers stay live for the
+            # whole run
+            Xbt_d.block_until_ready()
+            X_d.delete()
+            del X_d
+            phases.enter("gbdt_dataset_aux")
+            y_d, _ = placement.shard_rows(y, mesh)
+            if row_valid is not None:
+                # in-group padding rows (ranker) are dead for
+                # counts/histograms
+                vmask = meshlib.validity_mask(n, n_pad)
+                vmask[:n] *= np.asarray(row_valid, np.float32)
+                vmask_d, _ = placement.shard_rows(vmask, mesh)
+            else:
+                vmask_d = _device_validity_mask(n, n_pad, mesh)
+            if weight is not None:
+                w_d, _ = placement.shard_rows(
+                    np.asarray(weight, np.float32), mesh)
+            else:
+                # default unit weights with zeros on padding rows — exactly
+                # the validity mask, so no second array is synthesized or
+                # stored
+                w_d = vmask_d
+            return cls(binner, Xbt_d, y_d, w_d, vmask_d, n, n_pad, mesh,
+                       max_bin, categorical_features)
 
 
 def _with_tree_defaults(fields: Dict) -> Dict:
@@ -1562,6 +1586,7 @@ def _measure_hist_engine(engine: str, binned_d, stats_d,
 _HIST_CAL_ROWS = 16384
 
 
+@_in_fit_span
 def train_booster(
     X: Optional[np.ndarray] = None,
     y: Optional[np.ndarray] = None,
@@ -1604,6 +1629,7 @@ def train_booster(
     provide_training_metric: bool = False,
     max_bin_by_feature=None,
     eval_metric_name: Optional[str] = None,
+    _phases: Optional[_Phases] = None,    # _in_fit_span's, never a caller's
 ) -> Booster:
     """Train a boosted ensemble, rows sharded over the mesh ``data`` axis.
 
@@ -1621,6 +1647,8 @@ def train_booster(
     ``mesh`` are taken from the dataset (``X`` may still be passed alongside
     for ``init_booster`` warm starts, which score raw rows).
     """
+    phases, fit = _phases, _phases.parent       # the gbdt_fit span's own
+    phases.enter("gbdt_fit_prepare")
     # persistent compile cache (utils/compile_cache): wire it before the
     # first program of this fit traces, so serving workers and repeat CLI
     # fits skip the cold multi-second XLA compile
@@ -1811,7 +1839,6 @@ def train_booster(
                 return _truncate_booster(init_booster,
                                          prior + num_iterations)
 
-    tw = _PhaseTimer()
     if boosting_type == "rf":
         # random forest: no shrinkage; the averaged ensemble is scaled at
         # finalize time instead (LightGBM rf semantics)
@@ -1826,12 +1853,13 @@ def train_booster(
             bin_sample_count=bin_sample_count, seed=seed,
             categorical_features=categorical_features, mesh=mesh,
             row_valid=row_valid, bin_dtype=bin_dtype,
-            max_bin_by_feature=max_bin_by_feature, _timer=tw)
+            max_bin_by_feature=max_bin_by_feature)
     mesh = dataset.mesh
     binner = dataset.binner
     max_bin = dataset.max_bin
     cfg = cfg._replace(num_bins=max_bin)
     n, n_pad, F = dataset.n, dataset.n_pad, dataset.num_features
+    fit.set(trees=num_iterations * K, rows=n, features=F)
     Xbt_d, y_d, w_d, vmask_d = (dataset.Xbt_d, dataset.y_d, dataset.w_d,
                                 dataset.vmask_d)
     # categorical routing mask: None when absent so the purely-numeric path
@@ -1931,9 +1959,6 @@ def train_booster(
         base = np.zeros(K, dtype=np.float32)
         scores_d = _device_tile_scores(jnp.zeros(K, jnp.float32), n_pad, K,
                                        mesh)
-    if tw.on:
-        jax.block_until_ready(scores_d)
-        tw.mark("base_scores")
 
     has_valid = valid_set is not None
     valid_fp = None
@@ -1970,9 +1995,6 @@ def train_booster(
         else:
             vscores0 = np.tile(base[None, :], (nv, 1))
         vscores_d, _ = placement.shard_rows(vscores0.astype(np.float32), mesh)
-        if tw.on:
-            jax.block_until_ready((Xvb_d, yv_d, wv_d, vscores_d))
-            tw.mark("valid_prep")
     else:
         Xvb_d = yv_d = wv_d = vscores_d = None
 
@@ -2007,7 +2029,7 @@ def train_booster(
             metric_eval_period=metric_eval_period,
             drop_rate=drop_rate, max_drop=max_drop, skip_drop=skip_drop,
             drop_seed=drop_seed, binner=binner, max_bin=max_bin,
-            is_cat_j=is_cat_j)
+            is_cat_j=is_cat_j, phases=phases)
 
     grow_axis = _grow_axis_for(mesh, cfg)
 
@@ -2019,44 +2041,47 @@ def train_booster(
         cond (``_grow_with_warmup``), and rf's validation metric evaluates
         the *average* of the trees grown so far.
         """
-        if K > 1:
-            grad, hess = obj.grad_hess(scores, yl, wl)
-        else:
-            grad, hess = obj.grad_hess(scores[:, 0], yl, wl)
-            grad, hess = grad[:, None], hess[:, None]
-        if use_goss:
-            # GOSS (boostingType=goss): keep the top_rate fraction by |grad|,
-            # sample other_rate of the rest amplified by (1-a)/b. The
-            # amplification rides the row mask, so weighted counts see it too
-            # (a documented deviation from LightGBM's unweighted counts).
-            absg = jnp.abs(grad).sum(axis=1) * vmask
-            n_valid = jnp.maximum(jnp.sum(vmask), 1.0)
-            # keep top_rate*n_valid rows of an N-row shard (padded rows have
-            # absg 0 and cluster at the bottom of the quantile)
-            q = jnp.clip(1.0 - top_rate * n_valid / vmask.shape[0], 0.0, 1.0)
-            top = absg >= jnp.quantile(absg, q)
-            k2 = jax.random.fold_in(bag_key, jax.lax.axis_index("data"))
-            keep_p = other_rate / max(1.0 - top_rate, 1e-6)
-            rest_keep = jax.random.uniform(k2, vmask.shape) < keep_p
-            amp = (1.0 - top_rate) / max(other_rate, 1e-6)
-            row_mask = vmask * jnp.where(top, 1.0,
-                                         jnp.where(rest_keep, amp, 0.0))
-        elif use_bagging:
-            # bag_key changes only every bagging_freq iterations (LightGBM
-            # semantics: the subsample is reused for baggingFreq rounds)
-            k = jax.random.fold_in(bag_key, jax.lax.axis_index("data"))
-            if stratified_bagging:
-                # LightGBM pos/neg_bagging_fraction: per-class keep
-                # probability (binary labels; validated at entry)
-                frac = jnp.where(yl > 0.5,
-                                 jnp.float32(pos_bagging_fraction),
-                                 jnp.float32(neg_bagging_fraction))
+        with jax.named_scope("gbdt_grad"):
+            if K > 1:
+                grad, hess = obj.grad_hess(scores, yl, wl)
             else:
-                frac = jnp.float32(bagging_fraction)
-            bag = (jax.random.uniform(k, vmask.shape) < frac)
-            row_mask = vmask * bag.astype(jnp.float32)
-        else:
-            row_mask = vmask
+                grad, hess = obj.grad_hess(scores[:, 0], yl, wl)
+                grad, hess = grad[:, None], hess[:, None]
+            if use_goss:
+                # GOSS (boostingType=goss): keep the top_rate fraction by
+                # |grad|, sample other_rate of the rest amplified by
+                # (1-a)/b. The amplification rides the row mask, so weighted
+                # counts see it too (a documented deviation from LightGBM's
+                # unweighted counts).
+                absg = jnp.abs(grad).sum(axis=1) * vmask
+                n_valid = jnp.maximum(jnp.sum(vmask), 1.0)
+                # keep top_rate*n_valid rows of an N-row shard (padded rows
+                # have absg 0 and cluster at the bottom of the quantile)
+                q = jnp.clip(1.0 - top_rate * n_valid / vmask.shape[0],
+                             0.0, 1.0)
+                top = absg >= jnp.quantile(absg, q)
+                k2 = jax.random.fold_in(bag_key, jax.lax.axis_index("data"))
+                keep_p = other_rate / max(1.0 - top_rate, 1e-6)
+                rest_keep = jax.random.uniform(k2, vmask.shape) < keep_p
+                amp = (1.0 - top_rate) / max(other_rate, 1e-6)
+                row_mask = vmask * jnp.where(top, 1.0,
+                                             jnp.where(rest_keep, amp, 0.0))
+            elif use_bagging:
+                # bag_key changes only every bagging_freq iterations (LightGBM
+                # semantics: the subsample is reused for baggingFreq rounds)
+                k = jax.random.fold_in(bag_key, jax.lax.axis_index("data"))
+                if stratified_bagging:
+                    # LightGBM pos/neg_bagging_fraction: per-class keep
+                    # probability (binary labels; validated at entry)
+                    frac = jnp.where(yl > 0.5,
+                                     jnp.float32(pos_bagging_fraction),
+                                     jnp.float32(neg_bagging_fraction))
+                else:
+                    frac = jnp.float32(bagging_fraction)
+                bag = (jax.random.uniform(k, vmask.shape) < frac)
+                row_mask = vmask * bag.astype(jnp.float32)
+            else:
+                row_mask = vmask
 
         trees_out = []
         fmask = jnp.ones(F, dtype=bool)
@@ -2076,7 +2101,8 @@ def train_booster(
             if not is_rf:
                 # rf: trees are independent (gradients stay at the base
                 # score); gbdt/goss: boost on the updated margin
-                scores = scores.at[:, k].add(tree.leaf_value[row_node])
+                with jax.named_scope("gbdt_score_update"):
+                    scores = scores.at[:, k].add(tree.leaf_value[row_node])
             trees_out.append(tree)
         trees_stacked = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *trees_out)
@@ -2174,6 +2200,7 @@ def train_booster(
         donate = ()
     else:
         donate = (4, 8) if has_valid else (4,)
+    fit.set(path="host_loop", program=_hit_or_built(cache_key))
     step = _cached_program(cache_key, lambda: jax.jit(shard_map(
         step_packed, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False), donate_argnums=donate))
@@ -2240,19 +2267,19 @@ def train_booster(
                 in_specs=(col_spec, row_spec, row_spec, row_spec, row2_spec),
                 out_specs=P(), check_vma=False))
 
-        multi = _cached_program(fuse_key, build_multi)
-        tw.mark("build_multi")
-        from ...utils.profiling import annotate
-        with annotate(f"gbdt_train_fused:{num_iterations}it"):
-            trees_dev = multi(Xbt_d, y_d, w_d, vmask_d, scores_d)
-        if tw.on:
-            jax.block_until_ready(trees_dev)
-            tw.mark("multi_exec")
+        multi = phases.program("fused", fuse_key, build_multi)
+        # a first call traces, lowers and compiles (or loads) in here:
+        # utils/compile_cache.py records those stages as children
+        phases.enter("gbdt_fit_dispatch")
+        trees_dev = multi(Xbt_d, y_d, w_d, vmask_d, scores_d)
+        phases.enter("gbdt_fit_wait")
+        jax.block_until_ready(trees_dev)
+        phases.enter("gbdt_fit_download")
         trees_seq = unpack_trees(np.asarray(trees_dev),
                                  (num_iterations, K),
                                  2 * cfg.num_leaves - 1,
                                  bitset_words(cfg.num_bins))
-        tw.mark("trees_download")
+        phases.enter("gbdt_fit_finalize")
         all_seq: List[Tree] = []
         for it in range(num_iterations):
             for k in range(K):
@@ -2314,13 +2341,14 @@ def train_booster(
                           row2_spec, row_spec, row_spec, row2_spec),
                 out_specs=(P(), P(), P(), P()), check_vma=False))
 
-        multi_v = _cached_program(fuse_key, build_multi_valid)
-        tw.mark("build_multi_valid")
-        from ...utils.profiling import annotate
-        with annotate(f"gbdt_train_fused_valid:{num_iterations}it"):
-            buf_dev, mbuf_dev, n_done_dev, best_it_dev = multi_v(
-                Xbt_d, y_d, w_d, vmask_d, scores_d, Xvb_d, yv_d, wv_d,
-                vscores_d)
+        multi_v = phases.program("fused_valid", fuse_key, build_multi_valid)
+        phases.enter("gbdt_fit_dispatch")
+        buf_dev, mbuf_dev, n_done_dev, best_it_dev = multi_v(
+            Xbt_d, y_d, w_d, vmask_d, scores_d, Xvb_d, yv_d, wv_d,
+            vscores_d)
+        phases.enter("gbdt_fit_wait")
+        jax.block_until_ready((buf_dev, mbuf_dev, n_done_dev, best_it_dev))
+        phases.enter("gbdt_fit_download")
         n_done = int(n_done_dev)
         best_iter = int(best_it_dev)
         # slice on device before downloading: when early stopping fires well
@@ -2329,7 +2357,6 @@ def train_booster(
         mbuf = np.asarray(mbuf_dev[:n_done])
         history[metric_name].extend(float(x) for x in mbuf)
         rows = np.asarray(buf_dev[:n_done])
-        tw.mark("trees_download")
         for it in range(n_done):
             # each buffer row is one iteration's pack of K stacked trees —
             # the same layout the host loop downloads per iteration
@@ -2396,6 +2423,8 @@ def train_booster(
                            reason=category, iteration=step)
 
         unregister_dump = _watchdog.add_event_callback(_last_good_dump)
+    if not fuse_es:
+        phases.enter("gbdt_fit_rounds")
     t_round = time.perf_counter()
     try:
         for it in ([] if fuse_es else range(iterations_done, num_iterations)):
@@ -2489,6 +2518,7 @@ def train_booster(
         hb.close()
         if unregister_dump is not None:
             unregister_dump()
+    phases.enter("gbdt_fit_finalize")
     booster = _finalize(all_trees)
     # early-stop truncation applies to fresh runs and checkpoint resumes
     # alike (the checkpoint's trees carry global iteration indices); only a
@@ -2521,7 +2551,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
                 early_stopping_rounds, iteration_callback, metric_eval_period,
                 early_stopping_tolerance=0.0,
                 drop_rate, max_drop, skip_drop, drop_seed,
-                binner, max_bin, is_cat_j=None) -> Booster:
+                binner, max_bin, is_cat_j=None, phases) -> Booster:
     """DART boosting: Dropouts meet Multiple Additive Regression Trees.
 
     Parity target: LightGBM's ``boosting=dart`` (reference exposes it via
@@ -2630,6 +2660,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
             check_vma=False)) if has_valid else None)
         return dstep, deval
 
+    phases.parent.set(path="dart", program=_hit_or_built(cache_key))
     dstep, deval = _cached_program(cache_key, build_dart)
 
     sh = lambda spec: placement.sharding(spec, mesh)
@@ -2719,16 +2750,18 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
                           row_spec if has_valid else P()),
                 out_specs=(P(), P(), P(), P()), check_vma=False))
 
-        multi_d = _cached_program(fuse_key, build_dart_fused)
-        from ...utils.profiling import annotate
-        with annotate(f"dart_train_fused:{num_iterations}it"):
-            buf_dev, mbuf_dev, n_done_dev, best_it_dev = multi_d(
-                Xbt_d, y_d, w_d, vmask_d, contribs_d,
-                Xvb_d if has_valid else dummy,
-                vcontribs_d if has_valid else dummy,
-                jnp.asarray(eff_rows), jnp.asarray(post_rows),
-                yv_d if has_valid else dummy,
-                wv_d if has_valid else dummy)
+        multi_d = phases.program("dart", fuse_key, build_dart_fused)
+        phases.enter("gbdt_fit_dispatch")
+        buf_dev, mbuf_dev, n_done_dev, best_it_dev = multi_d(
+            Xbt_d, y_d, w_d, vmask_d, contribs_d,
+            Xvb_d if has_valid else dummy,
+            vcontribs_d if has_valid else dummy,
+            jnp.asarray(eff_rows), jnp.asarray(post_rows),
+            yv_d if has_valid else dummy,
+            wv_d if has_valid else dummy)
+        phases.enter("gbdt_fit_wait")
+        jax.block_until_ready((buf_dev, mbuf_dev, n_done_dev, best_it_dev))
+        phases.enter("gbdt_fit_download")
         n_done = int(n_done_dev)
         best_iter = int(best_it_dev)
         if has_valid:
@@ -2746,6 +2779,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
         # the per-tree scale vector is the post-step scales of the last
         # executed iteration — identical to the host loop's final `scales`
         scales = post_rows[n_done - 1].copy()
+        phases.enter("gbdt_fit_finalize")
         booster = _finalize_trees(all_trees, binner, max_bin, K, base,
                                   objective, depth_cap, objective_kwargs,
                                   best_iter, history, None)
@@ -2753,6 +2787,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
                                      np.repeat(scales[:n_done], K))
 
     hb = _watchdog.register("gbdt_dart_round_loop", stall_seconds=120.0)
+    phases.enter("gbdt_fit_rounds")
     t_round = time.perf_counter()
     try:
         for it in range(num_iterations):
@@ -2802,6 +2837,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
 
     finally:
         hb.close()
+    phases.enter("gbdt_fit_finalize")
     booster = _finalize_trees(all_trees, binner, max_bin, K, base, objective,
                               depth_cap, objective_kwargs, best_iter, history,
                               None)

@@ -281,6 +281,7 @@ def _positional_uniform(key, channels: int, n_local: int, axis_name):
         1.0 / (1 << 24))
 
 
+@jax.named_scope("gbdt_quantize")
 def _quantize_for(cfg: GrowConfig, base_t, qkey, axis_name, blocks_local,
                   rows_per_block):
     """int8 stat quantization, topology-aware. Blocked mode derives the
@@ -436,6 +437,7 @@ def bit_test(bits: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return ((word >> (idx.astype(jnp.uint32) & 31)) & 1).astype(bool)
 
 
+@jax.named_scope("gbdt_split_find")
 def _best_split(hist, tot_g, tot_h, tot_c, cfg: GrowConfig, feat_mask, allow,
                 is_cat=None):
     """Best split of one node from its histogram — numeric or categorical.
@@ -498,6 +500,7 @@ def _best_split(hist, tot_g, tot_h, tot_c, cfg: GrowConfig, feat_mask, allow,
             pick(gl), pick(hl), pick(cl), bits)
 
 
+@jax.named_scope("gbdt_route")
 def _route_rows_to_children(binned_t, row_node, slots, do, feats, bins_,
                             bits_k, lid, is_cat):
     """Shared [W, n] row-routing for batched growth (leafwise rounds and
@@ -559,6 +562,7 @@ def _use_subtraction(cfg, axis_name, n: int) -> bool:
             and not cfg.voting and n >= 8192)
 
 
+@jax.named_scope("gbdt_hist")
 def _subtracted_pair_hists(binned_t, base_t, qscales, row_small,
                            small_is_left, parent_hists, K, B, h_buf, cfg):
     """Shared compaction+subtraction core for both growth policies.
@@ -612,6 +616,7 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         base_t, qscales = _quantize_for(cfg, base_t, qkey, axis_name, bl,
                                         rpb)
 
+    @jax.named_scope("gbdt_hist")
     def all_hist(row_pos, W):
         """Global per-node histogram [F, W*3, B] + selected-feature mask.
 
@@ -711,25 +716,28 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             # stream only each candidate's SMALLER child (by raw routed row
             # count, which is what bounds the n//2 buffer); the larger
             # sibling derives from the cached candidate histogram
-            rawL = jnp.sum(move & goleft_k, axis=1).astype(jnp.int32)
-            rawA = jnp.sum(move, axis=1).astype(jnp.int32)
-            small_is_left = rawL <= rawA - rawL               # ties -> left
-            in_small = jnp.any(
-                move & (goleft_k == small_is_left[:, None]), axis=0)
-            spos = jnp.sum(jnp.where(move, arange_kb[:, None], 0), axis=0)
-            row_small = jnp.where(in_small, spos, -1).astype(jnp.int32)
+            with jax.named_scope("gbdt_route"):
+                rawL = jnp.sum(move & goleft_k, axis=1).astype(jnp.int32)
+                rawA = jnp.sum(move, axis=1).astype(jnp.int32)
+                small_is_left = rawL <= rawA - rawL           # ties -> left
+                in_small = jnp.any(
+                    move & (goleft_k == small_is_left[:, None]), axis=0)
+                spos = jnp.sum(jnp.where(move, arange_kb[:, None], 0),
+                               axis=0)
+                row_small = jnp.where(in_small, spos, -1).astype(jnp.int32)
             hw = _subtracted_pair_hists(
                 binned_t, base_t, qscales, row_small, small_is_left,
                 st["nhist"][jnp.where(do, slots, 0)], KB, B, h_buf, cfg)
             sel = jnp.ones(F, dtype=bool)
         else:
             # child position in [0, 2*KB): 2i = left child of candidate i
-            cpos = jnp.where(goleft_k, 2 * arange_kb[:, None],
-                             2 * arange_kb[:, None] + 1)
-            in_any = jnp.any(move, axis=0)
-            child_pos = jnp.where(
-                in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
-            ).astype(jnp.int32)
+            with jax.named_scope("gbdt_route"):
+                cpos = jnp.where(goleft_k, 2 * arange_kb[:, None],
+                                 2 * arange_kb[:, None] + 1)
+                in_any = jnp.any(move, axis=0)
+                child_pos = jnp.where(
+                    in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
+                ).astype(jnp.int32)
 
             h, sel = all_hist(child_pos, W2)         # [F, W2*3, B]
             hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
@@ -751,36 +759,37 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         new = dict(st)
         new["row_node"] = new_row_node
 
-        # record splits; index M is out of bounds -> dropped for non-splits
-        pslot = jnp.where(do, slots, M)
-        cslot = jnp.where(jnp.repeat(do, 2),
-                          jnp.stack([lid, rid], 1).reshape(-1), M)
-        cdep2 = jnp.repeat(child_depth, 2)
-        new["feat"] = st["feat"].at[pslot].set(feats, mode="drop")
-        new["thr"] = st["thr"].at[pslot].set(bins_, mode="drop")
-        new["left"] = st["left"].at[pslot].set(lid, mode="drop")
-        new["right"] = st["right"].at[pslot].set(rid, mode="drop")
-        new["is_leaf"] = st["is_leaf"].at[pslot].set(False, mode="drop")
-        new["gain"] = st["gain"].at[pslot].set(top_g, mode="drop")
-        new["tbits"] = st["tbits"].at[pslot].set(bits_k, mode="drop")
-        new["depth"] = st["depth"].at[cslot].set(cdep2, mode="drop")
-        new["ng"] = st["ng"].at[cslot].set(tg, mode="drop")
-        new["nh"] = st["nh"].at[cslot].set(th, mode="drop")
-        new["nc"] = st["nc"].at[cslot].set(tc, mode="drop")
-        new["cg"] = (st["cg"].at[pslot].set(NEG_INF, mode="drop")
-                     .at[cslot].set(g2, mode="drop"))
-        new["cf"] = st["cf"].at[cslot].set(f2, mode="drop")
-        new["cb"] = st["cb"].at[cslot].set(b2, mode="drop")
-        new["clg"] = st["clg"].at[cslot].set(lg2, mode="drop")
-        new["clh"] = st["clh"].at[cslot].set(lh2, mode="drop")
-        new["clc"] = st["clc"].at[cslot].set(lc2, mode="drop")
-        new["cbits"] = st["cbits"].at[cslot].set(bits2, mode="drop")
-        new["num_nodes"] = st["num_nodes"] + 2 * n_split
-        if use_sub:
-            # cache the children's histograms: they are the subtraction
-            # parents of every round that later splits them (cslot order is
-            # [l0, r0, l1, r1, ...], matching hw's channel order)
-            new["nhist"] = st["nhist"].at[cslot].set(hw, mode="drop")
+        with jax.named_scope("gbdt_tree_update"):
+            # record splits; index M is out of bounds -> dropped for non-splits
+            pslot = jnp.where(do, slots, M)
+            cslot = jnp.where(jnp.repeat(do, 2),
+                              jnp.stack([lid, rid], 1).reshape(-1), M)
+            cdep2 = jnp.repeat(child_depth, 2)
+            new["feat"] = st["feat"].at[pslot].set(feats, mode="drop")
+            new["thr"] = st["thr"].at[pslot].set(bins_, mode="drop")
+            new["left"] = st["left"].at[pslot].set(lid, mode="drop")
+            new["right"] = st["right"].at[pslot].set(rid, mode="drop")
+            new["is_leaf"] = st["is_leaf"].at[pslot].set(False, mode="drop")
+            new["gain"] = st["gain"].at[pslot].set(top_g, mode="drop")
+            new["tbits"] = st["tbits"].at[pslot].set(bits_k, mode="drop")
+            new["depth"] = st["depth"].at[cslot].set(cdep2, mode="drop")
+            new["ng"] = st["ng"].at[cslot].set(tg, mode="drop")
+            new["nh"] = st["nh"].at[cslot].set(th, mode="drop")
+            new["nc"] = st["nc"].at[cslot].set(tc, mode="drop")
+            new["cg"] = (st["cg"].at[pslot].set(NEG_INF, mode="drop")
+                         .at[cslot].set(g2, mode="drop"))
+            new["cf"] = st["cf"].at[cslot].set(f2, mode="drop")
+            new["cb"] = st["cb"].at[cslot].set(b2, mode="drop")
+            new["clg"] = st["clg"].at[cslot].set(lg2, mode="drop")
+            new["clh"] = st["clh"].at[cslot].set(lh2, mode="drop")
+            new["clc"] = st["clc"].at[cslot].set(lc2, mode="drop")
+            new["cbits"] = st["cbits"].at[cslot].set(bits2, mode="drop")
+            new["num_nodes"] = st["num_nodes"] + 2 * n_split
+            if use_sub:
+                # cache the children's histograms: they are the subtraction
+                # parents of every round that later splits them (cslot order is
+                # [l0, r0, l1, r1, ...], matching hw's channel order)
+                new["nhist"] = st["nhist"].at[cslot].set(hw, mode="drop")
         return new
 
     def round_body(_, st):
@@ -818,6 +827,7 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     return tree, state["row_node"]
 
 
+@jax.named_scope("gbdt_renew_leaf")
 def _renew_leaf_stats(state, grad, hess, vm, M: int, axis_name,
                       blocks_local: int = 0, rows_per_block: int = 0):
     """Full-precision leaf-stat renewal for quantized training (LightGBM
@@ -999,25 +1009,27 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
                 # one fused histogram pass covers the whole level: the
                 # row->position one-hot and masked stats are built in VMEM
                 feat_mask_lvl = feat_mask
-                if bl:
-                    # canonical blocked fold: topology-independent f32 order
-                    h = _blocked_node_hist(binned_t, row_pos, base_t, W, B,
-                                           qscales, bl, rpb, axis_name)
-                else:
-                    h = node_histogram(binned_t, row_pos, base_t, W, B,
-                                       scales=qscales)         # [F, W*3, B]
-                    if axis_name is not None:
-                        if cfg.voting:
-                            # per-level voting: shards vote top_k features
-                            # by their best local gain across the WHOLE
-                            # frontier, then only the global top-2k
-                            # features' level histograms cross the
-                            # interconnect
-                            h, sel = _voting_select(h, feat_mask, cfg,
-                                                    axis_name, W)
-                            feat_mask_lvl = feat_mask & sel
-                        else:
-                            h = lax.psum(h, axis_name)
+                with jax.named_scope("gbdt_hist"):
+                    if bl:
+                        # canonical blocked fold: topology-independent f32
+                        # order
+                        h = _blocked_node_hist(binned_t, row_pos, base_t, W,
+                                               B, qscales, bl, rpb, axis_name)
+                    else:
+                        h = node_histogram(binned_t, row_pos, base_t, W, B,
+                                           scales=qscales)     # [F, W*3, B]
+                        if axis_name is not None:
+                            if cfg.voting:
+                                # per-level voting: shards vote top_k
+                                # features by their best local gain across
+                                # the WHOLE frontier, then only the global
+                                # top-2k features' level histograms cross
+                                # the interconnect
+                                h, sel = _voting_select(h, feat_mask, cfg,
+                                                        axis_name, W)
+                                feat_mask_lvl = feat_mask & sel
+                            else:
+                                h = lax.psum(h, axis_name)
                 h = h.reshape(F, W, 3, B).transpose(1, 0, 2, 3)  # [W,F,3,B]
 
             tot = jnp.stack([tree_arrays["ng"][jnp.maximum(fr, 0)],
@@ -1052,27 +1064,28 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
                 binned_t, row_node, jnp.where(active, fr, -1), do, feats,
                 bins_, bits_w, lid, is_cat)
 
-            # record splits into tree arrays; index M (out of bounds) drops
-            # the scatter for nodes that don't split
-            slot = jnp.where(do, fr, M)
-            ta = dict(tree_arrays)
-            ta["feat"] = ta["feat"].at[slot].set(feats, mode="drop")
-            ta["thr"] = ta["thr"].at[slot].set(bins_, mode="drop")
-            ta["left"] = ta["left"].at[slot].set(lid, mode="drop")
-            ta["right"] = ta["right"].at[slot].set(rid, mode="drop")
-            ta["is_leaf"] = ta["is_leaf"].at[slot].set(False, mode="drop")
-            ta["gain"] = ta["gain"].at[slot].set(gains, mode="drop")
-            ta["bits"] = ta["bits"].at[slot].set(bits_w, mode="drop")
-            # children stats
-            parent_g, parent_h, parent_c = tot[:, 0], tot[:, 1], tot[:, 2]
-            lslot = jnp.where(do, lid, M)
-            rslot = jnp.where(do, rid, M)
-            ta["ng"] = ta["ng"].at[lslot].set(lgs, mode="drop")
-            ta["ng"] = ta["ng"].at[rslot].set(parent_g - lgs, mode="drop")
-            ta["nh"] = ta["nh"].at[lslot].set(lhs, mode="drop")
-            ta["nh"] = ta["nh"].at[rslot].set(parent_h - lhs, mode="drop")
-            ta["nc"] = ta["nc"].at[lslot].set(lcs, mode="drop")
-            ta["nc"] = ta["nc"].at[rslot].set(parent_c - lcs, mode="drop")
+            with jax.named_scope("gbdt_tree_update"):
+                # record splits into tree arrays; index M (out of bounds) drops
+                # the scatter for nodes that don't split
+                slot = jnp.where(do, fr, M)
+                ta = dict(tree_arrays)
+                ta["feat"] = ta["feat"].at[slot].set(feats, mode="drop")
+                ta["thr"] = ta["thr"].at[slot].set(bins_, mode="drop")
+                ta["left"] = ta["left"].at[slot].set(lid, mode="drop")
+                ta["right"] = ta["right"].at[slot].set(rid, mode="drop")
+                ta["is_leaf"] = ta["is_leaf"].at[slot].set(False, mode="drop")
+                ta["gain"] = ta["gain"].at[slot].set(gains, mode="drop")
+                ta["bits"] = ta["bits"].at[slot].set(bits_w, mode="drop")
+                # children stats
+                parent_g, parent_h, parent_c = tot[:, 0], tot[:, 1], tot[:, 2]
+                lslot = jnp.where(do, lid, M)
+                rslot = jnp.where(do, rid, M)
+                ta["ng"] = ta["ng"].at[lslot].set(lgs, mode="drop")
+                ta["ng"] = ta["ng"].at[rslot].set(parent_g - lgs, mode="drop")
+                ta["nh"] = ta["nh"].at[lslot].set(lhs, mode="drop")
+                ta["nh"] = ta["nh"].at[rslot].set(parent_h - lhs, mode="drop")
+                ta["nc"] = ta["nc"].at[lslot].set(lcs, mode="drop")
+                ta["nc"] = ta["nc"].at[rslot].set(parent_c - lcs, mode="drop")
 
             # next frontier: the children, compacted into 2*W slots
             W_next = min(2 * W, L)

@@ -212,12 +212,6 @@ REGISTRY: Tuple[EnvVar, ...] = (
     EnvVar(name="MMLSPARK_TPU_DISABLE_FUSED_DART", default="(off)",
            section="performance",
            doc="set to force the host round loop for DART training"),
-    EnvVar(name="MMLSPARK_TPU_TIMING", default="(off)",
-           section="performance",
-           doc="`1` prints a wall-time phase breakdown per "
-               "`train_booster` call (console output by design — an "
-               "explicit operator request, independent of the telemetry "
-               "kill switch)"),
     EnvVar(name="MMLSPARK_TPU_BINNED_CACHE", default="1",
            section="performance",
            doc="`0` disables the binned-device-dataset fit cache (the "

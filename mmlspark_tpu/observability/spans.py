@@ -32,7 +32,8 @@ from . import metrics as _metrics
 from . import tracing as _tracing
 
 __all__ = [
-    "span", "span_fn", "instant", "dump_trace", "get_trace_events",
+    "span", "span_fn", "instant", "record_finished", "dump_trace",
+    "get_trace_events",
     "clear_trace", "set_default_attrs", "get_default_attrs", "current_span",
     "MAX_TRACE_EVENTS", "set_max_trace_events", "get_max_trace_events",
     "dropped_events",
@@ -233,6 +234,28 @@ def instant(name: str, **attrs: Any) -> None:
         "pid": _pid(), "tid": threading.get_ident(),
         "args": {**_default_attrs, **attrs},
     })
+
+
+def record_finished(name: str, seconds: float, **attrs: Any) -> None:
+    """Record a span that lasted ``seconds`` and ends now, as a child of the
+    span open in this context — for work that reports its duration only
+    once it is over (jax's compile-stage monitoring events,
+    utils/compile_cache.py). Records nothing while no span is open, so the
+    same work elsewhere in the process leaves no orphan events."""
+    if not _metrics.enabled():
+        return
+    parent = _parent.get()
+    if parent is None:
+        return
+    end = time.perf_counter()
+    _record({
+        "name": name, "ph": "X", "cat": "mmlspark",
+        "ts": (end - seconds) * 1e6, "dur": seconds * 1e6,
+        "pid": _pid(), "tid": threading.get_ident(),
+        "args": {**_default_attrs, **attrs, "parent": parent.name},
+    })
+    _metrics.safe_histogram("span_duration_seconds",
+                            name=name).observe(seconds)
 
 
 def get_trace_events() -> List[Dict[str, Any]]:

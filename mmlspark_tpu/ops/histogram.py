@@ -685,6 +685,7 @@ def _hist_pallas(binned_t: jnp.ndarray, stats_t: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((Fp, Sp, BP), jnp.float32),
         interpret=_interpret_mode(),
         compiler_params=_compiler_params(),
+        name="gbdt_hist_kernel",
     )(binned_t, stats_t)
     return out[:F, :S, :B]
 
@@ -721,5 +722,6 @@ def _node_hist_pallas(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((Fp, Sp, BP), out_dtype),
         interpret=_interpret_mode(),
         compiler_params=_compiler_params(),
+        name="gbdt_node_hist_kernel",
     )(binned_t, row_pos, base8)
     return out[:F, :S, :B]

@@ -103,6 +103,7 @@ def ensure(fallback_dir: Optional[str] = None) -> Optional[str]:
         # cache engages from here on.
         _jcc.reset_cache()
         monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
         return _DIR
 
 
@@ -123,3 +124,26 @@ def _on_event(event: str, **kwargs) -> None:
     if counter:
         from ..observability import metrics as _metrics
         _metrics.safe_counter(counter).inc()
+
+
+_STAGE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "gbdt_jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "gbdt_jax_lower",
+    # compile_or_get_cached as a whole: a persistent-cache read is inside it
+    "/jax/core/compile/backend_compile_duration": "gbdt_xla_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "gbdt_cache_load",
+}
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    """jax's compile stages as children of the span they ran under (a first
+    ``gbdt_fit_dispatch`` holds the fit program's trace, lowering and
+    compile or cache load). jax reports each stage when it ends, so the
+    span is recorded finished; outside any span nothing is recorded. A jit
+    traced inside another reports its own trace stage nested in the outer
+    one's — one fit program holds some 350 such traces of ``jnp`` helpers,
+    microseconds each — so stages under a millisecond are left out."""
+    name = _STAGE_SPANS.get(event)
+    if name and duration_secs >= 1e-3:
+        from ..observability import spans as _spans
+        _spans.record_finished(name, duration_secs, **kwargs)
