@@ -500,14 +500,55 @@ def _best_split(hist, tot_g, tot_h, tot_c, cfg: GrowConfig, feat_mask, allow,
             pick(gl), pick(hl), pick(cl), bits)
 
 
+# Widest bitset, in uint32 words, that row routing tests with a select chain
+# (8192 bins); wider ones keep XLA's gather. On a v5e the gather costs about
+# 7 ns an element whatever the width, the whole routing with the chain 0.05 ns
+# at 2 and 8 words, 0.11 at 64 and 0.72 at 256 (PERF.md, PR 27), so by rate
+# the chain would win up to some 2000 words. The bound is the unrolled chain's
+# compile time, paid once a leafwise program and once a level depthwise: level
+# with the gather's up to 256 words, five times it at 1024. uint8 bins give at
+# most 8 words.
+_ROUTE_SELECT_MAX_WORDS = 256
+
+
+def _note_route_lookup(lookup: str) -> None:
+    """gbdt_route_lookup_total{lookup}: counted where the routing is staged
+    out (the choice follows from the bitset's static width), so it tracks
+    program builds and costs nothing on the device."""
+    try:
+        from ...observability import metrics as _metrics
+        _metrics.safe_counter("gbdt_route_lookup_total", lookup=lookup).inc()
+    except Exception:  # noqa: BLE001 — telemetry must not fail the fit
+        pass
+
+
+def _bitset_words_of_rows(bits_k, rows):
+    """word[w, i] = bits_k[w, rows[w, i] >> 5] for a [W, BW] bitset table and
+    [W, n] bin ids. Up to ``_ROUTE_SELECT_MAX_WORDS`` words it is a select
+    over the words, elementwise with a [W, 1] broadcast; wider bitsets (int16
+    and int32 bins) keep the per-element gather."""
+    BW = bits_k.shape[1]
+    widx = rows >> 5
+    if BW > _ROUTE_SELECT_MAX_WORDS:
+        _note_route_lookup("gather")
+        return jnp.take_along_axis(bits_k, widx, axis=1)
+    _note_route_lookup("select")
+    word = jnp.broadcast_to(bits_k[:, :1], rows.shape)
+    for j in range(1, BW):
+        word = jnp.where(widx == j, bits_k[:, j:j + 1], word)
+    return word
+
+
 @jax.named_scope("gbdt_route")
 def _route_rows_to_children(binned_t, row_node, slots, do, feats, bins_,
                             bits_k, lid, is_cat):
     """Shared [W, n] row-routing for batched growth (leafwise rounds and
     depthwise levels): rows whose current node is a splitting candidate move
-    to its left/right child slot (``lid``/``lid+1``). All routing is
-    elementwise [W, n] + reduce (XLA fuses into one pass) — no per-row
-    feature gathers.
+    to its left/right child slot (``lid``/``lid+1``). The category test is a
+    select over the bitset's words (``_bitset_words_of_rows``), so beyond
+    fetching the W candidate feature rows (``binned_t[feats]``) the routing
+    is elementwise [W, n] + reduce, which XLA fuses with its consumers'
+    reductions into a few passes over the rows.
 
     Returns (new_row_node, move [W, n], goleft_k [W, n]).
     """
@@ -517,9 +558,12 @@ def _route_rows_to_children(binned_t, row_node, slots, do, feats, bins_,
     rows = binned_t[feats].astype(jnp.int32)         # [W, n]
     goleft_k = rows <= bins_[:, None]
     if is_cat is not None:
-        word = jnp.take_along_axis(bits_k, rows >> 5, axis=1)
+        word = _bitset_words_of_rows(bits_k, rows)
         member = ((word >> (rows.astype(jnp.uint32) & 31)) & 1).astype(bool)
-        goleft_k = jnp.where(is_cat[feats][:, None], member, goleft_k)
+        # a [W, F] one-hot test, not is_cat[feats]: no gather, however small
+        cat_k = jnp.any((feats[:, None] == jnp.arange(is_cat.shape[0]))
+                        & is_cat[None, :], axis=1)
+        goleft_k = jnp.where(cat_k[:, None], member, goleft_k)
     in_any = jnp.any(move, axis=0)
     go_left_row = jnp.any(move & goleft_k, axis=0)
     lid_row = jnp.sum(jnp.where(move, lid[:, None], 0), axis=0)
