@@ -1867,6 +1867,7 @@ def train_booster(
     is_cat_np = binner.is_cat_mask()
     is_cat_j = jnp.asarray(is_cat_np) if is_cat_np.any() else None
     nshards = meshlib.num_shards(mesh)
+    fit.set(shards=nshards)
 
     # placement + determinism resolution — BEFORE any compiled-program
     # cache key below (the PR 4 resolve-before-cache-key rule): the plan

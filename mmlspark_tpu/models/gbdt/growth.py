@@ -210,6 +210,38 @@ def resolve_growth_backend(cfg: GrowConfig) -> GrowConfig:
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# Cross-shard reductions: one funnel
+# ---------------------------------------------------------------------------
+
+
+def _allreduce(x, axis_name, what: str, per: str = "tree", op=lax.psum,
+               **kw):
+    """``op(x, axis_name)`` for tree growth; the identity on one shard, where
+    it emits nothing. Every collective of this module goes through here, under
+    ``jax.named_scope("gbdt_allreduce")`` nested in the caller's scope, so a
+    device trace tells the wire from the work.
+
+    gbdt_allreduce_bytes_total{what, per}: the result's bytes on one shard,
+    counted where the reduction is staged out (as ``_note_route_lookup``
+    counts), so it tracks program builds and costs nothing on the device.
+    ``per`` says how often the built program runs the site: once a ``tree``,
+    once a leafwise ``round`` that runs, or once for the depthwise ``level``
+    it belongs to (each level is a site of its own, and a level under the
+    last split is skipped)."""
+    if axis_name is None:
+        return x
+    with jax.named_scope("gbdt_allreduce"):
+        out = op(x, axis_name, **kw)
+    try:
+        from ...observability import metrics as _metrics
+        _metrics.safe_counter("gbdt_allreduce_bytes_total", what=what,
+                              per=per).inc(out.size * out.dtype.itemsize)
+    except Exception:  # noqa: BLE001 — telemetry must not fail the fit
+        pass
+    return out
+
+
 def _hist_block_geometry(cfg: GrowConfig, axis_name, n: int):
     """(blocks_local, rows_per_block) for the blocked reduction; (0, n) on
     the plain psum path. Raises when a pinned block count cannot tile this
@@ -235,13 +267,13 @@ def _hist_block_geometry(cfg: GrowConfig, axis_name, n: int):
     return bl, n // bl
 
 
-def _blocked_fold(parts: jnp.ndarray, axis_name):
+def _blocked_fold(parts: jnp.ndarray, axis_name, what: str, per="tree"):
     """Gather per-shard block partials into canonical order and fold them
     left-to-right. ``parts``: [blocks_local, ...] stacked partials; the
     explicit unrolled fold (not a reduce op) pins the f32 rounding order
     regardless of how XLA would lower an axis reduction."""
-    if axis_name is not None:
-        parts = lax.all_gather(parts, axis_name, axis=0, tiled=True)
+    parts = _allreduce(parts, axis_name, what, per, op=lax.all_gather,
+                       axis=0, tiled=True)
     acc = parts[0]
     for j in range(1, parts.shape[0]):
         acc = acc + parts[j]
@@ -291,9 +323,8 @@ def _quantize_for(cfg: GrowConfig, base_t, qkey, axis_name, blocks_local,
     stochastic-rounding bits from global row indices."""
     if not blocks_local:
         return quantize_stats(base_t, qkey)
-    amax = jnp.max(jnp.abs(base_t), axis=1)
-    if axis_name is not None:
-        amax = lax.pmax(amax, axis_name)
+    amax = _allreduce(jnp.max(jnp.abs(base_t), axis=1), axis_name,
+                      "quantize", op=lax.pmax)
     q_max = quant_q_max(rows_per_block)
     u = None if qkey is None else _positional_uniform(
         qkey, base_t.shape[0], base_t.shape[1], axis_name)
@@ -301,7 +332,8 @@ def _quantize_for(cfg: GrowConfig, base_t, qkey, axis_name, blocks_local,
 
 
 def _blocked_node_hist(binned_t, row_pos, base_t, W: int, B: int, qscales,
-                       blocks_local: int, rows_per_block: int, axis_name):
+                       blocks_local: int, rows_per_block: int, axis_name,
+                       per):
     """[F, W*3, B] histogram via the canonical blocked reduction: one
     engine pass per fixed row block (identical shapes on every topology),
     gathered and folded in block order."""
@@ -312,7 +344,7 @@ def _blocked_node_hist(binned_t, row_pos, base_t, W: int, B: int, qscales,
             base_t[:, j * rows_per_block:(j + 1) * rows_per_block],
             W, B, scales=qscales)
         for j in range(blocks_local)])
-    return _blocked_fold(parts, axis_name)
+    return _blocked_fold(parts, axis_name, "hist", per)
 
 
 def _stat_totals(base_t, qscales, axis_name, blocks_local, rows_per_block):
@@ -348,7 +380,7 @@ def _stat_totals(base_t, qscales, axis_name, blocks_local, rows_per_block):
 
         tot = _blocked_fold(
             jnp.stack([block_sum(j) for j in range(blocks_local)]),
-            axis_name)
+            axis_name, "totals")
         if qscales is not None:
             tot = tot * qscales
         return tot
@@ -356,9 +388,7 @@ def _stat_totals(base_t, qscales, axis_name, blocks_local, rows_per_block):
         tot = jnp.sum(base_t.astype(jnp.int32), axis=1) * qscales
     else:
         tot = jnp.sum(base_t, axis=1)
-    if axis_name is not None:
-        tot = lax.psum(tot, axis_name)
-    return tot
+    return _allreduce(tot, axis_name, "totals")
 
 
 def _soft_threshold(g, l1):
@@ -384,7 +414,7 @@ def _feature_best_gains(hist, fm, cfg):
     return jnp.max(jnp.where(ok, gain, NEG_INF), axis=-1)
 
 
-def _voting_select(h, feat_mask, cfg, axis_name, W):
+def _voting_select(h, feat_mask, cfg, axis_name, W, per):
     """voting_parallel feature selection (LightGBMParams.scala:13-27):
     each shard votes its top_k features by best local gain (max over the
     W frontier nodes), votes are psum'd, and only the global top-2k
@@ -401,11 +431,12 @@ def _voting_select(h, feat_mask, cfg, axis_name, W):
     # small nodes at deep levels) must not cast junk votes for the
     # arbitrary indices top_k returns
     ballots = (top_g > NEG_INF).astype(jnp.float32)
-    votes = lax.psum(jnp.zeros(F).at[local_top].add(ballots), axis_name)
+    votes = _allreduce(jnp.zeros(F).at[local_top].add(ballots), axis_name,
+                       "votes", per)
     # deterministic tie-break toward low feature index on every shard
     _, sel = lax.top_k(votes - jnp.arange(F) * 1e-6, min(2 * k, F))
     sel = jnp.sort(sel)
-    hsel = lax.psum(h[sel], axis_name)
+    hsel = _allreduce(h[sel], axis_name, "hist", per)
     hfull = jnp.zeros_like(h).at[sel].set(hsel)
     return hfull, jnp.zeros(F, dtype=bool).at[sel].set(True)
 
@@ -661,8 +692,9 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                         rpb)
 
     @jax.named_scope("gbdt_hist")
-    def all_hist(row_pos, W):
-        """Global per-node histogram [F, W*3, B] + selected-feature mask.
+    def all_hist(row_pos, W, per):
+        """Global per-node histogram [F, W*3, B] + selected-feature mask;
+        ``per``: how often the pass runs (:func:`_allreduce`).
 
         data_parallel: one full [F, W*3, B] psum — or, under hist_blocks,
         the canonical blocked fold (topology-independent f32 order).
@@ -672,14 +704,13 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         unselected features are masked)."""
         if bl:
             return (_blocked_node_hist(binned_t, row_pos, base_t, W, B,
-                                       qscales, bl, rpb, axis_name),
+                                       qscales, bl, rpb, axis_name, per),
                     jnp.ones(F, dtype=bool))
         h = node_histogram(binned_t, row_pos, base_t, W, B, scales=qscales)
-        if axis_name is None:
-            return h, jnp.ones(F, dtype=bool)
-        if not cfg.voting:
-            return lax.psum(h, axis_name), jnp.ones(F, dtype=bool)
-        return _voting_select(h, feat_mask, cfg, axis_name, W)
+        if axis_name is None or not cfg.voting:
+            return (_allreduce(h, axis_name, "hist", per),
+                    jnp.ones(F, dtype=bool))
+        return _voting_select(h, feat_mask, cfg, axis_name, W, per)
 
     # Leafwise histogram subtraction: every round's candidates already have
     # their own histograms cached in ``nhist`` (root from the root pass,
@@ -690,7 +721,7 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     use_sub = _use_subtraction(cfg, axis_name, n)
     h_buf = max(n // 2, 1)
 
-    root_hist, sel0 = all_hist(jnp.zeros(n, dtype=jnp.int32), 1)
+    root_hist, sel0 = all_hist(jnp.zeros(n, dtype=jnp.int32), 1, "tree")
     # totals from the raw stats (not the histogram: under voting_parallel an
     # unselected feature's rows are zeroed there). Quantized mode totals the
     # DEQUANTIZED stats so node stats stay consistent with histogram sums.
@@ -783,7 +814,7 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                     in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
                 ).astype(jnp.int32)
 
-            h, sel = all_hist(child_pos, W2)         # [F, W2*3, B]
+            h, sel = all_hist(child_pos, W2, "round")   # [F, W2*3, B]
             hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
 
         # child totals: left from the candidate cache, right = parent - left
@@ -890,11 +921,11 @@ def _renew_leaf_stats(state, grad, hess, vm, M: int, axis_name,
                 :, seg[j * rows_per_block:(j + 1) * rows_per_block]].add(
                 stats[:, j * rows_per_block:(j + 1) * rows_per_block])
             for j in range(blocks_local)])
-        renew = _blocked_fold(parts, axis_name)
+        renew = _blocked_fold(parts, axis_name, "renew")
     else:
-        renew = jnp.zeros((3, M), jnp.float32).at[:, seg].add(stats)
-        if axis_name is not None:
-            renew = lax.psum(renew, axis_name)
+        renew = _allreduce(
+            jnp.zeros((3, M), jnp.float32).at[:, seg].add(stats), axis_name,
+            "renew")
     for i, k in enumerate(("ng", "nh", "nc")):
         state[k] = jnp.where(state["is_leaf"], renew[i], state[k])
     return state
@@ -1058,22 +1089,22 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
                         # canonical blocked fold: topology-independent f32
                         # order
                         h = _blocked_node_hist(binned_t, row_pos, base_t, W,
-                                               B, qscales, bl, rpb, axis_name)
+                                               B, qscales, bl, rpb, axis_name,
+                                               "level")
                     else:
                         h = node_histogram(binned_t, row_pos, base_t, W, B,
                                            scales=qscales)     # [F, W*3, B]
-                        if axis_name is not None:
-                            if cfg.voting:
-                                # per-level voting: shards vote top_k
-                                # features by their best local gain across
-                                # the WHOLE frontier, then only the global
-                                # top-2k features' level histograms cross
-                                # the interconnect
-                                h, sel = _voting_select(h, feat_mask, cfg,
-                                                        axis_name, W)
-                                feat_mask_lvl = feat_mask & sel
-                            else:
-                                h = lax.psum(h, axis_name)
+                        if axis_name is not None and cfg.voting:
+                            # per-level voting: shards vote top_k features
+                            # by their best local gain across the WHOLE
+                            # frontier, then only the global top-2k
+                            # features' level histograms cross the
+                            # interconnect
+                            h, sel = _voting_select(h, feat_mask, cfg,
+                                                    axis_name, W, "level")
+                            feat_mask_lvl = feat_mask & sel
+                        else:
+                            h = _allreduce(h, axis_name, "hist", "level")
                 h = h.reshape(F, W, 3, B).transpose(1, 0, 2, 3)  # [W,F,3,B]
 
             tot = jnp.stack([tree_arrays["ng"][jnp.maximum(fr, 0)],

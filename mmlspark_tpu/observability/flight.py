@@ -250,8 +250,11 @@ def _run_dump_callbacks() -> None:
 def _on_signal(signum, frame) -> None:  # noqa: ARG001 — signal signature
     try:
         from . import logging as _logging  # lazy: logging imports flight
-        record("signal_dump", signum=int(signum))
-        path = dump()
+        # the marker and the snapshot in one step: writers on other threads
+        # can push the marker out of a small ring between the two
+        with _lock:
+            record("signal_dump", signum=int(signum))
+            path = dump()
         _run_dump_callbacks()
         _logging.console(f"[flight] dumped {len(events())} events to {path}",
                          err=True)

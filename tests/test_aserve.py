@@ -312,6 +312,9 @@ class TestContinuousBatching:
             for t in threads:
                 t.join(timeout=30)
             assert not errs, errs[:5]
+            # the scorer counts a batch after it has replied: the last
+            # client can be here before the last count
+            q.await_served(150, timeout=5)
             assert q.requests_served == 150
             # under 6 concurrent keep-alive clients batching must form
             assert q.batches_served < q.requests_served
