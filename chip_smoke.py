@@ -92,11 +92,21 @@ def _series(family: str) -> list:
         "series", [])
 
 
-def engine_counts() -> dict:
-    out = {"pallas": 0, "onehot": 0, "scatter": 0}
-    for s in _series("hist_engine_selected_total"):
-        out[s["labels"]["engine"]] += int(s["value"])
+def _label_counts(name: str, label: str, values: tuple) -> dict:
+    out = dict.fromkeys(values, 0)
+    for s in _series(name):
+        out[s["labels"][label]] += int(s["value"])
     return out
+
+
+def engine_counts() -> dict:
+    return _label_counts("hist_engine_selected_total", "engine",
+                         ("pallas", "onehot", "scatter"))
+
+
+def layout_counts() -> dict:
+    return _label_counts("hist_kernel_layout_total", "layout",
+                         ("folded", "plain"))
 
 
 # ---------------------------------------------------------------------------
@@ -201,9 +211,16 @@ def _kernel_variant(binned_t, pos, stats, scales, W, B, toy) -> None:
         return lowered, {k: v - before[k]
                          for k, v in engine_counts().items()}
 
+    layouts = layout_counts()
     lowered, picked = lower()
     if picked != {"pallas": 1, "onehot": 0, "scatter": 0}:
         raise RuntimeError(f"{tag}: engine selection {picked}, not pallas")
+    layouts = {k: v - layouts[k] for k, v in layout_counts().items()}
+    # the rule as ops/histogram.py states it: two 128-lane tiles of bins and
+    # both copies of the stats inside one 128-row operand
+    layout = "folded" if 128 < B <= 256 and 3 * W <= 64 else "plain"
+    if layouts != {"folded": 0, "plain": 0, layout: 1}:
+        raise RuntimeError(f"{tag}: kernel layout {layouts}, not {layout}")
     interpret = H._interpret_mode()
     mosaic = "tpu_custom_call" in lowered.as_text()
     if not toy and (interpret or not mosaic):
@@ -224,7 +241,7 @@ def _kernel_variant(binned_t, pos, stats, scales, W, B, toy) -> None:
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4, err_msg=tag)
     rb = H._pick_row_block(n, F, 3 * W, B, fused_w=W, quantized=quantized)
     print(f"kernel {tag}: engine=pallas interpret={interpret} "
-          f"mosaic={mosaic} row_block={rb} count_exact=True "
+          f"mosaic={mosaic} row_block={rb} layout={layout} count_exact=True "
           f"max_abs_err={np.abs(got - want).max():.3e}", flush=True)
 
 

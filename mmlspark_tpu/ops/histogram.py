@@ -21,7 +21,12 @@ bin-scatter into dense one-hot contractions that run on the systolic array:
 
 i.e. per feature a ``[S, n] @ [n, B]`` matmul with the one-hot bin matrix.
 Stats ride in bf16 (one-hot products are exact; values round at 2^-8 relative)
-and accumulate in f32 on the MXU.
+and accumulate in f32 on the MXU. The Pallas kernels run that contraction in
+one of two layouts, picked from the static shape (:func:`_fold_words`): the
+plain one above, or, at 129..256 bins with up to 64 stats rows, a FOLDED one
+that splits a bin into ``hi * 128 + lo``, one-hots ``lo`` alone and lets
+``hi`` pick one of two stacked copies of the stats — one MXU tile a feature
+instead of two, the same histogram cell for cell.
 
 Layout: everything here is **column-major** — ``binned_t`` is ``[F, n]`` and
 stats are ``[S, n]`` — so the Pallas grid slices the row axis (the 128-lane
@@ -165,16 +170,20 @@ def round_stats(stats: jnp.ndarray, dtype=jnp.bfloat16) -> jnp.ndarray:
                                 mantissa_bits=fi.nmant)
 
 
-def _note_engine(engine: str) -> None:
-    """hist_engine_selected_total{engine}: selections happen at trace time
-    (engine choice is static per compiled program), so the counter tracks
-    program builds, not per-batch executions."""
+def _count_build(name: str, **labels) -> None:
+    """Bump a counter of what a program was BUILT with: these choices are
+    static per compiled program and made at trace time, so the counters
+    track program builds, not per-batch executions."""
     try:
         from ..observability import metrics as _metrics
-        _metrics.safe_counter("hist_engine_selected_total",
-                              engine=engine).inc()
+        _metrics.safe_counter(name, **labels).inc()
     except Exception:  # noqa: BLE001 — telemetry must not fail the kernel
         pass
+
+
+def _note_engine(engine: str) -> None:
+    """hist_engine_selected_total{engine}: the engine a program took."""
+    _count_build("hist_engine_selected_total", engine=engine)
 
 
 def _select_engine(n: int, F: int, S: int, B: int, fused_w: int = 0,
@@ -422,8 +431,9 @@ def _hist_row_blocks(binned_t, stats_t, B, rows_per_block,
 # The kernels below keep the one-hot entirely in VMEM: grid (n/RB,), each
 # step builds a transposed [B, RB] one-hot in registers/VMEM per feature
 # (bins on sublanes, rows on lanes — no relayout of the lane-major bin row),
-# feeds the MXU with a lane-axis [S, RB] x [B, RB] contraction, and
-# accumulates the [S, B] block in
+# feeds the MXU with a lane-axis [S, RB] x [B, RB] contraction (folded
+# layout: [2S, RB] x [128, RB], see _fold_words), and accumulates the
+# [S, B] block in
 # the output block that stays resident across the row-block axis (classic
 # matmul accumulation pattern). Measured ~1.5 ms for the same shape — ~35x.
 # ---------------------------------------------------------------------------
@@ -478,6 +488,37 @@ def _bin_packing(B: int):
     return -(-B // 128) * 128, 1
 
 
+def _fold_words(B: int, S: int, itemsize: int) -> int:
+    """The layout rule, from static shapes alone: 0 keeps the plain
+    contraction ``[S, RB] x [BP, RB]``; ``k > 0`` picks the FOLDED one,
+    where ``k`` 32-bit words hold the ``S`` stats rows (4 int8 or 2 bf16
+    rows a word).
+
+    With 128 < B <= 256 the plain layout pushes two 128-lane one-hot tiles
+    a feature through the MXU, and on v5e building a tile costs the VPU more
+    than the MXU takes to use it. A bin is ``hi * 128 + lo``: the folded
+    layout builds ONE one-hot tile, of ``lo``, and moves ``hi`` to the stats
+    side — the stats rows stacked twice, each copy kept where ``hi`` is its
+    own — so ``sum_r A[(hi, s), r] * oh[lo, r] = hist[s, hi * 128 + lo]``,
+    cell for cell the plain histogram. It needs both copies inside one
+    128-row operand: ``S <= 64``, i.e. every leafwise pass up to
+    ``leaf_batch = 10`` (W <= 21) and every root. Packed features
+    (B <= 64), one-tile bins (B <= 128) and wider stats keep the plain
+    layout.
+    """
+    BP, P = _bin_packing(B)
+    if P != 1 or BP != 256:
+        return 0
+    k = -(-max(S, 1) // (4 // itemsize))
+    return k if _fold_rows(k, itemsize) <= 128 else 0
+
+
+def _fold_rows(k: int, itemsize: int) -> int:
+    """Rows of the folded stats operand (and of its accumulator block): two
+    copies of ``k`` words, padded to whole 8-sublane word tiles."""
+    return -(-2 * k // 8) * 8 * (4 // itemsize)
+
+
 def _pick_row_block(n: int, F: int, S: int, B: int, fused_w: int = 0,
                     quantized: bool = False) -> int:
     """Largest row-block size whose resident VMEM fits the budget.
@@ -498,34 +539,53 @@ def _pick_row_block(n: int, F: int, S: int, B: int, fused_w: int = 0,
     scoped at RB=8192 and 19.2 MB at RB=4096 for B=255/W<=16, i.e. ~8x the
     single-buffer model. Charge 8 one-hot buffers in that case so the chosen
     RB actually compiles on hardware.
+
+    The folded layout (:func:`_fold_words`) is modeled from what this
+    compiler allocates for it (libtpu 0.0.34, the smallest scoped limit
+    that compiles, F=39 uint8 bins): 2.25 MB at W=16 int8 RB=4096, 4.75 MB
+    at RB=8192, 6.0 MB at W=21, 3.25 MB at W=16 bf16 RB=8192, 1.0 MB at
+    W=1 — the input blocks and the rebuilt masked stats (the 36 B a row
+    and node that the fused terms below already charge for int8) and no
+    one-hot at all: the unrolled features' tiles live and die in vregs.
+    So it charges the stats stacked twice as 32-bit words and, for spills,
+    one one-hot tile and one folded operand, not eight.
     """
     BP, P = _bin_packing(B)
     Fp = -(-F // P) * P
     Sp = -(-max(S, 1) // 16) * 16
     elt = 4 if quantized else 2
     onehot_bufs = 8 if (Fp // P) <= _unroll_max() else 1
+    live = onehot_bufs * max(BP, 128) * elt         # bytes a row of RB
+    out_rows = Sp
+    itemsize = 1 if quantized else 2
+    fold_k = _fold_words(B, S, itemsize)
+    if fold_k:
+        out_rows, BP = _fold_rows(fold_k, itemsize), 128
+        live = (128 + out_rows) * elt + out_rows * itemsize
     for RB in (8192, 4096, 2048, 1024, 512):
         if RB > max(512, n):
             continue  # don't pad a small input up to a huge block
         binned_block = Fp * RB * 4
         if fused_w:
             in_blocks = binned_block + RB * 4 + 8 * RB * 4
-            scratch = (onehot_bufs * RB * max(BP, 128) * elt
+            scratch = (RB * live
                        + 2 * (fused_w * 3 * RB * elt) + Sp * RB * elt)
         else:
             in_blocks = binned_block + Sp * RB * 2
-            scratch = onehot_bufs * RB * max(BP, 128) * elt
-        out_block = Fp * Sp * BP * 4
+            scratch = RB * live
+        out_block = Fp * out_rows * BP * 4
         if 2 * in_blocks + out_block + scratch <= _vmem_budget():
             return RB
     return 0
 
 
-def _hist_dot_accumulate(o_ref, b_ref, sb, Fp: int, BP: int, P: int):
+def _hist_dot_accumulate(o_ref, b_ref, sb, Fp: int, BP: int, P: int,
+                         fold_k: int = 0):
     """Shared inner loop: per step, pack P features' one-hots into one
     128-lane dot with the [Sp, RB] stats and accumulate the [Sp, BP] slices
-    into their o_ref rows. int8 stats accumulate in int32 (the 2x-rate MXU
-    path); bf16 in f32.
+    into their o_ref rows (``fold_k``: the folded layout's one tile a
+    feature, see :func:`_fold_words`). int8 stats accumulate in int32 (the
+    2x-rate MXU path); bf16 in f32.
 
     The feature loop is a static Python unroll, NOT lax.fori_loop: the
     dynamically-indexed loop measured ~3-5 us of scalar-core overhead per
@@ -536,17 +596,26 @@ def _hist_dot_accumulate(o_ref, b_ref, sb, Fp: int, BP: int, P: int):
     linear-in-F compile time/program size for a sub-us-per-step win.
     """
     acc = jnp.int32 if sb.dtype == jnp.int8 else jnp.float32
+    if fold_k:
+        words = _stack_stats_words(sb, fold_k, o_ref.shape[1])
+        dtype = sb.dtype
+
+        def group_dot(g):
+            _hist_fold_dot(o_ref, b_ref, words, g, fold_k, dtype, acc)
+    else:
+        def group_dot(g):
+            _hist_group_dot(o_ref, b_ref, sb, g, BP, P, acc)
 
     groups = Fp // P
     if groups > _unroll_max():
         def body(g, _):
-            _hist_group_dot(o_ref, b_ref, sb, g, BP, P, acc)
+            group_dot(g)
             return 0
 
         lax.fori_loop(0, groups, body, 0)
         return
     for g in range(groups):
-        _hist_group_dot(o_ref, b_ref, sb, g, BP, P, acc)
+        group_dot(g)
 
 
 _UNROLL_MAX = 128
@@ -599,7 +668,75 @@ def _hist_group_dot(o_ref, b_ref, sb, g, BP: int, P: int, acc):
             o_ref[g * P + p] += h[:, p * BP:(p + 1) * BP]
 
 
-def _make_hist_kernel(Fp: int, BP: int, P: int):
+def _stack_stats_words(sb, k: int, rows: int):
+    """Once a row block: the [k * pack, RB] stats as ``k`` packed 32-bit
+    words a row, stacked twice (words 0..k-1 for ``hi == 0``, k..2k-1 for
+    ``hi == 1``) and zero-padded to ``rows // pack`` words. Everything the
+    fold does per feature then runs on words: a quarter (int8) or half
+    (bf16) of the vregs that the same select costs on the rows."""
+    w = pltpu.bitcast(sb, jnp.int32)                # [k, RB]
+    parts = [w, w]
+    pad = rows * sb.dtype.itemsize // 4 - 2 * k
+    if pad:
+        parts.append(jnp.zeros((pad, w.shape[1]), jnp.int32))
+    return jnp.concatenate(parts, axis=0)
+
+
+def _onehot_lo(lo, dtype):
+    """Transposed one-hot ``[128, RB]`` of ``lo`` in [0, 128).
+
+    int8 builds it on packed words, four bins a word: with both operands
+    under 128, a byte of ``0x80808080 - (lo4 ^ bins4)`` keeps its top bit
+    exactly where bin and row agree and no borrow crosses a byte, so a tile
+    costs 4 VALU operations a vreg (xor, sub, shift, and) where the int32
+    compare, select and two packs cost 13. The words' bins are read back
+    off :func:`pltpu.bitcast`'s own layout (word i, byte b = row 4 i + b).
+    bf16 gains nothing that way (5 operations either way) and compares."""
+    if dtype != jnp.int8:
+        bins = lax.broadcasted_iota(jnp.int32, (128, lo.shape[0]), 0)
+        return (lo[None, :] == bins).astype(dtype)
+    word = lax.broadcasted_iota(jnp.int32, (32, lo.shape[0]), 0)
+    bins4 = word * 0x04040404 + 0x03020100
+    lo4 = (lo * 0x01010101)[None, :]
+    hit = jnp.int32(-0x7F7F7F80) - (lo4 ^ bins4)    # 0x80808080 - x
+    return pltpu.bitcast(lax.shift_right_logical(hit, 7) & 0x01010101,
+                         jnp.int8)
+
+
+def _hist_fold_dot(o_ref, b_ref, words, g, k: int, dtype, acc):
+    """One feature of the folded layout: one 128-bin one-hot tile, the
+    bin's high bit selecting which stacked copy of the stats is live."""
+    row = b_ref[g, :].astype(jnp.int32)             # [RB], rows on lanes
+    copy = (lax.broadcasted_iota(jnp.int32, words.shape, 0) >= k
+            ).astype(jnp.int32)
+    a = jnp.where(copy == (row >> 7)[None, :], words, 0)
+    h = lax.dot_general(pltpu.bitcast(a, dtype), _onehot_lo(row & 127, dtype),
+                        (((1,), (1,)), ((), ())), preferred_element_type=acc)
+    o_ref[g] += h
+
+
+def _stage_layout(B: int, S: int, Fp: int, itemsize: int):
+    """(fold_k, stats rows Sp, accumulator block) of a kernel being staged
+    out, counted in hist_kernel_layout_total."""
+    fold_k = _fold_words(B, S, itemsize)
+    _count_build("hist_kernel_layout_total",
+                 layout="folded" if fold_k else "plain")
+    if fold_k:
+        return (fold_k, fold_k * 4 // itemsize,
+                (Fp, _fold_rows(fold_k, itemsize), 128))
+    Sp = -(-S // 16) * 16                          # pad stats to sublane tile
+    return 0, Sp, (Fp, Sp, _bin_packing(B)[0])
+
+
+def _to_hist(out, F: int, S: int, B: int, fold_k: int, Sp: int):
+    """The accumulator block as ``[F, S, B]``; a folded one's two copies of
+    the stats rows, put side by side, are the bins' two halves."""
+    if not fold_k:
+        return out[:F, :S, :B]
+    return jnp.concatenate([out[:F, :S], out[:F, Sp:Sp + S]], axis=2)[:, :, :B]
+
+
+def _make_hist_kernel(Fp: int, BP: int, P: int, fold_k: int = 0):
     def kernel(b_ref, s_ref, o_ref):
         j = pl.program_id(0)
         sb = s_ref[:, :]                            # [Sp, RB] bf16
@@ -608,13 +745,13 @@ def _make_hist_kernel(Fp: int, BP: int, P: int):
         def _():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        _hist_dot_accumulate(o_ref, b_ref, sb, Fp, BP, P)
+        _hist_dot_accumulate(o_ref, b_ref, sb, Fp, BP, P, fold_k)
 
     return kernel
 
 
 def _make_node_hist_kernel(Fp: int, W: int, Sp: int, BP: int, P: int,
-                           quantized: bool = False):
+                           quantized: bool = False, fold_k: int = 0):
     def kernel(b_ref, p_ref, base_ref, o_ref):
         j = pl.program_id(0)
         pos = p_ref[0, :]                           # [RB] int32
@@ -635,7 +772,7 @@ def _make_node_hist_kernel(Fp: int, W: int, Sp: int, BP: int, P: int,
         def _():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        _hist_dot_accumulate(o_ref, b_ref, sb, Fp, BP, P)
+        _hist_dot_accumulate(o_ref, b_ref, sb, Fp, BP, P, fold_k)
 
     return kernel
 
@@ -664,7 +801,7 @@ def _hist_pallas(binned_t: jnp.ndarray, stats_t: jnp.ndarray,
     B = int(num_bins)
     BP, P = _bin_packing(B)
     Fp = -(-F // P) * P
-    Sp = -(-S // 16) * 16                          # pad stats to sublane tile
+    fold_k, Sp, out_block = _stage_layout(B, S, Fp, stats_t.dtype.itemsize)
     RB = _pick_row_block(n, F, S, B)
     n_pad = -(-max(n, RB) // RB) * RB
     # zero stats on padding rows: they contribute nothing to any bin
@@ -675,19 +812,19 @@ def _hist_pallas(binned_t: jnp.ndarray, stats_t: jnp.ndarray,
     nb = n_pad // RB
 
     out = pl.pallas_call(
-        _make_hist_kernel(Fp, BP, P),
+        _make_hist_kernel(Fp, BP, P, fold_k),
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((Fp, RB), lambda j: (0, j)),
             pl.BlockSpec((Sp, RB), lambda j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((Fp, Sp, BP), lambda j: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Fp, Sp, BP), jnp.float32),
+        out_specs=pl.BlockSpec(out_block, lambda j: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(out_block, jnp.float32),
         interpret=_interpret_mode(),
         compiler_params=_compiler_params(),
         name="gbdt_hist_kernel",
     )(binned_t, stats_t)
-    return out[:F, :S, :B]
+    return _to_hist(out, F, S, B, fold_k, Sp)
 
 
 def _node_hist_pallas(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
@@ -697,7 +834,7 @@ def _node_hist_pallas(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
     S = 3 * W
     BP, P = _bin_packing(B)
     Fp = -(-F // P) * P
-    Sp = -(-S // 16) * 16
+    fold_k, Sp, out_block = _stage_layout(B, S, Fp, 1 if quantized else 2)
     RB = _pick_row_block(n, F, S, B, fused_w=W, quantized=quantized)
     n_pad = -(-max(n, RB) // RB) * RB
     binned_t = _pad_features_to(_pad_rows_to(binned_t, n_pad), Fp)
@@ -711,17 +848,17 @@ def _node_hist_pallas(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
     out_dtype = jnp.int32 if quantized else jnp.float32
 
     out = pl.pallas_call(
-        _make_node_hist_kernel(Fp, W, Sp, BP, P, quantized),
+        _make_node_hist_kernel(Fp, W, Sp, BP, P, quantized, fold_k),
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((Fp, RB), lambda j: (0, j)),
             pl.BlockSpec((1, RB), lambda j: (0, j)),
             pl.BlockSpec((8, RB), lambda j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((Fp, Sp, BP), lambda j: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Fp, Sp, BP), out_dtype),
+        out_specs=pl.BlockSpec(out_block, lambda j: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(out_block, out_dtype),
         interpret=_interpret_mode(),
         compiler_params=_compiler_params(),
         name="gbdt_node_hist_kernel",
     )(binned_t, row_pos, base8)
-    return out[:F, :S, :B]
+    return _to_hist(out, F, S, B, fold_k, Sp)

@@ -128,6 +128,100 @@ class TestPallasInterpret:
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
+    # the folded layout's gate (ops/histogram.py::_fold_words): two
+    # 128-lane tiles of bins and both copies of the stats in 128 rows
+    LAYOUTS = [(255, 1, "folded"), (255, 16, "folded"), (255, 21, "folded"),
+               (129, 16, "folded"), (255, 22, "plain"), (255, 31, "plain"),
+               (128, 16, "plain"), (63, 16, "plain")]
+
+    @staticmethod
+    def _layout_case(B, W, bins, n=1100, F=6):
+        """Bins at the fold's seams (127 | 128, the last bin) and a column
+        that is one bin throughout, beside uniform ones."""
+        rng = np.random.default_rng(B * 100 + W)
+        b = rng.integers(0, B, size=(F, n), dtype=np.int32)
+        b[0, :] = min(128, B - 1)
+        b[1, :n // 3] = min(127, B - 1)
+        b[1, n // 3:2 * n // 3] = min(128, B - 1)
+        b[1, 2 * n // 3:] = B - 1                    # 254 at 255 bins
+        pos = rng.integers(-1, W, size=n).astype(np.int32)
+        grad = rng.normal(size=n).astype(np.float32)
+        mask = (rng.uniform(size=n) < 0.9).astype(np.float32)
+        base = np.stack([grad * mask, np.abs(grad) * mask, mask])
+        return (jnp.asarray(b.astype(bins)), jnp.asarray(pos),
+                jnp.asarray(base))
+
+    @pytest.mark.parametrize("bins", ["uint8", "int32"])
+    @pytest.mark.parametrize("stats", ["int8", "bf16"])
+    @pytest.mark.parametrize("B,W,layout", LAYOUTS)
+    def test_node_kernel_layouts_match_scatter(self, B, W, layout, stats,
+                                               bins, monkeypatch):
+        """Either layout returns the scatter engine's histogram: int8 to
+        the bit, bf16 to tests/test_histogram_engines.py's tolerance with
+        the count channel exact."""
+        binned_t, pos, base = self._layout_case(B, W, bins)
+        stats_t, scales = (quantize_stats(base) if stats == "int8"
+                           else (base, None))
+        got = np.asarray(node_histogram(binned_t, pos, stats_t, W, B,
+                                        scales=scales))
+        monkeypatch.delenv("MMLSPARK_TPU_PALLAS_INTERPRET")
+        monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "scatter")
+        want = np.asarray(node_histogram(binned_t, pos, stats_t, W, B,
+                                         scales=scales))
+        assert got.shape == (binned_t.shape[0], 3 * W, B)
+        if stats == "int8":
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_array_equal(got[:, 2::3, :], want[:, 2::3, :])
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("stats", ["int8", "bf16"])
+    @pytest.mark.parametrize("B,W,layout", LAYOUTS)
+    def test_layout_counter_follows_the_gate(self, B, W, layout, stats):
+        """hist_kernel_layout_total{layout} says which layout a staged-out
+        kernel took: a function of (B, S, dtype) and nothing else."""
+        import jax
+
+        from mmlspark_tpu.observability import metrics
+
+        def counts():
+            return {k: metrics.counter("hist_kernel_layout_total",
+                                       layout=k).value
+                    for k in ("folded", "plain")}
+
+        binned_t, pos, base = self._layout_case(B, W, "uint8", n=512, F=2)
+        stats_t, scales = (quantize_stats(base) if stats == "int8"
+                           else (base, None))
+        before = counts()
+        jax.jit(lambda b, p, s: node_histogram(
+            b, p, s, W, B, scales=scales)).lower(binned_t, pos, stats_t)
+        delta = {k: v - before[k] for k, v in counts().items()}
+        assert delta == {"folded": 0, "plain": 0, layout: 1}
+
+    @pytest.mark.parametrize("B,S,layout", [(255, 6, "folded"),
+                                            (200, 64, "folded"),
+                                            (255, 66, "plain")])
+    def test_cols_kernel_layouts_match_scatter(self, B, S, layout,
+                                               monkeypatch):
+        """histogram_cols (gbdt_hist_kernel) takes the same gate."""
+        from mmlspark_tpu.observability import metrics
+        rng = np.random.default_rng(S)
+        n, F = 1200, 4
+        b = rng.integers(0, B, size=(F, n), dtype=np.int32)
+        b[0, :] = 128
+        b[1, :n // 2], b[1, n // 2:] = 127, B - 1
+        binned_t = jnp.asarray(b)
+        stats_t = jnp.asarray(rng.normal(size=(S, n)).astype(np.float32))
+        taken = metrics.counter("hist_kernel_layout_total", layout=layout)
+        before = taken.value
+        got = np.asarray(histogram_cols(binned_t, stats_t, B))
+        assert taken.value == before + 1
+        monkeypatch.delenv("MMLSPARK_TPU_PALLAS_INTERPRET")
+        monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "scatter")
+        want = np.asarray(histogram_cols(binned_t, stats_t, B))
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
 class TestNarrowBinStorage:
     """uint8/int16 bin-id storage (the Criteo-scale HBM lever): the Pallas
     kernels widen per block in VMEM, so results must be bit-identical to
@@ -348,10 +442,17 @@ def test_vmem_picker_fits_bench_shapes_at_leafbatch_width():
     would be ~10x slower and invisible on CPU."""
     from mmlspark_tpu.ops.histogram import _pick_row_block
 
-    for B in (255, 63):
-        for W in (1, 2, 16, 31):
-            rb = _pick_row_block(1_000_000, 28, 3 * W, B, fused_w=W)
-            assert rb > 0, (B, W)
-            rbq = _pick_row_block(1_000_000, 28, 3 * W, B, fused_w=W,
-                                  quantized=True)
-            assert rbq > 0, ("quantized", B, W)
+    # the smoke's 1 M x 28 and the benchmark cells' 68,321,280 x 39
+    # (BENCHMARK.json: one chip's share of Criteo-1TB, uint8 bins)
+    for n, F, widths in ((1_000_000, 28, (1, 2, 16, 31)),
+                         (68_321_280, 39, (1, 16))):
+        for B in (255, 63):
+            for W in widths:
+                rb = _pick_row_block(n, F, 3 * W, B, fused_w=W)
+                assert rb > 0, (n, B, W)
+                rbq = _pick_row_block(n, F, 3 * W, B, fused_w=W,
+                                      quantized=True)
+                assert rbq > 0, ("quantized", n, B, W)
+                # a pass is 16,680 or 8,340 grid steps at the cells' size:
+                # nothing smaller than 4096 rows a step belongs there
+                assert min(rb, rbq) >= 4096, (n, B, W, rb, rbq)

@@ -151,7 +151,10 @@ class TestCrossEngineEquivalence:
         np.testing.assert_array_equal(got, got_rm)
 
     @pytest.mark.parametrize("engine", ENGINES)
-    @pytest.mark.parametrize("B,W", [(255, 3), (63, 16), (31, 2)])
+    # (255, 3), (255, 16): the pallas kernel's folded layout; (255, 22): the
+    # first width past it (ops/histogram.py::_fold_words)
+    @pytest.mark.parametrize("B,W", [(255, 3), (63, 16), (31, 2), (255, 16),
+                                     (255, 22)])
     def test_node_histogram_cross_engine(self, engine, B, W, monkeypatch):
         # count channel must be exact; grad/hess to f32 tolerance
         rng = np.random.default_rng(1)
@@ -173,11 +176,14 @@ class TestCrossEngineEquivalence:
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
     @pytest.mark.parametrize("engine", ENGINES)
-    def test_node_histogram_quantized_exact(self, engine, monkeypatch):
+    @pytest.mark.parametrize("B,W", [(63, 4), (255, 16), (255, 21),
+                                     (129, 16), (255, 22)])
+    def test_node_histogram_quantized_exact(self, engine, B, W, monkeypatch):
         # int8 stats accumulate in int32 on every engine — exact equality
-        # after dequantization
+        # after dequantization, in the pallas kernel's plain layout and in
+        # its folded one (255 and 129 bins up to W = 21)
         rng = np.random.default_rng(2)
-        n, F, B, W = 1100, 5, 63, 4
+        n, F = 1100, 5
         binned_t = jnp.asarray(rng.integers(0, B, size=(F, n),
                                             dtype=np.int32))
         pos = jnp.asarray(rng.integers(-1, W, size=n).astype(np.int32))
