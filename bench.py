@@ -88,7 +88,7 @@ def _dump_metrics_snapshot(leg: str, wall_start: float = 0.0) -> None:
         payload["slo"] = _obs_slo.snapshot_payload()
         payload["tail"] = _obs_tail.snapshot_payload()
         # auto-tuner provenance: which knobs were measured-resolved (and
-        # from where — calibration vs store vs pinned) during this leg,
+        # from where — measured vs store vs pinned) during this leg,
         # so an A/B round is attributable to tuning rather than noise
         from mmlspark_tpu import tuning as _tuning
         payload["tuning"] = _tuning.provenance()
@@ -318,8 +318,7 @@ def _run_leg(on_tpu: bool) -> None:
         return round(sec_iters / best, 3)
 
     leafwise_tps = _rate(ds, cfg_over=dict(growth_policy="leafwise"))
-    # leafwise with int8 quantized grads (subtraction resolves off on TPU:
-    # full-width one-hot passes stay on the MXU)
+    # leafwise with int8 quantized grads
     leafwise_best_tps = _rate(ds, cfg_over=dict(
         growth_policy="leafwise", quantized_grad=True))
     leafwise_best63_tps = _rate(ds63, cfg_over=dict(

@@ -58,10 +58,6 @@ def _eligible(est, param_maps: List[Dict[str, Any]]) -> bool:
         return False
     if g("useQuantizedGrad"):
         return False
-    # histSubtraction is NOT gated here: it is tri-state ("auto" default,
-    # resolved per backend) and only ENGAGES above the growth layer's row
-    # threshold — swept_fit applies that engagement rule once the row
-    # count is known, so default-config sweeps keep the vmapped fast path
     if g("earlyStoppingRound") > 0 or g("isProvideTrainingMetric"):
         return False
     if g("modelString") or g("checkpointDir") or g("initScoreCol"):
@@ -121,18 +117,7 @@ def swept_fit(est, param_maps: List[Dict[str, Any]],
     if not _eligible(est, param_maps):
         return None
     X, y, w = est._extract_arrays(train)
-    base_cfg: GrowConfig = est._grow_config()   # "auto" already resolved
-    # subtraction would actually engage inside the trials (single-device
-    # rule, resolved config): fall back to sequential fits so the sweep
-    # takes exactly the code path — and the memory profile — a plain
-    # est.fit() would. The engagement row count is the PADDED dataset size
-    # (trials grow on replicated padded rows, not len(y)); below the
-    # threshold the resolved flag is inert and the envelope is unchanged.
-    from ..models.gbdt.growth import _use_subtraction
-    nshards = meshlib.num_shards(meshlib.get_default_mesh())
-    n_pad = -(-len(y) // nshards) * nshards
-    if _use_subtraction(base_cfg, None, n_pad):
-        return None
+    base_cfg: GrowConfig = est._grow_config()
     objinfo = _objective_of(est, y)
     if objinfo is None:
         return None

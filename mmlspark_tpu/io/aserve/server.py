@@ -138,7 +138,7 @@ class AsyncServingServer:
             self.slot_table = SlotTable(self.slots, row_spec.width,
                                         row_spec.dtype,
                                         quantizer=row_spec.quantizer)
-        # tuning evidence: the geometry the slot-sizing decision (site 4)
+        # tuning evidence: the geometry the slot-sizing decision (site 3)
         # reconciles against the aserve_slots HBM claim headroom
         if row_bytes:
             _tuning.note_slot_geometry(row_bytes, self.slots)
@@ -394,7 +394,7 @@ class AsyncServingServer:
 
     # -- batch take (scoring thread) ---------------------------------------
     def _hold_forming(self, hold: float) -> None:
-        """Tuning site 3 (dispatch pacing): keep the forming buffer open
+        """Tuning site 2 (dispatch pacing): keep the forming buffer open
         up to ``hold`` seconds past its first arrival so a memory-bound,
         under-occupied score stage dispatches fuller batches — the extra
         rows ride the same HBM sweep. Exits early the moment the buffer

@@ -45,7 +45,7 @@ def resolve_slots(max_batch: int, row_bytes: "int | None" = None) -> int:
     ladder is exact.
 
     ``MMLSPARK_TPU_ASERVE_SLOTS=auto`` asks the auto-tuner (tuning
-    site 4) for the measured size — the p99.9 of observed admitted-batch
+    site 3) for the measured size — the p99.9 of observed admitted-batch
     rows reconciled against the ``aserve_slots`` HBM claim headroom. A
     first process with no measured decision sizes statically (the
     untuned rule); the raw-string check matters because ``env_int``

@@ -942,7 +942,7 @@ def make_reply(entity: Any, status_code: int = 200) -> Dict[str, Any]:
 def bucket_size(n: int, max_batch: int) -> int:
     """Smallest bucket >= n (capped): static shapes under jit, so the
     compiled program cache holds a bounded set of entries, not one per
-    size. Consults the auto-tuner's measured ladder (tuning site 2) when
+    size. Consults the auto-tuner's measured ladder (tuning site 1) when
     one is decided — the SAME resolution ``Booster.predict_plan`` does,
     so the batcher and the predictor cache key can never disagree on
     rung geometry — else the static pow2 grid."""
@@ -1119,7 +1119,7 @@ class ServingQuery:
             _metrics.safe_histogram("serving_batch_size", api=api,
                                     buckets=_BATCH_SIZE_BUCKETS).observe(
                 len(batch))
-            # tuning evidence (site 2): the batch-size histogram the
+            # tuning evidence (site 1): the batch-size histogram the
             # measured bucket ladder derives from — fed by BOTH engines
             _tuning.observe_batch_size(len(batch))
             ds = requests_to_dataset(batch)

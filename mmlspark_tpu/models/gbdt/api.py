@@ -28,7 +28,7 @@ from ...observability import metrics as _metrics
 from ...observability import spans as _spans
 from ...observability import watchdog as _watchdog
 from .booster import Booster, LightGBMDataset, _densify, train_booster
-from .growth import GrowConfig, resolve_growth_backend
+from .growth import GrowConfig
 
 # Bounded cache of pre-binned device datasets keyed by a CONTENT fingerprint
 # of the training arrays + every binning-relevant param. Hyperparameter
@@ -42,15 +42,6 @@ from collections import OrderedDict
 
 _BINNED_CACHE: "OrderedDict" = OrderedDict()
 _BINNED_CACHE_MAX = 2
-
-
-def _to_tristate_bool(v):
-    """Param converter for True | False | "auto": keeps the sentinel,
-    coerces everything else exactly like ``TypeConverters.to_bool`` (so
-    1/0/'true'/'false' inputs keep working across the tri-state change)."""
-    if isinstance(v, str) and v.strip().lower() == "auto":
-        return "auto"
-    return TypeConverters.to_bool(v)
 
 
 def _dataset_nbytes(ds) -> float:
@@ -255,20 +246,6 @@ class _LightGBMParams(HasLabelCol, HasFeaturesCol, HasWeightCol, HasInitScoreCol
         "HBM-resident dataset 2x/4x — the lever that fits Criteo-scale "
         "data on a pod (docs/performance.md)", "int32",
         TypeConverters.to_string)
-    histSubtraction = Param(
-        "histSubtraction", "Parent-minus-sibling histogram subtraction "
-        "(LightGBM's constant-time trick, here as smaller-child row "
-        "compaction — bounds per-pass histogram rows at n/2). Single-device "
-        "fits only; sharded fits keep full-width passes regardless. "
-        "True | False | 'auto' (default): auto engages it on non-TPU "
-        "backends, where halving histogram rows is a measured win, and "
-        "keeps full-width MXU passes on TPU",
-        "auto", _to_tristate_bool)
-    compactSelector = Param(
-        "compactSelector", "Row-compaction selector for histSubtraction: "
-        "argsort (one stable sort), searchsorted (cumsum + binary search) "
-        "or 'auto' (default: argsort on TPU, searchsorted elsewhere)",
-        "auto", TypeConverters.to_string)
     categoricalSlotNames = Param(
         "categoricalSlotNames", "Categorical slots by feature name; requires "
         "a featuresCol with slot names (use categoricalSlotIndexes for "
@@ -322,12 +299,7 @@ class _LightGBMParams(HasLabelCol, HasFeaturesCol, HasWeightCol, HasInitScoreCol
         "on input partitioning", True, TypeConverters.to_bool)
 
     def _grow_config(self) -> GrowConfig:
-        # resolved ("auto" -> concrete per backend) BEFORE the config can
-        # reach any compiled-program cache key — train_booster re-resolves
-        # defensively, but the sweep path consumes this config directly.
-        # The resolver also owns compact_selector/hist_subtraction value
-        # validation (one error message, one allowed-values list).
-        return resolve_growth_backend(GrowConfig(
+        return GrowConfig(
             num_leaves=self.get_or_default("numLeaves"),
             max_depth=self.get_or_default("maxDepth"),
             num_bins=self.get_or_default("maxBin"),
@@ -344,10 +316,8 @@ class _LightGBMParams(HasLabelCol, HasFeaturesCol, HasWeightCol, HasInitScoreCol
             quantized_grad=self.get_or_default("useQuantizedGrad"),
             quant_renew_leaf=self.get_or_default("quantRenewLeaf"),
             quant_warmup_iters=self.get_or_default("quantWarmupIters"),
-            hist_subtraction=self.get_or_default("histSubtraction"),
-            compact_selector=self.get_or_default("compactSelector"),
             max_delta_step=self.get_or_default("maxDeltaStep"),
-        ))
+        )
 
     def _extract_arrays(self, dataset: Dataset):
         fcol = self.get_or_default("featuresCol")

@@ -46,7 +46,7 @@ from ...parallel.placement import pspec as P
 from . import quantize as _quantize
 from .growth import (GrowConfig, Tree, bitset_words, grow_tree,
                      grow_tree_depthwise, predict_forest_raw,
-                     predict_tree_binned, resolve_growth_backend)
+                     predict_tree_binned)
 from .objectives import (HIGHER_IS_BETTER, Objective, eval_metric,
                          get_objective, score_transform)
 
@@ -1001,7 +1001,7 @@ class Booster:
         # sizes hit a bounded set of cached executables instead of one
         # trace per size. The bucket ladder is resolved HERE, before the
         # cache key below (the PR 4 rule, lint-anchored): the auto-tuner's
-        # measured ladder (tuning site 2 — rungs at the observed
+        # measured ladder (tuning site 1 — rungs at the observed
         # workload's batch-size percentiles, pow2 above them) when one is
         # decided, else the static pow2 grid. Large batch scoring keeps
         # its exact shape — padding 600k rows to 1M would waste up to 2x
@@ -1547,9 +1547,8 @@ def _grow_with_warmup(grow, it_scalar, cfg, qk, binned_t, grad_k, hess_k,
 
 
 def _grow_axis_for(mesh, cfg) -> "str | None":
-    """Collective axis for tree growth: None on a single-shard data axis so
-    depthwise histogram subtraction (single-device only) can engage — psum
-    over a size-1 axis is the identity it replaces. Voting keeps the axis
+    """Collective axis for tree growth: None on a single-shard data axis,
+    where a psum would be the identity. Voting keeps the axis
     even at size 1: its top-2k ballot restricts the split search and must
     behave identically regardless of shard count — and so does a resolved
     hist_blocks (the deterministic blocked reduction must run the SAME
@@ -1558,32 +1557,6 @@ def _grow_axis_for(mesh, cfg) -> "str | None":
     return ("data" if (dict(mesh.shape).get("data", 1) > 1 or cfg.voting
                        or det)
             else None)
-
-
-def _measure_hist_engine(engine: str, binned_d, stats_d,
-                         num_bins: int) -> float:
-    """One measured histogram round for the auto-tuner's engine
-    calibration: compile + warm, then time a single steady-state
-    execution of ``histogram_cols`` under the candidate engine. Runs a
-    standalone jit over an unsharded, undonated calibration slice — the
-    full step program (sharded, donated buffers) is never replayed here,
-    and the hint is always restored before returning."""
-    from ...ops import histogram as _hist
-    _hist.set_tuned_engine(engine)
-    try:
-        fn = jax.jit(lambda b, s: _hist.histogram_cols(b, s, num_bins))
-        jax.block_until_ready(fn(binned_d, stats_d))
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(binned_d, stats_d))
-        return time.perf_counter() - t0
-    finally:
-        _hist.set_tuned_engine("")
-
-
-#: row cap for the calibration slice: large enough that engine ranking
-#: matches full-dataset behavior, small enough that calibration stays a
-#: negligible fraction of the first fit
-_HIST_CAL_ROWS = 16384
 
 
 @_in_fit_span
@@ -1656,12 +1629,7 @@ def train_booster(
     # each fit starts with clean training-health sentinel windows — a
     # diverging fit yesterday must not poison today's gauge
     _watchdog.reset_training_health("gbdt")
-    # resolve backend-adaptive tri-states ("auto" hist_subtraction /
-    # compact_selector) to concrete values up front: cfg flows into the
-    # checkpoint fingerprint and every compiled-program cache key below,
-    # and an unresolved sentinel there would alias programs across
-    # backends (lint-pinned in tests/test_lint.py)
-    cfg = resolve_growth_backend(cfg or GrowConfig())
+    cfg = cfg or GrowConfig()
     if dataset is not None and checkpoint_dir is not None:
         raise ValueError(
             "checkpointDir requires raw X/y arrays (the resume fingerprint "
@@ -1879,34 +1847,6 @@ def train_booster(
     cfg = cfg._replace(hist_blocks=placement.resolve_hist_blocks(
         cfg.hist_blocks, mesh, n_pad, voting=cfg.voting))
     deterministic = isinstance(cfg.hist_blocks, int) and cfg.hist_blocks > 1
-
-    # auto-tuned histogram engine (tuning site 1) — resolved HERE, before
-    # the compiled-program cache key below, because the hint flows into
-    # that key through resolve_engine(). Only `auto` consults the tuner
-    # (an explicit MMLSPARK_TPU_HIST_ENGINE pin is the opt-out); the
-    # first tuned fit of a shape bucket calibrates each candidate engine
-    # with one real histogram round over a slice of this dataset's own
-    # binned columns, later fits/processes answer from the store.
-    from ...ops import histogram as _hist
-    _hist_env = (os.environ.get("MMLSPARK_TPU_HIST_ENGINE")
-                 or "auto").strip().lower()
-    if _tuning.enabled() and _hist_env in ("auto", ""):
-        _cal: Dict[str, tuple] = {}
-
-        def _measure(eng: str) -> float:
-            if "data" not in _cal:
-                rows = int(min(n_pad, _HIST_CAL_ROWS))
-                # gather once, share across candidates; unsharded (the
-                # calibration program must not depend on the mesh)
-                xbt = np.asarray(placement.to_host(Xbt_d))[:, :rows]
-                _cal["data"] = (placement.to_device(np.ascontiguousarray(xbt)),
-                                placement.to_device(
-                                    np.ones((2, rows), np.float32)))
-            return _measure_hist_engine(eng, *_cal["data"], max_bin)
-
-        _hist.set_tuned_engine(_tuning.resolve_hist_engine(
-            n_pad, F, max_bin, _hist.engine_candidates(),
-            measure=_measure) or "")
 
     # base score (replicated scalar per class). Computed on device from the
     # already-sharded label/weight arrays, then broadcast to the initial

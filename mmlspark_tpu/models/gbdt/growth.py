@@ -64,11 +64,8 @@ class GrowConfig(NamedTuple):
     # runs out mid-batch and a child's gain would have outranked a pending
     # leaf's. leaf_batch=1 is exact sequential best-first (LightGBM order);
     # the default trades that tail-order nuance for ~4-5x fewer passes.
-    # The histogram pass cost here is flat in the node axis (the one-hot
-    # matmul scans all rows regardless of node sizes), so subtraction alone
-    # would not reduce pass cost — batching cuts the PASS COUNT, and
-    # ``hist_subtraction`` additionally cuts per-pass cost by compacting the
-    # smaller children's rows into a half-width buffer.
+    # A histogram pass scans all rows whatever the nodes hold, so its cost
+    # is flat in the node axis: batching cuts the PASS COUNT.
     # Caveat under voting_parallel: the top-2k feature ballot then spans the
     # whole batch's children (one vote per pass, like depthwise's
     # frontier-wide vote) rather than one split's two children, so voting
@@ -112,33 +109,6 @@ class GrowConfig(NamedTuple):
     # to +-this; 0 disables. Stabilizes extreme leaf values (LightGBM
     # recommends it for poisson / highly imbalanced binary).
     max_delta_step: float = 0.0
-    # Histogram subtraction (LightGBM's parent-minus-sibling trick, made
-    # profitable on TPU by row compaction), honored by BOTH growth policies:
-    # gather the rows of each sibling pair's SMALLER child — at most n//2
-    # rows in total, guaranteed — into a half-width buffer, build only those
-    # children's histograms, and derive each larger sibling as parent minus
-    # smaller. Depthwise engages from level 1 (the previous level's
-    # histograms are the parents); leafwise caches every node's histogram
-    # so every round subtracts (see the nhist comment in grow_tree).
-    # Single-device only: a shard's local membership of the globally-smaller
-    # children is unbounded, so sharded fits (axis_name set) keep full-width
-    # passes regardless of this flag.
-    # Tri-state: True | False | "auto" (default). "auto" resolves per
-    # BACKEND via :func:`resolve_growth_backend` — off on TPU, where the
-    # row-compaction gather/sort costs more than the full-width one-hot
-    # pass it saves (no artefact of that measurement survives; ROADMAP D4
-    # re-measures it); ON elsewhere, where halving histogram rows is a
-    # CPU-side win. The sentinel NEVER reaches traced code or a compiled-program
-    # cache key: train_booster and the estimator layer both resolve it
-    # first (lint-pinned in tests/test_lint.py).
-    hist_subtraction: "bool | str" = "auto"
-    # Row-compaction selector for hist_subtraction: "argsort" (one stable
-    # [n] sort), "searchsorted" (cumsum + binary search, no sort), or
-    # "auto" (default: argsort on TPU, searchsorted elsewhere, where the
-    # sort-free form wins). A config field — not an env var — so every
-    # compiled-program cache keyed on cfg stays correct for free; resolved
-    # alongside hist_subtraction.
-    compact_selector: str = "auto"
     # Deterministic histogram-reduction geometry (topology-independent
     # training). 0 = the plain path: per-shard histograms psum'd across the
     # mesh — fast, but f32 accumulation order (and therefore the last ulp
@@ -150,48 +120,11 @@ class GrowConfig(NamedTuple):
     # dividing k grows BIT-IDENTICAL trees (model_string() equality at
     # k=8 across 1/2/4/8 devices; the preemption-resume story across
     # topology changes). Costs one gathered [k, F, 3W, B] transient per
-    # pass and disables histogram subtraction. "auto" (default) resolves
-    # via placement.resolve_hist_blocks (MMLSPARK_TPU_HIST_BLOCKS, default
-    # 0) BEFORE entering any compiled-program cache key; unresolved "auto"
-    # reaching growth behaves as 0.
+    # pass. "auto" (default) resolves via placement.resolve_hist_blocks
+    # (MMLSPARK_TPU_HIST_BLOCKS, default 0) BEFORE entering any
+    # compiled-program cache key; unresolved "auto" reaching growth behaves
+    # as 0.
     hist_blocks: "int | str" = "auto"
-
-
-def resolve_growth_backend(cfg: GrowConfig) -> GrowConfig:
-    """Resolve the backend-adaptive tri-states to concrete values.
-
-    ``hist_subtraction="auto"`` -> False on TPU (full-width MXU passes win
-    there), True elsewhere; ``compact_selector="auto"`` -> "argsort" on
-    TPU, "searchsorted" elsewhere (rationale on the GrowConfig fields).
-    MUST run before the config enters any compiled-program cache key or
-    traced code: two processes on different backends resolve differently,
-    and an unresolved sentinel in a cache key would alias their programs.
-    Idempotent; validates ``compact_selector`` either way.
-    """
-    hs, cs = cfg.hist_subtraction, cfg.compact_selector
-    if cs not in ("auto", "argsort", "searchsorted"):
-        raise ValueError(
-            f"compact_selector must be 'auto', 'argsort' or 'searchsorted',"
-            f" got {cs!r}")
-    if hs != "auto" and not isinstance(hs, bool):
-        raise ValueError(
-            f"hist_subtraction must be True, False or 'auto', got {hs!r}")
-    if hs == "auto" or cs == "auto":
-        from ...ops.histogram import _on_tpu_device
-        from ... import tuning as _tuning
-        # the auto-tuner's measured engine winner carries more signal
-        # than the backend name: a box whose measured histogram winner is
-        # the MXU-shaped pallas path wants the TPU-side tri-state
-        # resolution (full-width passes, argsort compaction) — and vice
-        # versa. No measurement -> the backend-name rule.
-        hint = _tuning.growth_tristate_hint()
-        tpu_like = (hint == "pallas") if hint else _on_tpu_device()
-        if hs == "auto":
-            hs = not tpu_like
-        if cs == "auto":
-            cs = "argsort" if tpu_like else "searchsorted"
-        cfg = cfg._replace(hist_subtraction=bool(hs), compact_selector=cs)
-    return cfg
 
 
 # ---------------------------------------------------------------------------
@@ -621,50 +554,6 @@ class Tree(NamedTuple):
     #                          splits; all-zero rows are numeric splits)
 
 
-def _use_subtraction(cfg, axis_name, n: int) -> bool:
-    """Single engagement rule for histogram subtraction, shared by both
-    growth policies: single-device only (see the GrowConfig comment), not
-    under voting, not under the deterministic blocked reduction (the
-    compacted smaller-child pass has no canonical block tiling), and only
-    worth the selector/gather overhead at real row counts (threshold
-    provisional until TPU gather costs are measured)."""
-    if cfg.hist_subtraction == "auto":
-        raise ValueError(
-            "hist_subtraction='auto' reached tree growth unresolved — "
-            "callers must apply resolve_growth_backend(cfg) first")
-    blocked = isinstance(cfg.hist_blocks, int) and cfg.hist_blocks > 1
-    return (cfg.hist_subtraction and axis_name is None and not blocked
-            and not cfg.voting and n >= 8192)
-
-
-@jax.named_scope("gbdt_hist")
-def _subtracted_pair_hists(binned_t, base_t, qscales, row_small,
-                           small_is_left, parent_hists, K, B, h_buf, cfg):
-    """Shared compaction+subtraction core for both growth policies.
-
-    row_small: [n] in [-1, K) -- each row's pair index if it lies in that
-    pair's SMALLER child, else -1. small_is_left: [K] bool. parent_hists:
-    [K, F, 3, B]. Gathers the selected rows (caller guarantees their count
-    is <= h_buf = n//2: pair row sets are disjoint and min(l, r) <= total/2),
-    builds the K smaller-child histograms in one pass over the half-width
-    buffer, derives each larger sibling as parent minus smaller (exact for
-    the count channel; f32-rounding-level differences on grad/hess, as in
-    LightGBM's own subtraction), and returns [2K, F, 3, B] interleaved as
-    [l0, r0, l1, r1, ...]."""
-    F = binned_t.shape[0]
-    src, n_sel = _compact_select(row_small >= 0, h_buf, cfg.compact_selector)
-    pos_h = jnp.where(jnp.arange(h_buf) < n_sel, row_small[src], -1)
-    h_small = node_histogram(jnp.take(binned_t, src, axis=1), pos_h,
-                             jnp.take(base_t, src, axis=1), K, B,
-                             scales=qscales)           # [F, K*3, B]
-    h_small = h_small.reshape(F, K, 3, B).transpose(1, 0, 2, 3)
-    h_large = parent_hists - h_small
-    sl = small_is_left[:, None, None, None]
-    left_h = jnp.where(sl, h_small, h_large)
-    right_h = jnp.where(sl, h_large, h_small)
-    return jnp.stack([left_h, right_h], axis=1).reshape(2 * K, F, 3, B)
-
-
 def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
               valid: jnp.ndarray, feat_mask: jnp.ndarray, cfg: GrowConfig,
               axis_name: Optional[str] = None,
@@ -712,15 +601,6 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                     jnp.ones(F, dtype=bool))
         return _voting_select(h, feat_mask, cfg, axis_name, W, per)
 
-    # Leafwise histogram subtraction: every round's candidates already have
-    # their own histograms cached in ``nhist`` (root from the root pass,
-    # every later node from the round that created it), so each round can
-    # stream ONLY the smaller child of each split (disjoint candidate row
-    # sets bound the total at n//2) and derive the larger sibling by
-    # subtraction. Same engagement rule as depthwise.
-    use_sub = _use_subtraction(cfg, axis_name, n)
-    h_buf = max(n // 2, 1)
-
     root_hist, sel0 = all_hist(jnp.zeros(n, dtype=jnp.int32), 1, "tree")
     # totals from the raw stats (not the histogram: under voting_parallel an
     # unselected feature's rows are zeroed there). Quantized mode totals the
@@ -751,13 +631,6 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         gain=zf,
         num_nodes=jnp.int32(1),
     )
-    if use_sub:
-        # per-node histogram cache [M, F, 3, B] f32 = M*F*3*B*4 bytes —
-        # ~5 MB at 31 leaves x 28 features x 255 bins, LINEAR IN F (a
-        # 1000-feature fit holds ~190 MB of HBM for the whole tree):
-        # the subtraction parent for every future candidate
-        state["nhist"] = jnp.zeros((M, F, 3, B), jnp.float32).at[0].set(
-            root_hist.reshape(F, 3, B))
 
     # Batched best-first: each round splits the top ``leaf_batch`` pending
     # leaves by cached gain in ONE fused histogram pass (their 2*KB children
@@ -787,35 +660,17 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         new_row_node, move, goleft_k = _route_rows_to_children(
             binned_t, st["row_node"], slots, do, feats, bins_, bits_k, lid,
             is_cat)
-        if use_sub:
-            # stream only each candidate's SMALLER child (by raw routed row
-            # count, which is what bounds the n//2 buffer); the larger
-            # sibling derives from the cached candidate histogram
-            with jax.named_scope("gbdt_route"):
-                rawL = jnp.sum(move & goleft_k, axis=1).astype(jnp.int32)
-                rawA = jnp.sum(move, axis=1).astype(jnp.int32)
-                small_is_left = rawL <= rawA - rawL           # ties -> left
-                in_small = jnp.any(
-                    move & (goleft_k == small_is_left[:, None]), axis=0)
-                spos = jnp.sum(jnp.where(move, arange_kb[:, None], 0),
-                               axis=0)
-                row_small = jnp.where(in_small, spos, -1).astype(jnp.int32)
-            hw = _subtracted_pair_hists(
-                binned_t, base_t, qscales, row_small, small_is_left,
-                st["nhist"][jnp.where(do, slots, 0)], KB, B, h_buf, cfg)
-            sel = jnp.ones(F, dtype=bool)
-        else:
-            # child position in [0, 2*KB): 2i = left child of candidate i
-            with jax.named_scope("gbdt_route"):
-                cpos = jnp.where(goleft_k, 2 * arange_kb[:, None],
-                                 2 * arange_kb[:, None] + 1)
-                in_any = jnp.any(move, axis=0)
-                child_pos = jnp.where(
-                    in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
-                ).astype(jnp.int32)
+        # child position in [0, 2*KB): 2i = left child of candidate i
+        with jax.named_scope("gbdt_route"):
+            cpos = jnp.where(goleft_k, 2 * arange_kb[:, None],
+                             2 * arange_kb[:, None] + 1)
+            in_any = jnp.any(move, axis=0)
+            child_pos = jnp.where(
+                in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
+            ).astype(jnp.int32)
 
-            h, sel = all_hist(child_pos, W2, "round")   # [F, W2*3, B]
-            hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
+        h, sel = all_hist(child_pos, W2, "round")   # [F, W2*3, B]
+        hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
 
         # child totals: left from the candidate cache, right = parent - left
         lg = st["clg"][slots]
@@ -860,11 +715,6 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             new["clc"] = st["clc"].at[cslot].set(lc2, mode="drop")
             new["cbits"] = st["cbits"].at[cslot].set(bits2, mode="drop")
             new["num_nodes"] = st["num_nodes"] + 2 * n_split
-            if use_sub:
-                # cache the children's histograms: they are the subtraction
-                # parents of every round that later splits them (cslot order is
-                # [l0, r0, l1, r1, ...], matching hw's channel order)
-                new["nhist"] = st["nhist"].at[cslot].set(hw, mode="drop")
         return new
 
     def round_body(_, st):
@@ -931,36 +781,6 @@ def _renew_leaf_stats(state, grad, hess, vm, M: int, axis_name,
     return state
 
 
-def _compact_select(sel: jnp.ndarray, h_buf: int, mode: str = "argsort"):
-    """Indices of the selected rows, compacted to the front of an ``h_buf``
-    buffer (stable order). Returns (src [h_buf] int32, n_sel int32 scalar);
-    entries past n_sel point at unselected rows and must be masked by the
-    caller (via the gathered per-row positions, not by index value).
-
-    ``mode`` (GrowConfig.compact_selector) picks the formulation:
-    - "argsort" (default): stable argsort of the not-selected key — one
-      [n] sort.
-    - "searchsorted": cumsum + vectorized binary search for the k-th
-      selected row — 20 rounds of [h_buf] gathers, no sort.
-    They are bit-identical in output for valid (j < n_sel) entries.
-    """
-    if mode not in ("argsort", "searchsorted"):
-        raise ValueError(
-            f"compact_selector must be 'argsort' or 'searchsorted', got "
-            f"{mode!r}")
-    n = sel.shape[0]
-    n_sel = jnp.sum(sel.astype(jnp.int32))
-    if mode == "searchsorted":
-        c = jnp.cumsum(sel.astype(jnp.int32))
-        src = jnp.searchsorted(c, jnp.arange(1, h_buf + 1, dtype=jnp.int32),
-                               side="left")
-        src = jnp.minimum(src, n - 1).astype(jnp.int32)
-    else:
-        key = jnp.where(sel, jnp.int8(0), jnp.int8(1))
-        src = jnp.argsort(key, stable=True)[:h_buf].astype(jnp.int32)
-    return src, n_sel
-
-
 def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
                         hess: jnp.ndarray, valid: jnp.ndarray,
                         feat_mask: jnp.ndarray, cfg: GrowConfig,
@@ -1018,94 +838,42 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
 
     vsplit = jax.vmap(_best_split, in_axes=(0, 0, 0, 0, None, None, 0, None))
 
-    use_sub = _use_subtraction(cfg, axis_name, n)
-    h_buf = max(n // 2, 1)
-
-    def _zero_aux(depth: int):
-        """(h_prev, pair_parent, child_raw) zeros shaped for level ``depth``:
-        the previous level's assembled histograms [W_prev, F, 3, B], each
-        sibling pair's parent position in the previous frontier [W//2], and
-        raw per-child row counts [W] (raw = including invalid rows — that is
-        what bounds the compaction buffer)."""
-        Wp = min(2 ** max(depth - 1, 0), L)
-        W = min(2 ** depth, L)
-        return (jnp.zeros((Wp, F, 3, B), jnp.float32),
-                jnp.full((W // 2,), -1, dtype=jnp.int32),
-                jnp.zeros((W,), dtype=jnp.int32))
-
-    def _sub_level_hist(aux, frontier, row_node, W):
-        """[W, F, 3, B] level histograms via smaller-child compaction.
-
-        Gathers the rows of each pair's smaller child (by raw count; at most
-        n//2 rows in total since the pairs' row sets are disjoint) into the
-        half-width buffer, builds only those W//2 histograms, and derives
-        each larger sibling as parent minus smaller (exact for the count
-        channel; f32-rounding-level differences on grad/hess, as in
-        LightGBM's own subtraction)."""
-        h_prev, pair_parent, child_raw = aux
-        Wh = W // 2
-        pair_active = pair_parent >= 0
-        left_raw = child_raw[0::2][:Wh]
-        right_raw = child_raw[1::2][:Wh]
-        small_off = (right_raw < left_raw).astype(jnp.int32)  # ties -> left
-        small_pos = 2 * jnp.arange(Wh, dtype=jnp.int32) + small_off
-        small_slot = frontier[small_pos]
-        slot_to_small = jnp.full(M, -1, dtype=jnp.int32)
-        slot_to_small = slot_to_small.at[
-            jnp.where(pair_active & (small_slot >= 0), small_slot, M)
-        ].set(jnp.arange(Wh, dtype=jnp.int32), mode="drop")
-        row_small = slot_to_small[row_node]            # [n] in [-1, Wh)
-        hw = _subtracted_pair_hists(
-            binned_t, base_t, qscales, row_small, small_off == 0,
-            h_prev[jnp.maximum(pair_parent, 0)], Wh, B, h_buf, cfg)
-        if 2 * Wh != W:
-            # odd frontier width: the last slot never holds a child (children
-            # arrive in pairs), so its channel is inert zero padding
-            hw = jnp.pad(hw, ((0, W - 2 * Wh), (0, 0), (0, 0), (0, 0)))
-        return hw
-
     def make_level(depth: int, W: int):
         def level_work(state):
-            row_node, frontier, num_nodes, leaves, tree_arrays = state[:5]
+            row_node, frontier, num_nodes, leaves, tree_arrays = state
             fr = frontier[:W]
             active = fr >= 0
 
-            if use_sub and depth >= 1:
-                h = _sub_level_hist(state[5], frontier, row_node, W)
-                feat_mask_lvl = feat_mask
-            else:
-                # per-row frontier position (rows at finished leaves get -1);
-                # index M is out of bounds -> dropped for inactive slots
-                slot_to_pos = jnp.full(M, -1, dtype=jnp.int32)
-                slot_to_pos = slot_to_pos.at[jnp.where(active, fr, M)].set(
-                    jnp.arange(W, dtype=jnp.int32), mode="drop")
-                row_pos = slot_to_pos[row_node]      # [n] in [-1, W)
+            # per-row frontier position (rows at finished leaves get -1);
+            # index M is out of bounds -> dropped for inactive slots
+            slot_to_pos = jnp.full(M, -1, dtype=jnp.int32)
+            slot_to_pos = slot_to_pos.at[jnp.where(active, fr, M)].set(
+                jnp.arange(W, dtype=jnp.int32), mode="drop")
+            row_pos = slot_to_pos[row_node]      # [n] in [-1, W)
 
-                # one fused histogram pass covers the whole level: the
-                # row->position one-hot and masked stats are built in VMEM
-                feat_mask_lvl = feat_mask
-                with jax.named_scope("gbdt_hist"):
-                    if bl:
-                        # canonical blocked fold: topology-independent f32
-                        # order
-                        h = _blocked_node_hist(binned_t, row_pos, base_t, W,
-                                               B, qscales, bl, rpb, axis_name,
-                                               "level")
+            # one fused histogram pass covers the whole level: the
+            # row->position one-hot and masked stats are built in VMEM
+            feat_mask_lvl = feat_mask
+            with jax.named_scope("gbdt_hist"):
+                if bl:
+                    # canonical blocked fold: topology-independent f32 order
+                    h = _blocked_node_hist(binned_t, row_pos, base_t, W, B,
+                                           qscales, bl, rpb, axis_name,
+                                           "level")
+                else:
+                    h = node_histogram(binned_t, row_pos, base_t, W, B,
+                                       scales=qscales)         # [F, W*3, B]
+                    if axis_name is not None and cfg.voting:
+                        # per-level voting: shards vote top_k features by
+                        # their best local gain across the WHOLE frontier,
+                        # then only the global top-2k features' level
+                        # histograms cross the interconnect
+                        h, sel = _voting_select(h, feat_mask, cfg,
+                                                axis_name, W, "level")
+                        feat_mask_lvl = feat_mask & sel
                     else:
-                        h = node_histogram(binned_t, row_pos, base_t, W, B,
-                                           scales=qscales)     # [F, W*3, B]
-                        if axis_name is not None and cfg.voting:
-                            # per-level voting: shards vote top_k features
-                            # by their best local gain across the WHOLE
-                            # frontier, then only the global top-2k
-                            # features' level histograms cross the
-                            # interconnect
-                            h, sel = _voting_select(h, feat_mask, cfg,
-                                                    axis_name, W, "level")
-                            feat_mask_lvl = feat_mask & sel
-                        else:
-                            h = _allreduce(h, axis_name, "hist", "level")
-                h = h.reshape(F, W, 3, B).transpose(1, 0, 2, 3)  # [W,F,3,B]
+                        h = _allreduce(h, axis_name, "hist", "level")
+            h = h.reshape(F, W, 3, B).transpose(1, 0, 2, 3)      # [W,F,3,B]
 
             tot = jnp.stack([tree_arrays["ng"][jnp.maximum(fr, 0)],
                              tree_arrays["nh"][jnp.maximum(fr, 0)],
@@ -1135,7 +903,7 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
             # update rows: rows in split nodes move to their child slot
             # (keyed on node slot ids — inactive frontier slots are -1 and
             # match no row since row_node >= 0)
-            row_node, move, goleft_k = _route_rows_to_children(
+            row_node, _, _ = _route_rows_to_children(
                 binned_t, row_node, jnp.where(active, fr, -1), do, feats,
                 bins_, bits_w, lid, is_cat)
 
@@ -1174,45 +942,19 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
             frontier = jnp.full(L, -1, dtype=jnp.int32).at[:W_next].set(
                 compacted[:W_next])
 
-            out = (row_node, frontier, num_nodes + 2 * n_split,
-                   leaves + n_split, ta)
-            if use_sub:
-                # aux for the next level, packed with the SAME stable perm as
-                # the child slots so pairs stay adjacent: raw per-child row
-                # counts (from the routing masks — includes invalid rows,
-                # which is what bounds the compaction buffer) and each pair's
-                # parent position in THIS frontier. h_prev = this level's
-                # assembled histograms.
-                rawL = jnp.sum(move & goleft_k, axis=1).astype(jnp.int32)
-                rawA = jnp.sum(move, axis=1).astype(jnp.int32)
-                raw2 = jnp.stack([rawL, rawA - rawL], axis=1).reshape(-1)
-                pp2 = jnp.repeat(
-                    jnp.where(do, jnp.arange(W, dtype=jnp.int32), -1), 2)
-                raw_next = raw2[perm][:W_next]
-                pp_next = pp2[perm][:2 * (W_next // 2)][0::2]
-                out = out + ((h, pp_next, raw_next),)
-            return out
+            return (row_node, frontier, num_nodes + 2 * n_split,
+                    leaves + n_split, ta)
 
         return level_work
 
     state = (row_node, frontier, num_nodes, leaves, tree_arrays)
-    if use_sub:
-        state = state + (_zero_aux(0),)
     for depth in range(depth_cap):           # static unroll: W varies by level
         W = min(2 ** depth, L)
         # runtime skip: once the budget is spent or the frontier is empty,
         # the remaining (slack) levels cost nothing
         pred = (state[3] < jnp.int32(L)) & jnp.any(state[1] >= 0)
-        if use_sub:
-            # the skip branch must still produce next-level aux shapes (its
-            # content is never read once the tree is finished)
-            def _skip(s, _d=depth):
-                return s[:5] + (_zero_aux(_d + 1),)
-        else:
-            def _skip(s):
-                return s
-        state = lax.cond(pred, make_level(depth, W), _skip, state)
-    row_node, frontier, num_nodes, leaves, tree_arrays = state[:5]
+        state = lax.cond(pred, make_level(depth, W), lambda s: s, state)
+    row_node, frontier, num_nodes, leaves, tree_arrays = state
 
     if cfg.quantized_grad and cfg.quant_renew_leaf:
         tree_arrays = _renew_leaf_stats(

@@ -283,7 +283,7 @@ REGISTRY: Tuple[EnvVar, ...] = (
     EnvVar(name="MMLSPARK_TPU_TUNE_HOLD_MS", default="(tuner decides)",
            section="performance",
            doc="pin the async dispatch hold window in ms (`0` disables "
-               "holding entirely) — the opt-out for tuning site 3; "
+               "holding entirely) — the opt-out for tuning site 2; "
                "unset lets the tuner derive it from the roofline "
                "`bound` verdict and stage EWMAs"),
     EnvVar(name="MMLSPARK_TPU_TUNE_HOLD_CAP_MS", default="2.0",

@@ -95,42 +95,16 @@ def _use_pallas() -> bool:
 
 _ENGINES = ("pallas", "onehot", "scatter")
 
-# measured-winner hint installed by the auto-tuner (mmlspark_tpu/tuning)
-# before the train step's cache key is assembled; None = untuned. Module
-# state, not an argument: resolve_engine() is consulted from inside
-# traced program builders that cannot thread a hint through.
-_TUNED_ENGINE: str = ""
-
-
-def set_tuned_engine(engine: str = "") -> None:
-    """Install (or clear, with ``""``) the tuner's measured engine winner
-    consulted by ``auto``. An explicit ``MMLSPARK_TPU_HIST_ENGINE`` pin
-    always beats the hint — that is the documented opt-out."""
-    global _TUNED_ENGINE
-    if engine and engine not in _ENGINES:
-        raise ValueError(f"tuned engine must be one of {_ENGINES}, "
-                         f"got {engine!r}")
-    _TUNED_ENGINE = engine
-
-
-def engine_candidates() -> tuple:
-    """Engines worth measuring on this backend, static-rule choice first
-    (calibration order; the tuner needs >= 2 to decide)."""
-    if _use_pallas():
-        return ("pallas", "onehot")
-    return ("scatter", "onehot")
-
 
 def resolve_engine() -> str:
     """Histogram engine for the current backend/env (before shape gates).
 
     ``MMLSPARK_TPU_HIST_ENGINE=pallas|onehot|scatter|auto`` (default auto):
-    ``auto`` prefers the auto-tuner's measured winner when one is
-    installed (:func:`set_tuned_engine` — see docs/performance.md
-    §Auto-tuning), else picks ``pallas`` where the TPU kernel can lower
-    (a TPU backend, or ``MMLSPARK_TPU_PALLAS_INTERPRET`` off-TPU) and
-    ``scatter`` elsewhere. An explicit ``pallas`` where the kernel cannot
-    lower (no TPU backend and no interpreter, or
+    ``auto`` picks ``pallas`` where the TPU kernel can lower (a TPU
+    backend, or ``MMLSPARK_TPU_PALLAS_INTERPRET`` off-TPU) and ``scatter``
+    elsewhere: a function of the environment and the backend alone, and the
+    one place the engine is chosen. An explicit ``pallas`` where the kernel
+    cannot lower (no TPU backend and no interpreter, or
     ``MMLSPARK_TPU_DISABLE_PALLAS_HIST`` set) raises: a pinned engine never
     silently becomes another one.
     """
@@ -149,10 +123,6 @@ def resolve_engine() -> str:
         return "pallas"
     if env != "auto":
         return env
-    # measured hint: pallas is re-checked against lowerability (a store
-    # tuned on TPU must not pick pallas on a CPU box)
-    if _TUNED_ENGINE and (_TUNED_ENGINE != "pallas" or _use_pallas()):
-        return _TUNED_ENGINE
     if _use_pallas():
         return "pallas"
     return "onehot" if _on_tpu_device() else "scatter"

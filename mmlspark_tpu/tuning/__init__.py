@@ -5,7 +5,7 @@ PRs 16–18 built the measurement plane — the roofline ledger's
 ``bound: compute|memory`` verdicts, per-stage serving histograms, SLO
 burn rates — but every performance-relevant knob still resolved by
 static heuristics. This package is the decision layer: it turns those
-ledgers into resolved knob values at four sites, all sharing one
+ledgers into resolved knob values at three serving sites, all sharing one
 pattern — *observe* (EWMAs / histograms recorded here), *decide
 deterministically* (:mod:`.decisions`: pure functions of the evidence,
 no wall-clock or device reads), *resolve BEFORE any compiled-program
@@ -14,31 +14,25 @@ cache key is assembled* (the PR 4 rule, lint-anchored), *emit* a
 counter, and *degrade to today's static rule* whenever evidence is
 missing or the store's fingerprint skews.
 
-The four sites:
+The three sites:
 
-1. **hist_engine** — ``ops/histogram.resolve_engine``'s ``auto``
-   consults the per-(engine, shape-bucket) winner measured by a short
-   calibration on the first tuned fit (one real histogram round per
-   candidate engine, on the fit's own binned data); the
-   ``hist_subtraction``/``compact_selector`` tri-states take the same
-   measured hint (:func:`growth_tristate_hint`).
-2. **bucket_ladder** — the predict bucket ladder derives from the
+1. **bucket_ladder** — the predict bucket ladder derives from the
    observed serving batch-size histogram instead of the fixed pow2
    grid; ``Booster.predict_plan`` and ``serving.bucket_size`` both
    resolve it, so the hot path, the bundle builder and the key manifest
    can never disagree.
-3. **hold_window** — when the score stage is memory-bound and
+2. **hold_window** — when the score stage is memory-bound and
    under-occupied, the async dispatcher holds the forming buffer up to
    this window to dispatch fuller batches; a breaching endpoint (SLO
    fast-window burn > 1) is never held — that check is runtime state,
    applied at dispatch in ``io/aserve``.
-4. **slots** — ``MMLSPARK_TPU_ASERVE_SLOTS=auto`` sizes the slot table
+3. **slots** — ``MMLSPARK_TPU_ASERVE_SLOTS=auto`` sizes the slot table
    from the p99.9 of admitted-batch rows, reconciled against the
    ``aserve_slots`` HBM claim headroom.
 
 Decisions persist to a fingerprinted JSON store (:mod:`.store`) so the
 second process starts tuned: its resolvers answer from the store
-(flight events say ``source=store``) with zero calibration rounds.
+(flight events say ``source=store``) and decide nothing again.
 ``/debug/tuning`` (both serving engines) renders
 :func:`snapshot_payload`.
 
@@ -50,7 +44,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from ..observability import flight as _flight
 from ..observability import hbm as _hbm
@@ -60,7 +54,7 @@ from ..observability.env_registry import env_float, env_int
 from ..observability.logging import get_logger
 from . import decisions as _decisions
 from . import store as _store
-from .decisions import ladder_pad, shape_bucket
+from .decisions import ladder_pad
 from .store import TUNING_DIR_ENV
 
 logger = get_logger("mmlspark_tpu.tuning")
@@ -72,14 +66,11 @@ HOLD_MS_ENV = "MMLSPARK_TPU_TUNE_HOLD_MS"
 #: cap on the tuner-computed hold window (ms)
 HOLD_CAP_MS_ENV = "MMLSPARK_TPU_TUNE_HOLD_CAP_MS"
 
-_SITES = ("hist_engine", "bucket_ladder", "hold_window", "slots")
-
 __all__ = ["TUNING_DIR_ENV", "enabled", "reset", "configure",
            "observe_batch_size", "observe_score", "observe_forming_wait",
-           "note_slot_geometry", "resolve_hist_engine",
-           "resolve_bucket_ladder", "resolve_hold_window",
-           "resolve_slots_auto", "growth_tristate_hint", "ladder_pad",
-           "shape_bucket", "snapshot_payload", "provenance", "flush"]
+           "note_slot_geometry", "resolve_bucket_ladder",
+           "resolve_hold_window", "resolve_slots_auto", "ladder_pad",
+           "snapshot_payload", "provenance", "flush"]
 
 
 def _device_memory_limit() -> Optional[float]:
@@ -269,17 +260,6 @@ class _Tuner:
             self._evidence["slot_geometry"] = {
                 "row_bytes": int(row_bytes), "max_batch": int(max_batch)}
 
-    def observe_hist_engine(self, bucket: str, engine: str,
-                            seconds: float) -> None:
-        with self._lock:
-            self._ensure_loaded()
-            buckets = self._evidence.setdefault("hist_engine", {})
-            ev = buckets.setdefault(bucket, {}).setdefault(
-                engine, {"ewma_seconds": None, "samples": 0})
-            ev["ewma_seconds"] = _decisions.ewma_update(
-                ev["ewma_seconds"], seconds)
-            ev["samples"] += 1
-
     def _min_samples(self) -> int:
         return max(1, env_int(MIN_SAMPLES_ENV, 64))
 
@@ -345,61 +325,7 @@ class _Tuner:
                                  "mean_batch": round(mean_batch, 2)}}
         self.save()
 
-    # -- resolvers (the four sites) ----------------------------------------
-
-    def resolve_hist_engine(self, n_rows: int, num_features: int,
-                            num_bins: int, candidates: Sequence[str],
-                            measure: Optional[Callable[[str], float]] = None,
-                            ) -> Optional[str]:
-        bucket = shape_bucket(n_rows, num_features, num_bins)
-        site_key = f"hist_engine/{bucket}"
-        with self._lock:
-            self._ensure_loaded()
-            if self._degraded:
-                self._emit("hist_engine", None, "static", bucket=bucket)
-                return None
-            decision = self._decisions.get(site_key)
-        if decision is not None:
-            choice = decision.get("choice")
-            if choice is not None and choice not in candidates:
-                choice = None     # measured on hardware this host lacks
-            self._emit("hist_engine", choice,
-                       decision.get("source", "store") if choice is not None
-                       else "static", bucket=bucket)
-            return choice
-        if measure is None or len(candidates) < 2:
-            self._emit("hist_engine", None, "static", bucket=bucket)
-            return None
-        # calibration: one real measured round per candidate engine, on
-        # the caller's own data (the caller owns device + timing; the
-        # DECISION below is a pure function of the recorded EWMAs)
-        for engine in candidates:
-            try:
-                seconds = float(measure(engine))
-            except Exception as e:  # noqa: BLE001 — a candidate that
-                # cannot lower here simply drops out of the evidence
-                _flight.record("tuning", event="calibrate_failed",
-                               site="hist_engine", bucket=bucket,
-                               engine=engine,
-                               error=f"{type(e).__name__}: {e}")
-                continue
-            self.observe_hist_engine(bucket, engine, seconds)
-            _flight.record("tuning", event="calibrate", site="hist_engine",
-                           bucket=bucket, engine=engine,
-                           seconds=round(seconds, 6))
-        with self._lock:
-            bucket_ev = (self._evidence.get("hist_engine") or {}).get(
-                bucket, {})
-            choice = _decisions.decide_hist_engine(bucket_ev)
-            self._decisions[site_key] = {
-                "choice": choice, "source": "calibration",
-                "evidence": {eng: {"ewma_seconds":
-                                   round(ev["ewma_seconds"], 6),
-                                   "samples": ev["samples"]}
-                             for eng, ev in sorted(bucket_ev.items())}}
-        self.save()
-        self._emit("hist_engine", choice, "calibration", bucket=bucket)
-        return choice
+    # -- resolvers (the three sites) ---------------------------------------
 
     def bucket_ladder(self) -> Optional[Tuple[int, ...]]:
         with self._lock:
@@ -456,23 +382,6 @@ class _Tuner:
         self._emit("slots", choice, decision.get("source", "measured"))
         return min(choice, _decisions.pow2_ceil(max_batch))
 
-    def growth_hint(self) -> Optional[str]:
-        """The measured engine winner the growth tri-states key off:
-        the majority winner across decided shape buckets (lexicographic
-        tie-break — deterministic), None when nothing is decided."""
-        with self._lock:
-            self._ensure_loaded()
-            if self._degraded:
-                return None
-            winners = [d.get("choice") for k, d in self._decisions.items()
-                       if k.startswith("hist_engine/") and d.get("choice")]
-        if not winners:
-            return None
-        tally: Dict[str, int] = {}
-        for w in winners:
-            tally[w] = tally.get(w, 0) + 1
-        return sorted(tally.items(), key=lambda kv: (-kv[1], kv[0]))[0][0]
-
     # -- introspection -----------------------------------------------------
 
     def snapshot_payload(self) -> Dict[str, Any]:
@@ -492,8 +401,6 @@ class _Tuner:
                             in sorted(self._emitted.items())},
                 "evidence": {
                     "batch_size_samples": sum(counts.values()),
-                    "hist_engine_buckets": sorted(
-                        self._evidence.get("hist_engine") or {}),
                     "stage": dict(self._evidence.get("stage") or {}),
                 },
             }
@@ -593,23 +500,8 @@ def note_slot_geometry(row_bytes: int, max_batch: int) -> None:
         t.note_slot_geometry(row_bytes, max_batch)
 
 
-def resolve_hist_engine(n_rows: int, num_features: int, num_bins: int,
-                        candidates: Sequence[str],
-                        measure: Optional[Callable[[str], float]] = None,
-                        ) -> Optional[str]:
-    """Site 1: the measured histogram-engine winner for this fit's shape
-    bucket (store hit, or calibrated now via ``measure``), or None for
-    the static rule. The caller applies the hint and MUST do so before
-    any compiled-program cache key is assembled (lint-anchored)."""
-    t = _tuner()
-    if t is None:
-        return None
-    return t.resolve_hist_engine(n_rows, num_features, num_bins,
-                                 candidates, measure)
-
-
 def resolve_bucket_ladder() -> Optional[Tuple[int, ...]]:
-    """Site 2: the tuned predict bucket ladder (ascending ints), or None
+    """Site 1: the tuned predict bucket ladder (ascending ints), or None
     for the static pow2 ladder. Resolved by ``Booster.predict_plan``
     before its key tuple and by ``serving.bucket_size`` — cheap enough
     for both hot paths (two dict probes when tuning is disabled)."""
@@ -620,7 +512,7 @@ def resolve_bucket_ladder() -> Optional[Tuple[int, ...]]:
 
 
 def resolve_hold_window() -> float:
-    """Site 3: dispatch hold window in seconds (0.0 = dispatch on first
+    """Site 2: dispatch hold window in seconds (0.0 = dispatch on first
     formed request, the static rule). ``MMLSPARK_TPU_TUNE_HOLD_MS`` pins
     it; the SLO-burn override is applied at dispatch, not here."""
     t = _tuner()
@@ -631,22 +523,12 @@ def resolve_hold_window() -> float:
 
 def resolve_slots_auto(max_batch: int,
                        row_bytes: Optional[int] = None) -> Optional[int]:
-    """Site 4: measured slot-table size for ``ASERVE_SLOTS=auto``, or
+    """Site 3: measured slot-table size for ``ASERVE_SLOTS=auto``, or
     None when the store holds no decision (first process: static cap)."""
     t = _tuner()
     if t is None:
         return None
     return t.slots_auto(max_batch, row_bytes=row_bytes)
-
-
-def growth_tristate_hint() -> Optional[str]:
-    """The measured engine winner (``pallas``/``onehot``/``scatter``)
-    the ``hist_subtraction``/``compact_selector`` tri-states key off, or
-    None for the static backend-name rule."""
-    t = _tuner()
-    if t is None:
-        return None
-    return t.growth_hint()
 
 
 def snapshot_payload() -> Dict[str, Any]:

@@ -7,7 +7,7 @@ device queries. That purity IS the replay contract the tuner promises
 and it keeps each decision unit-testable without jax, a server, or a
 clock.
 
-The four decision sites (see the package docstring for where each is
+The three decision sites (see the package docstring for where each is
 applied) all follow the same shape: return the measured choice when the
 evidence clears the bar, return ``None`` (or the neutral value) when it
 does not — the caller then degrades to today's static rule.
@@ -21,11 +21,6 @@ from typing import Dict, Optional, Sequence, Tuple
 #: per-executable call EWMA, so the two planes age samples identically)
 EWMA_ALPHA = 0.2
 
-#: a calibration winner must beat the runner-up by this margin — inside
-#: it the measurement noise exceeds the signal and the static rule's
-#: choice is kept (re-deciding on noise would flip engines per process)
-ENGINE_WIN_MARGIN = 0.03
-
 #: ladder rungs snap up to multiples of this (sublane-friendly, and it
 #: bounds the rung set against high-cardinality batch-size workloads)
 LADDER_STEP = 8
@@ -34,9 +29,8 @@ LADDER_STEP = 8
 #: the "bounded set" contract that keeps iter_predict_plans enumerable
 LADDER_MAX_RUNGS = 4
 
-__all__ = ["EWMA_ALPHA", "ENGINE_WIN_MARGIN", "LADDER_STEP",
-           "LADDER_MAX_RUNGS", "ewma_update", "shape_bucket",
-           "decide_hist_engine", "decide_bucket_ladder", "ladder_pad",
+__all__ = ["EWMA_ALPHA", "LADDER_STEP", "LADDER_MAX_RUNGS", "ewma_update",
+           "decide_bucket_ladder", "ladder_pad",
            "percentile_from_counts", "decide_hold_window", "decide_slots",
            "pow2_ceil"]
 
@@ -51,34 +45,6 @@ def ewma_update(prev: Optional[float], sample: float,
 def pow2_ceil(n: int) -> int:
     n = max(1, int(n))
     return 1 << (n - 1).bit_length()
-
-
-def shape_bucket(n_rows: int, num_features: int, num_bins: int) -> str:
-    """The granularity engine measurements generalize across: pow2 row
-    and feature buckets plus the exact bin width (bin width changes the
-    engines' relative cost structure directly)."""
-    return f"r{pow2_ceil(n_rows)}f{pow2_ceil(num_features)}b{int(num_bins)}"
-
-
-def decide_hist_engine(
-        bucket_evidence: Dict[str, Dict[str, float]]) -> Optional[str]:
-    """Measured histogram-engine winner for one shape bucket, or None
-    when the evidence cannot support a decision (fewer than two engines
-    measured, or the win is inside the noise margin).
-
-    ``bucket_evidence``: ``{engine: {"ewma_seconds": s, "samples": n}}``.
-    Ties break lexicographically — deterministic across replays.
-    """
-    timed = sorted(
-        (float(ev["ewma_seconds"]), eng)
-        for eng, ev in bucket_evidence.items()
-        if ev.get("samples", 0) and float(ev.get("ewma_seconds", 0)) > 0)
-    if len(timed) < 2:
-        return None
-    best, runner = timed[0], timed[1]
-    if best[0] >= runner[0] * (1.0 - ENGINE_WIN_MARGIN):
-        return None
-    return best[1]
 
 
 def percentile_from_counts(counts: Dict[str, float], q: float) -> int:

@@ -2,7 +2,7 @@
 evidence and resolved decisions.
 
 The measure→decide loop (``mmlspark_tpu/tuning``) is only worth its
-calibration cost if the SECOND process starts tuned: decisions
+measurement cost if the SECOND process starts tuned: decisions
 serialize here as one JSON document per store directory
 (``MMLSPARK_TPU_TUNING_DIR``), written atomically (tmp + rename, the
 bundle-build idiom) so a crashed writer can never leave a torn store
@@ -10,7 +10,7 @@ where a restarting worker would read it.
 
 The store is fingerprinted like the bundle manifest — device kind,
 model content hash, framework version — because every decision in it
-is a *measurement* of those three things: an engine winner measured on
+is a *measurement* of those three things: a hold window measured on
 one device kind says nothing about another, and a bucket ladder derived
 from one model's serving workload must not shape another model's
 compiled-program keys. A mismatched fingerprint degrades LOUDLY to the

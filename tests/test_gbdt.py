@@ -34,6 +34,52 @@ def _binary_data():
     return train_test_split(X, y, test_size=0.3, random_state=0)
 
 
+def _many_rows_data(with_cats: bool, n: int = 9000, F: int = 10):
+    """9000 x 10 binary task; with ``with_cats`` features 8 and 9 are
+    category ids in [0, 6) that the label depends on."""
+    rng = np.random.default_rng(7)
+    X = rng.normal(size=(n, F)).astype(np.float32)
+    margin = X[:, 0] * X[:, 1] - X[:, 2] + 0.2 * rng.normal(size=n)
+    if with_cats:
+        X[:, 8:] = rng.integers(0, 6, size=(n, 2))
+        margin = margin + np.isin(X[:, 8], (1, 4)) - (X[:, 9] == 2)
+    return X, (margin > 0).astype(np.float32)
+
+
+def _routed_rows(b: Booster, X, cats) -> np.ndarray:
+    """[T, M] rows reaching every node of every tree, by a plain walk over
+    the finished trees' raw thresholds and category bitsets."""
+    trees = b.trees
+    feat, left, right = (np.asarray(a) for a in (trees.feat, trees.left,
+                                                 trees.right))
+    is_leaf, bits = np.asarray(trees.is_leaf), np.asarray(trees.cat_bitset)
+    thr = np.asarray(b.thr_raw)
+    out = np.zeros(feat.shape, np.float32)
+    for t in range(feat.shape[0]):
+        node = np.zeros(len(X), np.int64)
+        rows = np.arange(len(X))                 # rows not at a leaf yet
+        for _ in range(feat.shape[1]):
+            out[t] += np.bincount(node[rows], minlength=feat.shape[1])
+            rows = rows[~is_leaf[t, node[rows]]]
+            if not len(rows):
+                break
+            nd = node[rows]
+            f = feat[t, nd]
+            x = X[rows, f]
+            go_left = x <= thr[t, nd]
+            cid = x.astype(np.int64)
+            member = (bits[t, nd, cid >> 5] >> (cid & 31).astype(np.uint32)
+                      ) & 1
+            go_left = np.where(np.isin(f, cats), member.astype(bool), go_left)
+            node[rows] = np.where(go_left, left[t, nd], right[t, nd])
+    return out
+
+
+def _logloss(y, p):
+    p = np.clip(np.asarray(p, np.float64), 1e-12, 1 - 1e-12)
+    return float(-np.mean(y * np.log(p) + (1 - y) * np.log(1 - p)))
+
+
 def _to_ds(X, y, **extra):
     cols = {"features": np.asarray(X, np.float32), "label": np.asarray(y, np.float64)}
     cols.update(extra)
@@ -397,53 +443,46 @@ class TestBoosterInternals:
             num_leaves=63, min_data_in_leaf=40, leaf_batch=8), **common)
         assert np.allclose(b1.predict(X), b8.predict(X), atol=1e-5)
 
-    def test_hist_subtraction_matches_direct(self):
-        # Depthwise histogram subtraction (smaller-child compaction +
-        # parent-minus-sibling derivation) must reproduce the direct
-        # full-width passes. Needs a single-device mesh (the booster keeps
-        # full-width passes on a sharded data axis) and n >= 8192 (the
-        # engagement threshold). The count channel is exact under
-        # subtraction; grad/hess differ only at f32 rounding, so split
-        # decisions — and therefore predictions — must match.
+    @pytest.mark.parametrize("cats", [(), (8, 9)],
+                             ids=["numeric", "categorical"])
+    @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+    @pytest.mark.parametrize("policy", ["leafwise", "depthwise"])
+    def test_single_device_many_rows(self, policy, quantized, cats):
+        # The one-chip benchmark cells' shape: a single device, no collective
+        # axis, thousands of rows. Every node's count must be the rows the
+        # finished tree routes there (the benchmark's count_gap, at toy
+        # size); with f32 statistics the model equals the 8-device fit's to
+        # the leaf_batch test's tolerance (what criteo255q.train beside
+        # criteo255q.train4 relies on). With int8 each shard quantizes by
+        # itself, so across meshes only the training loss is comparable.
         import jax
         from mmlspark_tpu.parallel import mesh as meshlib
 
-        n, F = 9000, 10
-        rng = np.random.default_rng(7)
-        X = rng.normal(size=(n, F)).astype(np.float32)
-        y = (X[:, 0] * X[:, 1] - X[:, 2] + 0.2 * rng.normal(size=n) > 0
-             ).astype(np.float32)
-        with meshlib.default_mesh(
-                meshlib.make_mesh({"data": 1}, devices=jax.devices()[:1])):
-            preds = {}
-            for sub in (False, True):
-                cfg = GrowConfig(num_leaves=15, growth_policy="depthwise",
-                                 hist_subtraction=sub)
-                b = train_booster(X, y, objective="binary",
-                                  num_iterations=5, cfg=cfg, max_bin=63,
-                                  seed=0)
-                preds[sub] = np.asarray(b.predict(X))
-            np.testing.assert_allclose(preds[True], preds[False], atol=1e-4)
-            # the sort-free selector must agree with the argsort selector
-            cfg = GrowConfig(num_leaves=15, growth_policy="depthwise",
-                             hist_subtraction=True,
-                             compact_selector="searchsorted")
-            b = train_booster(X, y, objective="binary",
-                              num_iterations=5, cfg=cfg, max_bin=63,
-                              seed=0)
-            np.testing.assert_allclose(np.asarray(b.predict(X)),
-                                       preds[True], atol=1e-6)
-            # leafwise: every round's candidates have cached parent
-            # histograms, so subtraction engages on all rounds
-            for s in (False, True):
-                cfg = GrowConfig(num_leaves=15, growth_policy="leafwise",
-                                 hist_subtraction=s)
-                b = train_booster(X, y, objective="binary",
-                                  num_iterations=5, cfg=cfg, max_bin=63,
-                                  seed=0)
-                preds[("leaf", s)] = np.asarray(b.predict(X))
-            np.testing.assert_allclose(preds[("leaf", True)],
-                                       preds[("leaf", False)], atol=1e-4)
+        X, y = _many_rows_data(bool(cats))
+        fit = dict(objective="binary", num_iterations=5, max_bin=63, seed=0,
+                   categorical_features=cats)
+        cfg = GrowConfig(num_leaves=15, growth_policy=policy,
+                         quantized_grad=quantized, quant_warmup_iters=0)
+        one = meshlib.make_mesh({"data": 1}, devices=jax.devices()[:1])
+        with meshlib.default_mesh(one):
+            b1 = train_booster(X, y, cfg=cfg, **fit)
+        routed = _routed_rows(b1, X, cats)
+        cnt = np.asarray(b1.trees.node_cnt)
+        assert (cnt > 0).sum() >= 3 * b1.num_trees     # real trees grew
+        if quantized:
+            # the count channel is quantized with the gradients: a node's
+            # count is exact to the benchmark's count_gap limit, not the bit
+            assert np.max(np.abs(cnt - routed) / np.maximum(routed, 1)
+                          ) < 1e-4
+            with meshlib.default_mesh(one):
+                bf = train_booster(X, y, cfg=cfg._replace(
+                    quantized_grad=False), **fit)
+            l8, lf = _logloss(y, b1.predict(X)), _logloss(y, bf.predict(X))
+            assert abs(l8 - lf) < 0.02 * lf, (l8, lf)
+        else:
+            np.testing.assert_array_equal(cnt, routed)
+            b8 = train_booster(X, y, cfg=cfg, **fit)   # 8 virtual devices
+            assert np.allclose(b1.predict(X), b8.predict(X), atol=1e-5)
 
     def test_leaf_batch_budget_quality(self):
         # With a binding leaf budget the batched order may differ from

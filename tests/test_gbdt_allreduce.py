@@ -16,8 +16,7 @@ import pytest
 
 from mmlspark_tpu.models.gbdt import growth
 from mmlspark_tpu.models.gbdt.booster import LightGBMDataset, train_booster
-from mmlspark_tpu.models.gbdt.growth import (GrowConfig,
-                                             resolve_growth_backend)
+from mmlspark_tpu.models.gbdt.growth import GrowConfig
 from mmlspark_tpu.observability import metrics, spans
 from mmlspark_tpu.parallel import mesh as meshlib
 from mmlspark_tpu.parallel.compat import shard_map
@@ -165,9 +164,9 @@ def _grow_args(n):
 
 
 def _grow_fn(cfg, axis_name):
-    cfg = resolve_growth_backend(GrowConfig(
+    cfg = GrowConfig(
         num_leaves=7, num_bins=B, min_data_in_leaf=5, quantized_grad=True,
-        leaf_batch=4, **cfg))
+        leaf_batch=4, **cfg)
     grow = (growth.grow_tree_depthwise if cfg.growth_policy == "depthwise"
             else growth.grow_tree)
     is_cat = jnp.asarray([False] * (F - 1) + [True])

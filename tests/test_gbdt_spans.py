@@ -181,9 +181,9 @@ def lowered_text(dataset):
         ones = jnp.ones(n)
         texts = []
         for policy in ("leafwise", "depthwise"):
-            cfg = growth.resolve_growth_backend(growth.GrowConfig(
+            cfg = growth.GrowConfig(
                 num_leaves=4, num_bins=16, quantized_grad=True,
-                growth_policy=policy))
+                growth_policy=policy)
             grow = (growth.grow_tree if policy == "leafwise"
                     else growth.grow_tree_depthwise)
             texts.append(jax.jit(lambda b, g, h, v: grow(

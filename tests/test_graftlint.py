@@ -607,26 +607,11 @@ def test_trees_as_arguments(tmp_path):
 # --------------------------------------------------------------------------
 
 _BOOSTER_PIN_OK = """\
-    def resolve_growth_backend(cfg):
-        return cfg
-
     def resolve_predict_dtype(d):
         return d or "f32"
 
-    def resolve_hist_engine(r, f, b):
-        return ""
-
     def resolve_bucket_ladder():
         return ()
-
-    def _cached_program(key, build):
-        return build()
-
-    def train_booster(cfg):
-        hint = resolve_hist_engine(8, 8, 255)
-        cfg = resolve_growth_backend(cfg)
-        cache_key = (cfg,)
-        return _cached_program(cache_key, lambda: (cfg, hint))
 
     def predict_plan(self, n, predict_dtype=None):
         ladder = resolve_bucket_ladder()
@@ -635,21 +620,12 @@ _BOOSTER_PIN_OK = """\
         return key, ladder
 """
 
-_API_PIN_OK = """\
-    def resolve_growth_backend(cfg):
-        return cfg
-
-    def _grow_config(params):
-        return resolve_growth_backend(params)
-"""
-
 
 class TestResolveBeforeCacheKey:
     def test_general_env_read_after_key(self, tmp_path):
         active, suppressed = run_rule(
             tmp_path, "resolve-before-cache-key", {
                 "mmlspark_tpu/models/gbdt/booster.py": _BOOSTER_PIN_OK,
-                "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK,
                 "mmlspark_tpu/engine.py": """\
                     import os
 
@@ -675,32 +651,6 @@ class TestResolveBeforeCacheKey:
         assert "resolve_mode" in got[1].message
         assert [f.line for f in suppressed] == [10]
 
-    def test_anchored_pin_inversion(self, tmp_path):
-        inverted = _BOOSTER_PIN_OK.replace(
-            "        cfg = resolve_growth_backend(cfg)\n"
-            "        cache_key = (cfg,)",
-            "        cache_key = (cfg,)\n"
-            "        cfg = resolve_growth_backend(cfg)")
-        assert inverted != _BOOSTER_PIN_OK
-        active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": inverted,
-            "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK})
-        booster_hits = hits(active, "resolve-before-cache-key",
-                            "mmlspark_tpu/models/gbdt/booster.py")
-        assert booster_hits, active
-        assert any("before the first cache-key" in f.message
-                   or "before the key is built" in f.message
-                   for f in booster_hits)
-
-    def test_missing_grow_config_resolver(self, tmp_path):
-        api_bad = "def _grow_config(params):\n    return params\n"
-        active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": _BOOSTER_PIN_OK,
-            "mmlspark_tpu/models/gbdt/api.py": api_bad})
-        got = hits(active, "resolve-before-cache-key",
-                   "mmlspark_tpu/models/gbdt/api.py")
-        assert len(got) == 1 and "_grow_config" in got[0].message
-
     def test_predict_plan_pin_inversion(self, tmp_path):
         inverted = _BOOSTER_PIN_OK.replace(
             "        predict_dtype = resolve_predict_dtype(predict_dtype)\n"
@@ -709,8 +659,7 @@ class TestResolveBeforeCacheKey:
             "        predict_dtype = resolve_predict_dtype(predict_dtype)")
         assert inverted != _BOOSTER_PIN_OK
         active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": inverted,
-            "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK})
+            "mmlspark_tpu/models/gbdt/booster.py": inverted})
         got = hits(active, "resolve-before-cache-key",
                    "mmlspark_tpu/models/gbdt/booster.py")
         assert any("predict_plan's key assembly" in f.message
@@ -722,41 +671,10 @@ class TestResolveBeforeCacheKey:
             "")
         assert unresolved != _BOOSTER_PIN_OK
         active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": unresolved,
-            "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK})
+            "mmlspark_tpu/models/gbdt/booster.py": unresolved})
         got = hits(active, "resolve-before-cache-key",
                    "mmlspark_tpu/models/gbdt/booster.py")
         assert any("resolve_predict_dtype call missing" in f.message
-                   for f in got), active
-
-    def test_tuning_hist_pin_inversion(self, tmp_path):
-        inverted = _BOOSTER_PIN_OK.replace(
-            "        hint = resolve_hist_engine(8, 8, 255)\n"
-            "        cfg = resolve_growth_backend(cfg)\n"
-            "        cache_key = (cfg,)",
-            "        cfg = resolve_growth_backend(cfg)\n"
-            "        cache_key = (cfg,)\n"
-            "        hint = resolve_hist_engine(8, 8, 255)")
-        assert inverted != _BOOSTER_PIN_OK
-        active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": inverted,
-            "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK})
-        got = hits(active, "resolve-before-cache-key",
-                   "mmlspark_tpu/models/gbdt/booster.py")
-        assert any("tuning.resolve_hist_engine" in f.message
-                   and "before the first cache-key" in f.message
-                   for f in got), active
-
-    def test_tuning_hist_pin_missing_resolver(self, tmp_path):
-        unresolved = _BOOSTER_PIN_OK.replace(
-            "        hint = resolve_hist_engine(8, 8, 255)\n", "")
-        assert unresolved != _BOOSTER_PIN_OK
-        active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": unresolved,
-            "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK})
-        got = hits(active, "resolve-before-cache-key",
-                   "mmlspark_tpu/models/gbdt/booster.py")
-        assert any("resolve_hist_engine call missing" in f.message
                    for f in got), active
 
     def test_tuning_ladder_pin_inversion(self, tmp_path):
@@ -769,8 +687,7 @@ class TestResolveBeforeCacheKey:
             "        ladder = resolve_bucket_ladder()")
         assert inverted != _BOOSTER_PIN_OK
         active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": inverted,
-            "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK})
+            "mmlspark_tpu/models/gbdt/booster.py": inverted})
         got = hits(active, "resolve-before-cache-key",
                    "mmlspark_tpu/models/gbdt/booster.py")
         assert any("tuning.resolve_bucket_ladder" in f.message
@@ -784,8 +701,7 @@ class TestResolveBeforeCacheKey:
                                         "        return key")
         assert unresolved != _BOOSTER_PIN_OK
         active, _sup = run_rule(tmp_path, "resolve-before-cache-key", {
-            "mmlspark_tpu/models/gbdt/booster.py": unresolved,
-            "mmlspark_tpu/models/gbdt/api.py": _API_PIN_OK})
+            "mmlspark_tpu/models/gbdt/booster.py": unresolved})
         got = hits(active, "resolve-before-cache-key",
                    "mmlspark_tpu/models/gbdt/booster.py")
         assert any("resolve_bucket_ladder call missing" in f.message

@@ -7,8 +7,9 @@ histograms through the SAME ``histogram``/``histogram_cols``/
 ``node_histogram`` entry points: count channel exact, grad/hess to f32
 accumulation tolerance, int8 quantized stats exactly. Training on top of
 them must therefore grow bit-identical tree STRUCTURE. These tests pin
-all of that, plus the resolver rules, the ``hist_subtraction="auto"``
-tri-state, and the donated host-loop step buffers.
+all of that, plus the resolver rules (the one place the engine is chosen),
+the ``"auto"`` sentinel's absence from program cache keys, and the donated
+host-loop step buffers.
 """
 
 import subprocess
@@ -40,20 +41,27 @@ def _force_engine(monkeypatch, engine: str) -> None:
 
 
 class TestResolver:
-    def test_auto_on_cpu_is_scatter(self, monkeypatch):
-        monkeypatch.delenv("MMLSPARK_TPU_HIST_ENGINE", raising=False)
-        monkeypatch.delenv("MMLSPARK_TPU_PALLAS_INTERPRET", raising=False)
-        assert resolve_engine() == "scatter"
-
-    def test_auto_interpret_is_pallas(self, monkeypatch):
-        monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
-        monkeypatch.delenv("MMLSPARK_TPU_HIST_ENGINE", raising=False)
-        assert resolve_engine() == "pallas"
-
-    @pytest.mark.parametrize("engine", ENGINES)
-    def test_explicit_override(self, engine, monkeypatch):
-        _force_engine(monkeypatch, engine)
-        assert resolve_engine() == engine
+    # resolve_engine() is a function of MMLSPARK_TPU_HIST_ENGINE, the
+    # interpreter switch and the backend (the CPU here), nothing else
+    @pytest.mark.parametrize("env,interpret,want", [
+        (None, False, "scatter"), (None, True, "pallas"),
+        ("", False, "scatter"),
+        ("auto", False, "scatter"), ("auto", True, "pallas"),
+        ("scatter", False, "scatter"), ("scatter", True, "scatter"),
+        ("onehot", False, "onehot"), ("onehot", True, "onehot"),
+        ("pallas", True, "pallas"), (" Pallas ", True, "pallas"),
+    ])
+    def test_resolve_engine_table(self, env, interpret, want, monkeypatch):
+        monkeypatch.delenv("MMLSPARK_TPU_DISABLE_PALLAS_HIST", raising=False)
+        if env is None:
+            monkeypatch.delenv("MMLSPARK_TPU_HIST_ENGINE", raising=False)
+        else:
+            monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", env)
+        if interpret:
+            monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("MMLSPARK_TPU_PALLAS_INTERPRET", raising=False)
+        assert resolve_engine() == want
 
     def test_explicit_pallas_that_cannot_lower_raises(self, monkeypatch):
         # a pinned engine never silently becomes another one: no TPU
@@ -334,48 +342,12 @@ class TestTrainLevelEquivalence:
                                        err_msg=engine)
 
 
-class TestSubtractionAuto:
-    def test_resolves_concrete_before_cache(self):
-        from mmlspark_tpu.models.gbdt.growth import (GrowConfig,
-                                                     resolve_growth_backend)
-        r = resolve_growth_backend(GrowConfig())
-        assert isinstance(r.hist_subtraction, bool)
-        assert r.compact_selector in ("argsort", "searchsorted")
-        # idempotent
-        assert resolve_growth_backend(r) == r
-        # on the CPU test backend the auto default ENGAGES subtraction
-        # with the sort-free selector (docs/performance.md decision table)
-        assert r.hist_subtraction is True
-        assert r.compact_selector == "searchsorted"
-
-    def test_unresolved_sentinel_rejected_in_growth(self):
-        from mmlspark_tpu.models.gbdt.growth import GrowConfig, _use_subtraction
-        with pytest.raises(ValueError, match="auto"):
-            _use_subtraction(GrowConfig(), None, 10_000)
-
-    def test_bad_values_rejected(self):
-        from mmlspark_tpu.models.gbdt.growth import (GrowConfig,
-                                                     resolve_growth_backend)
-        with pytest.raises(ValueError, match="compact_selector"):
-            resolve_growth_backend(GrowConfig(compact_selector="quicksort"))
-        with pytest.raises(ValueError, match="hist_subtraction"):
-            resolve_growth_backend(GrowConfig(hist_subtraction="maybe"))
-
-    def test_estimator_accepts_legacy_bool_spellings(self):
-        # the tri-state param must keep the pre-tristate accepted inputs:
-        # 1/0/'true'/'false' coerce like to_bool, 'auto' passes through
-        from mmlspark_tpu.models.gbdt.api import LightGBMClassifier
-        for v, want in ((1, True), (0, False), ("true", True),
-                        ("false", False), ("auto", "auto"), (True, True)):
-            est = LightGBMClassifier(histSubtraction=v)
-            assert est.get_or_default("histSubtraction") == want, (v, want)
-            cfg = est._grow_config()
-            assert isinstance(cfg.hist_subtraction, bool), (v, cfg)
-
-    def test_sweep_fast_path_stays_eligible_under_auto_default(self):
-        # the vmapped sweep envelope must not be lost to the truthy "auto"
-        # sentinel: default-config estimators remain eligible; the
-        # engagement-threshold fallback lives in swept_fit (row count)
+class TestAutoSentinel:
+    @pytest.mark.parametrize("rows", [300, 9000])
+    def test_sweep_fast_path_eligible_at_any_row_count(self, rows):
+        # default-config estimators take the vmapped sweep whatever the row
+        # count (9000 rows used to fall back to sequential fits on the CPU,
+        # where the removed subtraction fork would have engaged)
         from mmlspark_tpu.automl.sweep import _eligible, swept_fit
         from mmlspark_tpu.core.dataset import Dataset
         from mmlspark_tpu.models.gbdt.api import LightGBMClassifier
@@ -385,14 +357,33 @@ class TestSubtractionAuto:
         maps = [{"learningRate": 0.1}, {"learningRate": 0.3}]
         assert _eligible(est, maps)
         rng = np.random.default_rng(0)
-        X = rng.normal(size=(300, 4)).astype(np.float32)
+        X = rng.normal(size=(rows, 4)).astype(np.float32)
         y = (X[:, 0] > 0).astype(np.float64)
         models = swept_fit(est, maps, Dataset({"features": X, "label": y}))
         assert models is not None and len(models) == 2
 
+    @pytest.mark.parametrize("make", ["estimator", "GrowConfig"])
+    def test_removed_subtraction_options_are_refused(self, make):
+        # histSubtraction / compactSelector and their GrowConfig fields went
+        # with the row-compaction fork (docs/migration.md): asking for them
+        # is an error that names what was asked for, never a silent no-op
+        from mmlspark_tpu.models.gbdt.api import LightGBMClassifier
+        from mmlspark_tpu.models.gbdt.growth import GrowConfig
+        if make == "estimator":
+            with pytest.raises(AttributeError, match="histSubtraction"):
+                LightGBMClassifier(histSubtraction=True)
+            with pytest.raises(AttributeError, match="compactSelector"):
+                LightGBMClassifier(compactSelector="argsort")
+        else:
+            with pytest.raises(TypeError, match="hist_subtraction"):
+                GrowConfig(hist_subtraction=True)
+            with pytest.raises(TypeError, match="compact_selector"):
+                GrowConfig(compact_selector="argsort")
+
     def test_no_auto_in_step_cache_keys(self):
-        # runtime version of the lint rule: fit with the tri-state default
-        # and prove no unresolved sentinel reached a compiled-program key
+        # runtime version of the lint rule: fit with the defaults
+        # (hist_blocks="auto") and prove no unresolved sentinel reached a
+        # compiled-program key
         from mmlspark_tpu.models.gbdt import booster as B
         from mmlspark_tpu.models.gbdt.booster import train_booster
 

@@ -7,12 +7,14 @@ shim runs the full pass as one parameterized test per rule, so a
 violation fails tier-1 with the exact rule id and file:line — identical
 coverage, one engine, one parse per file.
 
-The only guard that stays here is the *runtime* complement of
-``obs-import-cycle``: a fresh interpreter importing the telemetry layer
-standalone, proving the static rule's conclusion (no jax, no framework)
-against the real import system.
+The guards that stay here: the *runtime* complement of
+``obs-import-cycle`` (a fresh interpreter importing the telemetry layer
+standalone, proving the static rule's conclusion — no jax, no framework —
+against the real import system), and the layering of the histogram choice:
+tree growth and the kernel module do not ask the tuning plane anything.
 """
 
+import ast
 import os
 import subprocess
 import sys
@@ -55,6 +57,29 @@ def test_observability_imports_standalone():
         capture_output=True, text=True, timeout=120, cwd=ROOT)
     assert proc.returncode == 0, proc.stderr
     assert "lint_total 1" in proc.stdout
+
+
+@pytest.mark.parametrize("module", [
+    "mmlspark_tpu/models/gbdt/growth.py", "mmlspark_tpu/ops/histogram.py"])
+def test_histogram_layers_do_not_import_tuning(module):
+    """One histogram design, one engine choice (``resolve_engine``: the
+    environment and the backend): neither layer imports ``mmlspark_tpu
+    .tuning``, at module level or inside a function."""
+    with open(os.path.join(ROOT, module)) as f:
+        tree = ast.parse(f.read())
+    package = module.split("/")[:-1]                  # for relative imports
+    imported = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported += [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - node.level + 1] if node.level \
+                else []
+            base = ".".join(base + ([node.module] if node.module else []))
+            imported += [base] + [f"{base}.{a.name}" for a in node.names]
+    assert not [m for m in imported
+                if m == "mmlspark_tpu.tuning"
+                or m.startswith("mmlspark_tpu.tuning.")], imported
 
 
 if __name__ == "__main__":

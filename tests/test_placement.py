@@ -153,7 +153,7 @@ class TestHistBlocksResolution:
     def test_resolved_value_keys_the_config(self):
         """hist_blocks rides GrowConfig, so it reaches every
         compiled-program cache key for free — but it must be CONCRETE by
-        growth time (same contract as hist_subtraction='auto')."""
+        growth time."""
         import jax.numpy as jnp
 
         from mmlspark_tpu.models.gbdt.growth import (

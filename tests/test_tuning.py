@@ -5,7 +5,7 @@ measure→decide loop's load-bearing contracts, each pinned here:
   observation sequence replayed into two fresh store directories writes
   BYTE-IDENTICAL ``tuning.json`` files;
 * the second process warm-starts: every decision read back from the
-  store resolves with ``source=store`` and zero re-calibration;
+  store resolves with ``source=store`` and is not decided again;
 * a fingerprint-skewed (or unreadable) store degrades LOUDLY to the
   static rules — flight event + ``tuning_store_degraded_total`` — and
   is never overwritten by the degraded process;
@@ -50,9 +50,8 @@ def _clean(monkeypatch):
     metrics.set_enabled(prev)
 
 
-#: deterministic fake calibration wall times — scatter wins by far more
-#: than ENGINE_WIN_MARGIN, so the decision is stable under replay
-_ENGINE_TIMES = {"scatter": 0.0010, "onehot": 0.0050}
+#: the ladder ``_drive_full_ledger``'s batch sizes decide
+_LADDER = (1, 2, 4, 8, 40, 104)
 
 
 def _tuning_events(**match):
@@ -68,9 +67,6 @@ def _drive_full_ledger(store_dir):
     tuning.reset()
     tuning.configure(store_dir=str(store_dir))
     assert tuning.enabled()
-    choice = tuning.resolve_hist_engine(
-        500, 6, 255, ("onehot", "scatter"),
-        measure=lambda eng: _ENGINE_TIMES[eng])
     tuning.note_slot_geometry(row_bytes=24, max_batch=512)
     tuning.observe_score(0.004)
     tuning.observe_score(0.0044)
@@ -78,7 +74,7 @@ def _drive_full_ledger(store_dir):
     for n in (3, 5, 37, 37, 100) * 8:
         tuning.observe_batch_size(n)
     tuning.flush()
-    return choice
+    return tuning.resolve_bucket_ladder()
 
 
 class TestDecisionFunctions:
@@ -105,18 +101,6 @@ class TestDecisionFunctions:
         assert decisions.ladder_pad(40, ladder) == 40
         # out-of-distribution batches keep the static pow2 behavior
         assert decisions.ladder_pad(105, ladder) == 128
-
-    def test_hist_engine_margin(self):
-        clear_win = {"a": {"ewma_seconds": 0.10, "samples": 1},
-                     "b": {"ewma_seconds": 0.05, "samples": 1}}
-        assert decisions.decide_hist_engine(clear_win) == "b"
-        # a 2% win is inside the noise margin: keep the static rule
-        noise = {"a": {"ewma_seconds": 0.100, "samples": 1},
-                 "b": {"ewma_seconds": 0.098, "samples": 1}}
-        assert decisions.decide_hist_engine(noise) is None
-        # fewer than two timed engines cannot support a decision
-        assert decisions.decide_hist_engine(
-            {"a": {"ewma_seconds": 0.1, "samples": 1}}) is None
 
     def test_percentile_nearest_rank(self):
         counts = {"1": 50, "10": 49, "1000": 1}
@@ -160,53 +144,9 @@ class TestDecisionFunctions:
             "memory", 0.0005, 0.0008, 3.0, 32, 0.002) == 0.0
 
 
-class TestHistEngineCalibration:
-    def test_one_calibration_round_per_candidate(self, tmp_path):
-        tuning.configure(store_dir=str(tmp_path))
-        calls = []
-
-        def measure(eng):
-            calls.append(eng)
-            return _ENGINE_TIMES[eng]
-
-        choice = tuning.resolve_hist_engine(500, 6, 255,
-                                            ("onehot", "scatter"),
-                                            measure=measure)
-        assert choice == "scatter"
-        assert calls == ["onehot", "scatter"]
-        assert len(_tuning_events(event="calibrate")) == 2
-        # the decision is pinned: a second resolve re-measures nothing
-        choice2 = tuning.resolve_hist_engine(500, 6, 255,
-                                             ("onehot", "scatter"),
-                                             measure=measure)
-        assert choice2 == "scatter" and calls == ["onehot", "scatter"]
-        assert (tmp_path / store.STORE_NAME).exists()
-
-    def test_noise_margin_keeps_static(self, tmp_path):
-        tuning.configure(store_dir=str(tmp_path))
-        times = {"a": 0.100, "b": 0.099}
-        assert tuning.resolve_hist_engine(
-            64, 8, 63, ("a", "b"), measure=lambda e: times[e]) is None
-        applied = _tuning_events(site="hist_engine")
-        assert applied and applied[-1]["choice"] == "static"
-
-    def test_failed_candidate_drops_out(self, tmp_path):
-        tuning.configure(store_dir=str(tmp_path))
-
-        def measure(eng):
-            if eng == "onehot":
-                raise RuntimeError("cannot lower here")
-            return 0.001
-
-        # only one candidate timed → below the evidence bar → static
-        assert tuning.resolve_hist_engine(
-            500, 6, 255, ("onehot", "scatter"), measure=measure) is None
-        assert len(_tuning_events(event="calibrate_failed")) == 1
-
+class TestDisabled:
     def test_disabled_without_store_dir(self):
         assert not tuning.enabled()
-        assert tuning.resolve_hist_engine(
-            64, 8, 63, ("a", "b"), measure=lambda e: 0.001) is None
         assert tuning.resolve_bucket_ladder() is None
         assert tuning.resolve_hold_window() == 0.0
         assert tuning.resolve_slots_auto(64) is None
@@ -219,18 +159,17 @@ class TestStoreDeterminism:
         monkeypatch.setenv("MMLSPARK_TPU_TUNE_MIN_SAMPLES", "16")
         c1 = _drive_full_ledger(tmp_path / "a")
         c2 = _drive_full_ledger(tmp_path / "b")
-        assert c1 == c2 == "scatter"
+        assert c1 == c2 == _LADDER
         b1 = (tmp_path / "a" / store.STORE_NAME).read_bytes()
         b2 = (tmp_path / "b" / store.STORE_NAME).read_bytes()
         assert b1 == b2
         payload = json.loads(b1)
         assert payload["format_version"] == store.FORMAT_VERSION
         dec = payload["decisions"]
-        assert dec["bucket_ladder"]["choice"] == [1, 2, 4, 8, 40, 104]
+        assert dec["bucket_ladder"]["choice"] == list(_LADDER)
+        assert dec["bucket_ladder"]["source"] == "measured"
         assert dec["slots"]["choice"] == 128       # p99.9=100 → pow2
         assert "hold_window" in dec
-        assert dec["hist_engine/r512f8b255"]["choice"] == "scatter"
-        assert dec["hist_engine/r512f8b255"]["source"] == "calibration"
 
     def test_second_process_warm_starts_from_store(self, tmp_path,
                                                    monkeypatch):
@@ -240,26 +179,20 @@ class TestStoreDeterminism:
         tuning.reset()
         flight.clear()
         tuning.configure(store_dir=str(tmp_path))
-
-        def boom(eng):
-            raise AssertionError("warm process must not re-calibrate")
-
-        choice = tuning.resolve_hist_engine(500, 6, 255,
-                                            ("onehot", "scatter"),
-                                            measure=boom)
-        assert choice == "scatter"
-        assert _tuning_events(event="calibrate") == []
-        applied = _tuning_events(site="hist_engine")
+        # batches of another shape arrive: a decision read back is pinned,
+        # the warm process must not decide again
+        for _ in range(40):
+            tuning.observe_batch_size(200)
+        assert tuning.resolve_bucket_ladder() == _LADDER
+        applied = _tuning_events(site="bucket_ladder")
         assert applied and applied[-1]["source"] == "store"
-        assert tuning.resolve_bucket_ladder() == (1, 2, 4, 8, 40, 104)
         assert tuning.resolve_slots_auto(512) == 128
         assert metrics.counter("tuning_decisions_total",
-                               site="hist_engine",
-                               choice="scatter").value >= 1.0
+                               site="bucket_ladder",
+                               choice=str(_LADDER)).value >= 1.0
         prov = tuning.provenance()
         assert prov["status"] == "ok"
-        assert prov["bucket_ladder"] == [1, 2, 4, 8, 40, 104]
-        assert tuning.growth_tristate_hint() == "scatter"
+        assert prov["bucket_ladder"] == list(_LADDER)
 
     def test_hold_env_pin_overrides_store(self, tmp_path, monkeypatch):
         tuning.configure(store_dir=str(tmp_path))
@@ -286,8 +219,6 @@ class TestStoreDegrade:
         metrics.reset()
         tuning.configure(store_dir=str(tmp_path))
         # every resolver answers static — no behavior change
-        assert tuning.resolve_hist_engine(
-            500, 6, 255, ("onehot", "scatter")) is None
         assert tuning.resolve_bucket_ladder() is None
         assert tuning.resolve_slots_auto(512) is None
         assert tuning.resolve_hold_window() == 0.0
@@ -318,7 +249,7 @@ class TestStoreDegrade:
 
 
 class TestHoldBurnBypass:
-    """Dispatch pacing (site 3) against a live SLO plane: a breaching
+    """Dispatch pacing (site 2) against a live SLO plane: a breaching
     endpoint is NEVER held — its latency budget is already gone."""
 
     def _server(self):

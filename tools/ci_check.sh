@@ -573,11 +573,11 @@ EOF
 fi
 
 # tuning smoke lane: the measure→decide loop across two processes — the
-# first process calibrates the histogram engine (one real round per
-# candidate) and persists the decision to a shared store; the second
-# process warm-starts the same knob from the store with ZERO calibration
-# runs, and the snapshot (/debug/tuning's payload) reports the decision
-# with its per-engine evidence.
+# first process observes a serving batch-size histogram, decides the
+# predict bucket ladder at the evidence bar and persists it to a shared
+# store; the second process warm-starts the same knob from the store
+# (source=store) without deciding again, and the snapshot (/debug/tuning's
+# payload) reports the decision with its evidence.
 if [ "${CI_SKIP_TUNING:-0}" != "1" ]; then
     if (cd "$ROOT" && env JAX_PLATFORMS=cpu \
             python - <<'EOF'
@@ -589,54 +589,46 @@ import tempfile
 
 SNIPPET = r'''
 import json
-import numpy as np
-from mmlspark_tpu.models.gbdt.booster import train_booster
-from mmlspark_tpu.models.gbdt.growth import GrowConfig
+import sys
 from mmlspark_tpu.observability import flight
 from mmlspark_tpu import tuning
 
-rng = np.random.default_rng(0)
-X = rng.normal(size=(600, 6)).astype(np.float32)
-y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
-train_booster(X=X, y=y, num_iterations=2, objective="binary",
-              cfg=GrowConfig(num_leaves=7, min_data_in_leaf=5))
-events = [e for e in flight.events() if e.get("kind") == "tuning"]
-cal = [e for e in events if e.get("event") == "calibrate"]
-dec = [(e["choice"], e["source"]) for e in events
-       if e.get("site") == "hist_engine" and e.get("choice")
-       and e["choice"] != "static"]
-print(json.dumps({"calibrations": len(cal), "decisions": dec,
+if sys.argv[1] == "observe":
+    for n in (3, 5, 37, 37, 100) * 8:
+        tuning.observe_batch_size(n)
+    tuning.flush()
+ladder = tuning.resolve_bucket_ladder()
+dec = [(e["choice"], e["source"]) for e in flight.events()
+       if e.get("kind") == "tuning" and e.get("site") == "bucket_ladder"]
+print(json.dumps({"ladder": ladder, "decisions": dec,
                   "snapshot": tuning.snapshot_payload()}))
 '''
 
 with tempfile.TemporaryDirectory() as d:
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MMLSPARK_TPU_TUNING_DIR=d)
+               MMLSPARK_TPU_TUNING_DIR=d,
+               MMLSPARK_TPU_TUNE_MIN_SAMPLES="16")
 
-    def run():
-        p = subprocess.run([sys.executable, "-c", SNIPPET], env=env,
+    def run(mode):
+        p = subprocess.run([sys.executable, "-c", SNIPPET, mode], env=env,
                            capture_output=True, text=True, timeout=600)
         assert p.returncode == 0, p.stderr[-2000:]
         return json.loads(p.stdout.splitlines()[-1])
 
-    first = run()
-    assert first["calibrations"] >= 2, first  # one round per candidate
+    first = run("observe")
+    assert first["ladder"] == [1, 2, 4, 8, 40, 104], first
     assert first["decisions"] and all(
-        src == "calibration" for _c, src in first["decisions"]), first
+        src == "measured" for _c, src in first["decisions"]), first
     assert os.path.exists(os.path.join(d, "tuning.json")), os.listdir(d)
 
-    second = run()
-    assert second["calibrations"] == 0, second  # zero re-calibration
+    second = run("serve")
+    assert second["ladder"] == first["ladder"], (first, second)
     assert second["decisions"] and all(
         src == "store" for _c, src in second["decisions"]), second
-    assert [c for c, _s in second["decisions"]] == \
-        [c for c, _s in first["decisions"]], (first, second)
     snap = second["snapshot"]
-    site = next(k for k in snap["decisions"]
-                if k.startswith("hist_engine/"))
-    assert snap["decisions"][site].get("evidence"), snap["decisions"][site]
-print("tuning smoke: first process calibrated and persisted, second "
-      "process warm-started from the store with zero calibration")
+    assert snap["decisions"]["bucket_ladder"].get("evidence"), snap
+print("tuning smoke: first process decided the bucket ladder and persisted "
+      "it, second process warm-started from the store")
 EOF
     ); then
         :

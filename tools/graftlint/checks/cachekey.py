@@ -8,12 +8,9 @@ PR 4 incident class.
 
 Two parts, one rule (``resolve-before-cache-key``):
 
-1. **The anchored pin** (migrated from
-   ``test_auto_sentinel_resolved_before_program_cache_keys``):
-   ``train_booster`` must call ``resolve_growth_backend`` before its
-   first cache-key construction, and the estimator layer's
-   ``_grow_config`` must route through the resolver at all (the sweep
-   path bypasses ``train_booster``).
+1. **The anchored pins**: ``Booster.predict_plan`` is THE predictor-key
+   site, and what decides its key (the predict dtype lane, the tuned
+   bucket ladder) must be resolved before the key tuple is assembled.
 2. **The general analysis**: in ANY package function, an ``os.environ``
    read or a ``resolve_*()`` call *lexically after* the function's first
    cache-key construction (an assignment to a ``*cache_key*`` name, a
@@ -35,7 +32,6 @@ from ..core import (Checker, CheckerRotError, Finding, Module, Repo,
 
 _CACHE_NAME_RE = re.compile(r".*_CACHE$")
 _BOOSTER = "mmlspark_tpu/models/gbdt/booster.py"
-_API = "mmlspark_tpu/models/gbdt/api.py"
 
 
 def _is_cache_key_construction(node: ast.AST) -> bool:
@@ -115,45 +111,14 @@ class ResolveBeforeCacheKey(Checker):
 
     def _anchored_pin(self, repo: Repo) -> Iterator[Finding]:
         booster = repo.module(_BOOSTER)
-        api = repo.module(_API)
-        if booster is None or api is None:
-            raise CheckerRotError("models/gbdt/{booster,api}.py moved")
-        tb = next((n for n in ast.walk(booster.tree)
-                   if isinstance(n, ast.FunctionDef)
-                   and n.name == "train_booster"), None)
-        if tb is None:
-            raise CheckerRotError("train_booster vanished from booster.py")
-
-        def is_growth_resolver(n: ast.AST) -> bool:
-            return (isinstance(n, ast.Call)
-                    and isinstance(n.func, ast.Name)
-                    and n.func.id == "resolve_growth_backend")
-
-        resolver_ln = first_lineno(tb, is_growth_resolver)
-        cache_ln = first_lineno(tb, _is_cache_key_construction)
-        if cache_ln is None:
-            raise CheckerRotError(
-                "train_booster no longer constructs a cache key — "
-                "anchored pin matches nothing")
-        if resolver_ln is None:
-            yield self.finding(
-                booster, tb.lineno,
-                "train_booster no longer resolves the 'auto' tri-states "
-                "(resolve_growth_backend call missing)")
-        elif resolver_ln >= cache_ln:
-            yield self.finding(
-                booster, resolver_ln,
-                f"resolve_growth_backend (line {resolver_ln}) must run "
-                f"before the first cache-key construction "
-                f"(line {cache_ln})")
-
-        # same pin, second resolver: predict_plan is THE predictor-key
-        # site (booster hot path + bundle builder both call it), and the
-        # dtype lane must be resolved through the quantize funnel before
-        # the key tuple is assembled. Note the key here is a plain
-        # ``key = (...)`` assignment — _is_cache_key_construction only
-        # matches ``*cache_key*`` names / _CACHE subscripts, so the pin
-        # carries its own predicate.
+        if booster is None:
+            raise CheckerRotError("models/gbdt/booster.py moved")
+        # predict_plan is THE predictor-key site (booster hot path + bundle
+        # builder both call it), and the dtype lane must be resolved
+        # through the quantize funnel before the key tuple is assembled.
+        # Note the key here is a plain ``key = (...)`` assignment —
+        # _is_cache_key_construction only matches ``*cache_key*`` names /
+        # _CACHE subscripts, so the pin carries its own predicate.
         pp = next((n for n in ast.walk(booster.tree)
                    if isinstance(n, ast.FunctionDef)
                    and n.name == "predict_plan"), None)
@@ -187,32 +152,9 @@ class ResolveBeforeCacheKey(Checker):
                 f"resolve_predict_dtype (line {pp_resolver_ln}) must run "
                 f"before predict_plan's key assembly (line {pp_key_ln})")
 
-        # tuning resolvers (PR 19): the auto-tuner's measured decisions
-        # flow INTO the keys — the hist-engine hint keys the train step
-        # cache through resolve_engine(), and the measured bucket ladder
-        # decides predict_plan's n_pad — so both resolve_* calls must
-        # run strictly before their key is assembled. A hint installed
-        # after the key would alias tuned and untuned programs under one
-        # entry (the exact incident class this rule exists for).
-        def is_tuning_hist_resolver(n: ast.AST) -> bool:
-            return (isinstance(n, ast.Call)
-                    and call_name(n)[1] == "resolve_hist_engine")
-
-        th_ln = first_lineno(tb, is_tuning_hist_resolver)
-        if th_ln is None:
-            yield self.finding(
-                booster, tb.lineno,
-                "train_booster no longer consults the auto-tuner's "
-                "measured histogram engine (tuning.resolve_hist_engine "
-                "call missing) — the hint keys the step cache via "
-                "resolve_engine() and must be installed before the key")
-        elif th_ln >= cache_ln:
-            yield self.finding(
-                booster, th_ln,
-                f"tuning.resolve_hist_engine (line {th_ln}) must run "
-                f"before the first cache-key construction "
-                f"(line {cache_ln})")
-
+        # the tuned bucket ladder (PR 19) decides predict_plan's n_pad,
+        # which joins the key: resolved after the key it would alias tuned
+        # and pow2 programs under one entry
         def is_ladder_resolver(n: ast.AST) -> bool:
             return (isinstance(n, ast.Call)
                     and call_name(n)[1] == "resolve_bucket_ladder")
@@ -230,18 +172,6 @@ class ResolveBeforeCacheKey(Checker):
                 booster, pl_ln,
                 f"tuning.resolve_bucket_ladder (line {pl_ln}) must run "
                 f"before predict_plan's key assembly (line {pp_key_ln})")
-
-        gc = next((n for n in ast.walk(api.tree)
-                   if isinstance(n, ast.FunctionDef)
-                   and n.name == "_grow_config"), None)
-        if gc is None:
-            raise CheckerRotError("_grow_config vanished from api.py")
-        if first_lineno(gc, is_growth_resolver) is None:
-            yield self.finding(
-                api, gc.lineno,
-                "_grow_config must resolve 'auto' before handing "
-                "GrowConfig to direct consumers (the sweep path bypasses "
-                "train_booster)")
 
 
 register(ResolveBeforeCacheKey())
