@@ -109,6 +109,11 @@ def layout_counts() -> dict:
                          ("folded", "plain"))
 
 
+def onehot_counts() -> dict:
+    return _label_counts("hist_kernel_onehot_total", "onehot",
+                         ("packed", "compare"))
+
+
 # ---------------------------------------------------------------------------
 # phases
 # ---------------------------------------------------------------------------
@@ -211,7 +216,7 @@ def _kernel_variant(binned_t, pos, stats, scales, W, B, toy) -> None:
         return lowered, {k: v - before[k]
                          for k, v in engine_counts().items()}
 
-    layouts = layout_counts()
+    layouts, onehots = layout_counts(), onehot_counts()
     lowered, picked = lower()
     if picked != {"pallas": 1, "onehot": 0, "scatter": 0}:
         raise RuntimeError(f"{tag}: engine selection {picked}, not pallas")
@@ -221,6 +226,13 @@ def _kernel_variant(binned_t, pos, stats, scales, W, B, toy) -> None:
     layout = "folded" if 128 < B <= 256 and 3 * W <= 64 else "plain"
     if layouts != {"folded": 0, "plain": 0, layout: 1}:
         raise RuntimeError(f"{tag}: kernel layout {layouts}, not {layout}")
+    onehots = {k: v - onehots[k] for k, v in onehot_counts().items()}
+    # int8 statistics and every value of a tile under 128: the folded tile
+    # (bin & 127) or a plain kernel at up to 128 bins
+    onehot = ("packed" if quantized and (layout == "folded" or B <= 128)
+              else "compare")
+    if onehots != {"packed": 0, "compare": 0, onehot: 1}:
+        raise RuntimeError(f"{tag}: one-hot build {onehots}, not {onehot}")
     interpret = H._interpret_mode()
     mosaic = "tpu_custom_call" in lowered.as_text()
     if not toy and (interpret or not mosaic):
@@ -241,7 +253,8 @@ def _kernel_variant(binned_t, pos, stats, scales, W, B, toy) -> None:
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4, err_msg=tag)
     rb = H._pick_row_block(n, F, 3 * W, B, fused_w=W, quantized=quantized)
     print(f"kernel {tag}: engine=pallas interpret={interpret} "
-          f"mosaic={mosaic} row_block={rb} layout={layout} count_exact=True "
+          f"mosaic={mosaic} row_block={rb} layout={layout} onehot={onehot} "
+          f"count_exact=True "
           f"max_abs_err={np.abs(got - want).max():.3e}", flush=True)
 
 

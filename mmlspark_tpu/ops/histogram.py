@@ -519,6 +519,18 @@ def _pick_row_block(n: int, F: int, S: int, B: int, fused_w: int = 0,
     one-hot at all: the unrolled features' tiles live and die in vregs.
     So it charges the stats stacked twice as 32-bit words and, for spills,
     one one-hot tile and one folded operand, not eight.
+
+    A plain kernel of one tile a feature group (B <= 128) never needed the
+    eight buffers either, with the int32 compare or with the packed words
+    that int8 statistics get since (:func:`_onehot_packed`): same compiler
+    and table, RB=8192, the smallest limit that compiles is 4.75 MB at 63
+    bins W=16, 1.25 MB at W=1 and 5.75 MB at 128 bins W=16 for both
+    builds; packed, 8.5 MB at 63 bins W=31 and 4.75 MB at 31 bins W=16.
+    The eight were the two-tile 255-bin kernel's. The charge stays as
+    headroom: it takes 8192 rows at every shape of the cells already, and
+    dropping it would only double the block, and with it the unrolled
+    program the compiler has to schedule, for wide tables at frontiers
+    past 40 nodes, with no pass measured there.
     """
     BP, P = _bin_packing(B)
     Fp = -(-F // P) * P
@@ -617,23 +629,13 @@ def _hist_group_dot(o_ref, b_ref, sb, g, BP: int, P: int, acc):
     per pass at 1M rows x 28 features x 255 bins, with pass time flat in
     both bin count and stats dtype (the signature of a non-MXU bottleneck).
     """
+    # widen narrow bin storage (uint8/int16) per block, in VMEM only
+    rows = [b_ref[g * P + p, :].astype(jnp.int32) for p in range(P)]
+    h = lax.dot_general(sb, _onehot_tile(rows, BP, sb.dtype),
+                        (((1,), (1,)), ((), ())), preferred_element_type=acc)
     if P == 1:
-        # widen narrow bin storage (uint8/int16) per block, in VMEM only
-        row = b_ref[g, :].astype(jnp.int32)         # [RB], rows on lanes
-        bins = lax.broadcasted_iota(jnp.int32, (BP, row.shape[0]), 0)
-        oht = (row[None, :] == bins).astype(sb.dtype)      # [BP, RB]
-        h = lax.dot_general(sb, oht, (((1,), (1,)), ((), ())),
-                            preferred_element_type=acc)
         o_ref[g] += h
     else:
-        pieces = []
-        for p in range(P):
-            row = b_ref[g * P + p, :].astype(jnp.int32)
-            bins = lax.broadcasted_iota(jnp.int32, (BP, row.shape[0]), 0)
-            pieces.append((row[None, :] == bins).astype(sb.dtype))
-        oht = jnp.concatenate(pieces, axis=0)       # [P*BP, RB] = 128 sublanes
-        h = lax.dot_general(sb, oht, (((1,), (1,)), ((), ())),
-                            preferred_element_type=acc)
         for p in range(P):
             o_ref[g * P + p] += h[:, p * BP:(p + 1) * BP]
 
@@ -652,23 +654,72 @@ def _stack_stats_words(sb, k: int, rows: int):
     return jnp.concatenate(parts, axis=0)
 
 
-def _onehot_lo(lo, dtype):
-    """Transposed one-hot ``[128, RB]`` of ``lo`` in [0, 128).
+def _onehot_packed(dtype, BP: int, P: int) -> bool:
+    """The tile-builder rule, from static shapes alone: True builds the
+    one-hot tile of ``P`` features at ``BP`` sublanes each on packed 32-bit
+    words (:func:`_onehot_tile`), False compares in int32.
 
-    int8 builds it on packed words, four bins a word: with both operands
-    under 128, a byte of ``0x80808080 - (lo4 ^ bins4)`` keeps its top bit
-    exactly where bin and row agree and no borrow crosses a byte, so a tile
-    costs 4 VALU operations a vreg (xor, sub, shift, and) where the int32
-    compare, select and two packs cost 13. The words' bins are read back
-    off :func:`pltpu.bitcast`'s own layout (word i, byte b = row 4 i + b).
-    bf16 gains nothing that way (5 operations either way) and compares."""
-    if dtype != jnp.int8:
-        bins = lax.broadcasted_iota(jnp.int32, (128, lo.shape[0]), 0)
-        return (lo[None, :] == bins).astype(dtype)
-    word = lax.broadcasted_iota(jnp.int32, (32, lo.shape[0]), 0)
-    bins4 = word * 0x04040404 + 0x03020100
-    lo4 = (lo * 0x01010101)[None, :]
-    hit = jnp.int32(-0x7F7F7F80) - (lo4 ^ bins4)    # 0x80808080 - x
+    Packed wants int8 statistics (bf16 costs 5 VALU operations a vreg
+    either way and compares) and every value of the tile under 128, i.e.
+    exactly one 128-sublane tile, ``BP * P == 128``: packed features at 8
+    to 64 bins, one feature at 65 to 128, and the folded layout's
+    ``bin & 127``. The plain layout at ``BP >= 256`` (W > 21 at 255 bins,
+    int16/int32 bins past 256) compares.
+    """
+    return jnp.dtype(dtype) == jnp.int8 and BP * P == 128
+
+
+def _onehot_tile(rows, BP: int, dtype):
+    """Transposed one-hot ``[P * BP, RB]`` of ``P = len(rows)`` bin rows:
+    sublane ``p * BP + b`` is hot where ``rows[p] == b``. THE tile builder
+    of both layouts (the folded one's call is ``P = 1`` on ``bin & 127``).
+
+    Where :func:`_onehot_packed`, it is built on packed words, four
+    sublanes a word: with a bin and a sublane's own bin index both under
+    128, a byte of ``0x80808080 - (row4 ^ index4)`` keeps its top bit
+    exactly where they agree and no borrow crosses a byte, so a tile costs
+    4 VALU operations a vreg (xor, sub, shift, and) where the int32
+    compare, select and two packs cost 13. Word ``w`` of the 32 compares
+    the feature that owns it, ``w // (BP // 4)``, against the indices
+    ``4 (w % (BP // 4)) + byte``: whole 8-sublane word tiles up to
+    ``P = 4``, a select between the owners inside a word tile beyond. The
+    words' sublanes are read back off :func:`pltpu.bitcast`'s own layout
+    (word i, byte b = sublane 4 i + b).
+
+    Contract: ``0 <= rows[p] < 128``, which ``B <= BP <= 128`` gives.
+    Packed or compared, a bin in ``[B, BP)`` lands on its own feature's
+    padding sublanes (:func:`_to_hist` slices them off) and one in
+    ``[BP, 128)`` matches nothing; past 127 only the compare still matches
+    nothing, the packed bytes would borrow from their neighbours. Padding
+    features (:func:`_pad_features_to`) bin to 0 and padding rows carry
+    zero statistics.
+    """
+    P, RB = len(rows), rows[0].shape[0]
+    if not _onehot_packed(dtype, BP, P):
+        bins = lax.broadcasted_iota(jnp.int32, (BP, RB), 0)
+        pieces = [(row[None, :] == bins).astype(dtype) for row in rows]
+        return pieces[0] if P == 1 else jnp.concatenate(pieces, axis=0)
+    rows4 = [row * 0x01010101 for row in rows]
+    wpf = BP // 4                                   # words a feature
+    if wpf >= 8:
+        tiles = [jnp.broadcast_to(r[None, :], (wpf, RB)) for r in rows4]
+    else:
+        # several owners inside one 8-sublane word tile: select between them
+        word = lax.broadcasted_iota(jnp.int32, (8, RB), 0)
+        tiles = []
+        for t in range(0, P, 8 // wpf):
+            owners = rows4[t:t + 8 // wpf]
+            tile = jnp.broadcast_to(owners[-1][None, :], (8, RB))
+            for i in range(len(owners) - 2, -1, -1):
+                tile = jnp.where(word < (i + 1) * wpf, owners[i][None, :],
+                                 tile)
+            tiles.append(tile)
+    row4 = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
+    word = lax.broadcasted_iota(jnp.int32, (32, RB), 0)
+    if P > 1:
+        word &= wpf - 1                             # index inside its owner
+    index4 = word * 0x04040404 + 0x03020100
+    hit = jnp.int32(-0x7F7F7F80) - (row4 ^ index4)      # 0x80808080 - x
     return pltpu.bitcast(lax.shift_right_logical(hit, 7) & 0x01010101,
                          jnp.int8)
 
@@ -680,22 +731,29 @@ def _hist_fold_dot(o_ref, b_ref, words, g, k: int, dtype, acc):
     copy = (lax.broadcasted_iota(jnp.int32, words.shape, 0) >= k
             ).astype(jnp.int32)
     a = jnp.where(copy == (row >> 7)[None, :], words, 0)
-    h = lax.dot_general(pltpu.bitcast(a, dtype), _onehot_lo(row & 127, dtype),
+    h = lax.dot_general(pltpu.bitcast(a, dtype),
+                        _onehot_tile([row & 127], 128, dtype),
                         (((1,), (1,)), ((), ())), preferred_element_type=acc)
     o_ref[g] += h
 
 
-def _stage_layout(B: int, S: int, Fp: int, itemsize: int):
+def _stage_layout(B: int, S: int, Fp: int, dtype):
     """(fold_k, stats rows Sp, accumulator block) of a kernel being staged
-    out, counted in hist_kernel_layout_total."""
+    out, counted in hist_kernel_layout_total and, by the build its one-hot
+    tiles get, in hist_kernel_onehot_total."""
+    itemsize = jnp.dtype(dtype).itemsize
     fold_k = _fold_words(B, S, itemsize)
+    BP, P = (128, 1) if fold_k else _bin_packing(B)
     _count_build("hist_kernel_layout_total",
                  layout="folded" if fold_k else "plain")
+    _count_build("hist_kernel_onehot_total",
+                 onehot="packed" if _onehot_packed(dtype, BP, P)
+                 else "compare")
     if fold_k:
         return (fold_k, fold_k * 4 // itemsize,
                 (Fp, _fold_rows(fold_k, itemsize), 128))
     Sp = -(-S // 16) * 16                          # pad stats to sublane tile
-    return 0, Sp, (Fp, Sp, _bin_packing(B)[0])
+    return 0, Sp, (Fp, Sp, BP)
 
 
 def _to_hist(out, F: int, S: int, B: int, fold_k: int, Sp: int):
@@ -771,7 +829,7 @@ def _hist_pallas(binned_t: jnp.ndarray, stats_t: jnp.ndarray,
     B = int(num_bins)
     BP, P = _bin_packing(B)
     Fp = -(-F // P) * P
-    fold_k, Sp, out_block = _stage_layout(B, S, Fp, stats_t.dtype.itemsize)
+    fold_k, Sp, out_block = _stage_layout(B, S, Fp, stats_t.dtype)
     RB = _pick_row_block(n, F, S, B)
     n_pad = -(-max(n, RB) // RB) * RB
     # zero stats on padding rows: they contribute nothing to any bin
@@ -804,7 +862,8 @@ def _node_hist_pallas(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
     S = 3 * W
     BP, P = _bin_packing(B)
     Fp = -(-F // P) * P
-    fold_k, Sp, out_block = _stage_layout(B, S, Fp, 1 if quantized else 2)
+    fold_k, Sp, out_block = _stage_layout(
+        B, S, Fp, jnp.int8 if quantized else jnp.bfloat16)
     RB = _pick_row_block(n, F, S, B, fused_w=W, quantized=quantized)
     n_pad = -(-max(n, RB) // RB) * RB
     binned_t = _pad_features_to(_pad_rows_to(binned_t, n_pad), Fp)

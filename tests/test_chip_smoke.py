@@ -33,7 +33,13 @@ def test_toy_rehearsal_passes_and_never_claims_the_chip():
     for phase in ("kernel", "train", "predict", "serve", "several chips"):
         assert any(ln.startswith(f"-- {phase} passed") for ln in lines), phase
     # all 24 kernel variants, through the real kernel code (interpreted)
-    assert sum(ln.startswith("kernel ") for ln in lines) == 24
+    kernels = [ln for ln in lines if ln.startswith("kernel ")]
+    assert len(kernels) == 24
+    # each says which build its one-hot tiles got: packed words for int8
+    # under the fold (255 bins, W = 1 and 16) and at 63 bins, else a compare
+    assert sum(" onehot=packed " in ln for ln in kernels) == 10
+    assert sum(" onehot=compare " in ln for ln in kernels) == 14
+    assert all("stats=int8" in ln for ln in kernels if "=packed " in ln)
     assert "byte-identical on 1 and 8 devices" in r.stdout
     last = lines[-1]
     assert last.startswith("REHEARSAL passed")

@@ -50,6 +50,12 @@ CASES = [
     (255, 16, "bf16", "folded", "f32[39,96,128]"),
     (255, 22, "int8", "plain", "s32[39,80,256]"),
     (63, 16, "int8", "plain", "s32[40,48,64]"),
+    # the packed-word one-hot of the plain layout at P = 2, 1, 4, 8, 16
+    (63, 1, "int8", "plain", "s32[40,16,64]"),
+    (128, 16, "int8", "plain", "s32[39,48,128]"),
+    (31, 16, "int8", "plain", "s32[40,48,32]"),
+    (16, 16, "int8", "plain", "s32[40,48,16]"),
+    (8, 16, "int8", "plain", "s32[48,48,8]"),
 ]
 
 

@@ -151,15 +151,12 @@ class TestPallasInterpret:
         return (jnp.asarray(b.astype(bins)), jnp.asarray(pos),
                 jnp.asarray(base))
 
-    @pytest.mark.parametrize("bins", ["uint8", "int32"])
-    @pytest.mark.parametrize("stats", ["int8", "bf16"])
-    @pytest.mark.parametrize("B,W,layout", LAYOUTS)
-    def test_node_kernel_layouts_match_scatter(self, B, W, layout, stats,
-                                               bins, monkeypatch):
-        """Either layout returns the scatter engine's histogram: int8 to
-        the bit, bf16 to tests/test_histogram_engines.py's tolerance with
-        the count channel exact."""
-        binned_t, pos, base = self._layout_case(B, W, bins)
+    @staticmethod
+    def _assert_matches_scatter(case, W, B, stats, monkeypatch):
+        """The interpreted kernel against the scatter engine on one case:
+        int8 to the bit, bf16 to tests/test_histogram_engines.py's
+        tolerance with the count channel exact."""
+        binned_t, pos, base = case
         stats_t, scales = (quantize_stats(base) if stats == "int8"
                            else (base, None))
         got = np.asarray(node_histogram(binned_t, pos, stats_t, W, B,
@@ -175,28 +172,89 @@ class TestPallasInterpret:
             np.testing.assert_array_equal(got[:, 2::3, :], want[:, 2::3, :])
             np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
-    @pytest.mark.parametrize("stats", ["int8", "bf16"])
-    @pytest.mark.parametrize("B,W,layout", LAYOUTS)
-    def test_layout_counter_follows_the_gate(self, B, W, layout, stats):
-        """hist_kernel_layout_total{layout} says which layout a staged-out
-        kernel took: a function of (B, S, dtype) and nothing else."""
+    @staticmethod
+    def _staged_counts(name, label, values, case, W, B, stats):
+        """What staging one node kernel out adds to a labelled counter."""
         import jax
 
         from mmlspark_tpu.observability import metrics
 
         def counts():
-            return {k: metrics.counter("hist_kernel_layout_total",
-                                       layout=k).value
-                    for k in ("folded", "plain")}
+            return {v: metrics.counter(name, **{label: v}).value
+                    for v in values}
 
-        binned_t, pos, base = self._layout_case(B, W, "uint8", n=512, F=2)
+        binned_t, pos, base = case
         stats_t, scales = (quantize_stats(base) if stats == "int8"
                            else (base, None))
         before = counts()
         jax.jit(lambda b, p, s: node_histogram(
             b, p, s, W, B, scales=scales)).lower(binned_t, pos, stats_t)
-        delta = {k: v - before[k] for k, v in counts().items()}
+        return {v: n - before[v] for v, n in counts().items()}
+
+    @pytest.mark.parametrize("bins", ["uint8", "int32"])
+    @pytest.mark.parametrize("stats", ["int8", "bf16"])
+    @pytest.mark.parametrize("B,W,layout", LAYOUTS)
+    def test_node_kernel_layouts_match_scatter(self, B, W, layout, stats,
+                                               bins, monkeypatch):
+        """Either layout returns the scatter engine's histogram."""
+        self._assert_matches_scatter(self._layout_case(B, W, bins), W, B,
+                                     stats, monkeypatch)
+
+    @pytest.mark.parametrize("stats", ["int8", "bf16"])
+    @pytest.mark.parametrize("B,W,layout", LAYOUTS)
+    def test_layout_counter_follows_the_gate(self, B, W, layout, stats):
+        """hist_kernel_layout_total{layout} says which layout a staged-out
+        kernel took: a function of (B, S, dtype) and nothing else."""
+        delta = self._staged_counts(
+            "hist_kernel_layout_total", "layout", ("folded", "plain"),
+            self._layout_case(B, W, "uint8", n=512, F=2), W, B, stats)
         assert delta == {"folded": 0, "plain": 0, layout: 1}
+
+    # the tile builder's gate (ops/histogram.py::_onehot_packed): int8
+    # statistics and one 128-sublane tile, so every plain kernel up to 128
+    # bins (P = 2, 2, 2, 4, 8, 16, 1, 1 features a tile) and every folded one
+    ONEHOTS = [(63, 1), (63, 16), (64, 16), (31, 4), (16, 4), (8, 2),
+               (100, 16), (128, 16)]
+
+    @staticmethod
+    def _onehot_case(B, W, bins, n=1100, F=7):
+        """An odd feature count (the last group's padding feature bins to
+        0) and columns pinned to bin 0 and to B - 1, which is the tile's
+        BP - 1 where B == BP: the seam between two packed features."""
+        binned_t, pos, base = TestPallasInterpret._layout_case(
+            B, W, "int32", n=n, F=F)
+        b = np.array(binned_t)
+        b[0, :] = 0
+        b[1, :] = B - 1
+        b[2, 0::2], b[2, 1::2] = 0, B - 1
+        b[F - 1, :] = B - 1                          # beside the padding
+        return jnp.asarray(b.astype(bins)), pos, base
+
+    @pytest.mark.parametrize("bins", ["uint8", "int32"])
+    @pytest.mark.parametrize("stats", ["int8", "bf16"])
+    @pytest.mark.parametrize("B,W", ONEHOTS)
+    def test_node_kernel_onehots_match_scatter(self, B, W, stats, bins,
+                                               monkeypatch):
+        """Packed or compared, a tile gives the scatter engine's
+        histogram."""
+        self._assert_matches_scatter(self._onehot_case(B, W, bins), W, B,
+                                     stats, monkeypatch)
+
+    @pytest.mark.parametrize("stats", ["int8", "bf16"])
+    @pytest.mark.parametrize("B,W", ONEHOTS + [(255, 16), (255, 22),
+                                               (300, 4)])
+    def test_onehot_counter_follows_the_gate(self, B, W, stats):
+        """hist_kernel_onehot_total{onehot} says which build a staged-out
+        kernel's tiles got: a function of (stats dtype, BP, P), where the
+        folded layout's tile is BP = 128, P = 1."""
+        bins = "uint8" if B <= 256 else "int32"
+        delta = self._staged_counts(
+            "hist_kernel_onehot_total", "onehot", ("packed", "compare"),
+            self._onehot_case(B, W, bins, n=512, F=3), W, B, stats)
+        # every tile value under 128: up to 128 bins plain, or folded
+        one_tile = B <= 128 or (B <= 256 and 3 * W <= 64)
+        onehot = "packed" if stats == "int8" and one_tile else "compare"
+        assert delta == {"packed": 0, "compare": 0, onehot: 1}
 
     @pytest.mark.parametrize("B,S,layout", [(255, 6, "folded"),
                                             (200, 64, "folded"),
@@ -220,6 +278,27 @@ class TestPallasInterpret:
         monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "scatter")
         want = np.asarray(histogram_cols(binned_t, stats_t, B))
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("B", [63, 128])
+    def test_cols_kernel_int8_stats_pack(self, B):
+        """histogram_cols with int8 statistics takes the packed build too,
+        and sums exactly."""
+        from mmlspark_tpu.observability import metrics
+        rng = np.random.default_rng(B)
+        n, F, S = 1200, 5, 6
+        b = rng.integers(0, B, size=(F, n), dtype=np.int32)
+        b[0, :], b[F - 1, :] = 0, B - 1
+        st = rng.integers(-20, 20, size=(S, n)).astype(np.float32)
+        packed = metrics.counter("hist_kernel_onehot_total", onehot="packed")
+        before = packed.value
+        got = np.asarray(histogram_cols(jnp.asarray(b), jnp.asarray(st), B,
+                                        stats_dtype=jnp.int8))
+        assert packed.value == before + 1
+        want = np.zeros((F, S, B), np.float32)
+        for f in range(F):
+            for s_ in range(S):
+                np.add.at(want[f, s_], b[f], st[s_])
+        np.testing.assert_array_equal(got, want)
 
 
 class TestNarrowBinStorage:
@@ -456,3 +535,7 @@ def test_vmem_picker_fits_bench_shapes_at_leafbatch_width():
                 # a pass is 16,680 or 8,340 grid steps at the cells' size:
                 # nothing smaller than 4096 rows a step belongs there
                 assert min(rb, rbq) >= 4096, (n, B, W, rb, rbq)
+                # the cells' own kernels (int8, W = 1 and 16: folded at 255
+                # bins, packed-word tiles at 63) take the largest block
+                if n > 1_000_000:
+                    assert rbq == 8192, (n, B, W, rbq)
