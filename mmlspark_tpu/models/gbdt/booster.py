@@ -1827,7 +1827,8 @@ def train_booster(
     max_bin = dataset.max_bin
     cfg = cfg._replace(num_bins=max_bin)
     n, n_pad, F = dataset.n, dataset.n_pad, dataset.num_features
-    fit.set(trees=num_iterations * K, rows=n, features=F)
+    fit.set(trees=num_iterations * K, rows=n, features=F,
+            stats="int8" if cfg.quantized_grad else "bf16")
     Xbt_d, y_d, w_d, vmask_d = (dataset.Xbt_d, dataset.y_d, dataset.w_d,
                                 dataset.vmask_d)
     # categorical routing mask: None when absent so the purely-numeric path

@@ -286,13 +286,18 @@ def _stat_totals(base_t, qscales, axis_name, blocks_local, rows_per_block):
 
     The totals must be sums of the SAME values the histograms sum: every
     engine rounds float stats to bf16 on input, so they are rounded here
-    too. Split search derives every right child as ``total - left`` with
-    ``left`` a histogram prefix sum; totals of unrounded stats would hand
-    the whole dataset's rounding residue (systematic when hessians cluster:
-    ~4e-4 per row, hundreds at 1M rows) down the chain of right children
-    into one small leaf, whose hessian then lands near zero and whose value
-    explodes. :func:`round_stats` is that one rounding, in a form XLA does
-    not elide.
+    too (:func:`round_stats` is that one rounding, in a form XLA does not
+    elide). On the quantized path split search derives every right side as
+    ``total - left``, exactly: the sums are int32 before the scale. On the
+    float path nothing is derived from these totals any more: they are the
+    root's recorded stats and no other node's. A candidate's right side is
+    its own suffix sum, a child gets the pair its parent's winning candidate
+    was scored with, and the gain's parent term is the histogram's own sum
+    (:func:`_best_split`). Subtracting at the root's magnitude handed every
+    rounding of a 3e6 f32 sum (spacing 0.25 at 68 M rows), the kernel's
+    accumulation walk and the residue of this function's other summation
+    order down the chain of right children into one small leaf, whose
+    hessian then landed near zero and whose value exploded (PERF.md, PR 33).
 
     Quantized per-BLOCK sums accumulate in int32 (bounded: _quantize_for
     caps q_max by rows_per_block, so a block sum stays under 2^31) and
@@ -328,6 +333,36 @@ def _soft_threshold(g, l1):
     return jnp.sign(g) * jnp.maximum(jnp.abs(g) - l1, 0.0)
 
 
+# The float path's sum sites and the form each takes: where a sum is taken at
+# the node's own magnitude that was once a difference at its parent's (see
+# :func:`_stat_totals`). The benchmark's float-gradient driver reads the
+# counter below by these names.
+FLOAT_SUM_SITES = {"right_side": "suffix_sum",
+                   "child_totals": "candidate_pair",
+                   "node_totals": "own_histogram"}
+
+
+def _note_float_sums(site: str) -> None:
+    """gbdt_float_sums_total{site, form}: one of ``FLOAT_SUM_SITES``, counted
+    where it is staged out (as :func:`_note_route_lookup` counts), so it
+    tracks program builds. The quantized path counts nothing: its int32 sums
+    subtract exactly."""
+    try:
+        from ...observability import metrics as _metrics
+        _metrics.safe_counter("gbdt_float_sums_total", site=site,
+                              form=FLOAT_SUM_SITES[site]).inc()
+    except Exception:  # noqa: BLE001 — telemetry must not fail the fit
+        pass
+
+
+def _suffix_sums(x):
+    """``out[..., b] = sum(x[..., b + 1:])``: what a split after bin ``b``
+    sends right, summed from the far end so that no term is larger than the
+    result (float statistics; see :func:`_stat_totals`)."""
+    s = lax.cumsum(x, axis=x.ndim - 1, reverse=True)
+    return jnp.concatenate([s[..., 1:], jnp.zeros_like(s[..., :1])], axis=-1)
+
+
 def _feature_best_gains(hist, fm, cfg):
     """[F] best LOCAL split gain per feature from a local [F, 3, B]
     histogram (node totals taken from the local histogram itself) — the
@@ -337,7 +372,11 @@ def _feature_best_gains(hist, fm, cfg):
     hl = jnp.cumsum(hist[:, 1, :], axis=-1)
     cl = jnp.cumsum(hist[:, 2, :], axis=-1)
     tg, th, tc = gl[:, -1:], hl[:, -1:], cl[:, -1:]
-    gr, hr, cr = tg - gl, th - hl, tc - cl
+    if cfg.quantized_grad:
+        gr, hr, cr = tg - gl, th - hl, tc - cl
+    else:
+        _note_float_sums("right_side")
+        gr, hr, cr = (_suffix_sums(hist[:, s, :]) for s in range(3))
     gain = (_leaf_objective(gl, hl, cfg) + _leaf_objective(gr, hr, cfg)
             - _leaf_objective(tg, th, cfg))
     ok = ((cl >= cfg.min_data_in_leaf) & (cr >= cfg.min_data_in_leaf)
@@ -411,14 +450,26 @@ def _best_split(hist, tot_g, tot_h, tot_c, cfg: GrowConfig, feat_mask, allow,
     use LightGBM's sorted-subset search: bins ordered by smoothed ratio
     g/(h + cat_smooth), prefixes scanned as candidate left-subsets (capped at
     ``max_cat_threshold`` categories), the winner encoded as a bin bitset.
-    Returns (gain, feat, bin, left_g, left_h, left_c, bits[BW] uint32) —
-    ``bits`` is all-zero for a numeric winner.
+    Returns (gain, feat, bin, left_g, left_h, left_c, bits[BW] uint32,
+    right) — ``bits`` is all-zero for a numeric winner.
+
+    The two arithmetics, chosen statically by ``cfg.quantized_grad``.
+    Quantized: a right side is ``tot - left`` (exact in int32 before the
+    scale) and ``right`` is None: the caller takes ``parent - left``. Float:
+    a right side is the suffix sum of the same bins in the same order, the
+    gain's parent term is the feature's own full sum, and ``right`` is the
+    winner's (right_g, right_h, right_c); ``tot_*`` are not read, so every
+    number a small child gets is a sum of its own bins, good to f32's
+    relative error at the child's magnitude whatever its parent holds.
     """
     B = hist.shape[-1]
+    float_sums = not cfg.quantized_grad
     g, h, c = hist[:, 0, :], hist[:, 1, :], hist[:, 2, :]
     gl = jnp.cumsum(g, axis=-1)
     hl = jnp.cumsum(h, axis=-1)
     cl = jnp.cumsum(c, axis=-1)
+    if float_sums:
+        gr, hr, cr = _suffix_sums(g), _suffix_sums(h), _suffix_sums(c)
     prefix_ok = jnp.ones((hist.shape[0], B), dtype=bool)
     rank = None
     if is_cat is not None:
@@ -439,11 +490,20 @@ def _best_split(hist, tot_g, tot_h, tot_c, cfg: GrowConfig, feat_mask, allow,
         gl = jnp.where(icat, glc, gl)
         hl = jnp.where(icat, hlc, hl)
         cl = jnp.where(icat, clc, cl)
+        if float_sums:
+            gr = jnp.where(icat, _suffix_sums(gs), gr)
+            hr = jnp.where(icat, _suffix_sums(hs), hr)
+            cr = jnp.where(icat, _suffix_sums(cs), cr)
         # prefix length b+1 capped (LightGBM max_cat_threshold)
         prefix_ok = jnp.where(
             icat, jnp.arange(B)[None, :] < int(cfg.max_cat_threshold),
             prefix_ok)
-    gr, hr, cr = tot_g - gl, tot_h - hl, tot_c - cl
+    if float_sums:
+        _note_float_sums("right_side")
+        _note_float_sums("node_totals")
+        tot_g, tot_h = gl[:, -1:], hl[:, -1:]
+    else:
+        gr, hr, cr = tot_g - gl, tot_h - hl, tot_c - cl
     gain = (_leaf_objective(gl, hl, cfg) + _leaf_objective(gr, hr, cfg)
             - _leaf_objective(tot_g, tot_h, cfg))
     ok = ((cl >= cfg.min_data_in_leaf) & (cr >= cfg.min_data_in_leaf)
@@ -460,8 +520,9 @@ def _best_split(hist, tot_g, tot_h, tot_c, cfg: GrowConfig, feat_mask, allow,
     else:
         member = is_cat[f] & (rank[f] <= b)                     # [B] bool
         bits = _pack_bits(member)
+    right = (pick(gr), pick(hr), pick(cr)) if float_sums else None
     return (gain[f, b], f.astype(jnp.int32), b.astype(jnp.int32),
-            pick(gl), pick(hl), pick(cl), bits)
+            pick(gl), pick(hl), pick(cl), bits, right)
 
 
 # Widest bitset, in uint32 words, that row routing tests with a select chain
@@ -604,15 +665,19 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     root_hist, sel0 = all_hist(jnp.zeros(n, dtype=jnp.int32), 1, "tree")
     # totals from the raw stats (not the histogram: under voting_parallel an
     # unselected feature's rows are zeroed there). Quantized mode totals the
-    # DEQUANTIZED stats so node stats stay consistent with histogram sums.
+    # DEQUANTIZED stats so node stats stay consistent with histogram sums;
+    # float mode records them for the root and derives nothing from them.
     tot = _stat_totals(base_t, qscales, axis_name, bl, rpb)
     tot_g, tot_h, tot_c = tot[0], tot[1], tot[2]
 
     # cfg is static Python config: root may split unless max_depth == 0
     root_allow = jnp.bool_(cfg.max_depth < 0 or cfg.max_depth >= 1)
-    g0, f0, b0, lg0, lh0, lc0, bits0 = _best_split(
+    g0, f0, b0, lg0, lh0, lc0, bits0, right0 = _best_split(
         root_hist, tot_g, tot_h, tot_c, cfg, feat_mask & sel0, root_allow,
         is_cat)
+    # float statistics: a candidate's right-side sums ride the cache beside
+    # its left-side ones (``crg``/``crh``/``crc``), for the child to take
+    float_sums = right0 is not None
 
     zi = jnp.zeros(M, dtype=jnp.int32)
     zf = jnp.zeros(M, dtype=jnp.float32)
@@ -631,6 +696,10 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         gain=zf,
         num_nodes=jnp.int32(1),
     )
+    if float_sums:
+        _note_float_sums("child_totals")
+        state.update(crg=zf.at[0].set(right0[0]), crh=zf.at[0].set(right0[1]),
+                     crc=zf.at[0].set(right0[2]))
 
     # Batched best-first: each round splits the top ``leaf_batch`` pending
     # leaves by cached gain in ONE fused histogram pass (their 2*KB children
@@ -672,18 +741,26 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         h, sel = all_hist(child_pos, W2, "round")   # [F, W2*3, B]
         hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
 
-        # child totals: left from the candidate cache, right = parent - left
+        # child totals, left from the candidate cache. Quantized: right =
+        # parent - left, exact. Float: the right-side sums the candidate was
+        # scored with, never a difference at the parent's magnitude
         lg = st["clg"][slots]
         lh = st["clh"][slots]
         lc = st["clc"][slots]
-        tg = jnp.stack([lg, st["ng"][slots] - lg], 1).reshape(-1)   # [W2]
-        th = jnp.stack([lh, st["nh"][slots] - lh], 1).reshape(-1)
-        tc = jnp.stack([lc, st["nc"][slots] - lc], 1).reshape(-1)
+
+        def pair(left, parent, right):
+            r = (st[right][slots] if float_sums
+                 else st[parent][slots] - left)
+            return jnp.stack([left, r], 1).reshape(-1)               # [W2]
+
+        tg = pair(lg, "ng", "crg")
+        th = pair(lh, "nh", "crh")
+        tc = pair(lc, "nc", "crc")
         child_depth = st["depth"][slots] + 1         # [KB]
         can_split = jnp.where(cfg.max_depth < 0, True,
                               child_depth + 1 <= cfg.max_depth)
         allow2 = jnp.repeat(can_split & do, 2)
-        g2, f2, b2, lg2, lh2, lc2, bits2 = vsplit(
+        g2, f2, b2, lg2, lh2, lc2, bits2, right2 = vsplit(
             hw, tg, th, tc, cfg, feat_mask & sel, allow2, is_cat)
 
         new = dict(st)
@@ -714,6 +791,9 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             new["clh"] = st["clh"].at[cslot].set(lh2, mode="drop")
             new["clc"] = st["clc"].at[cslot].set(lc2, mode="drop")
             new["cbits"] = st["cbits"].at[cslot].set(bits2, mode="drop")
+            if float_sums:
+                for k, r in zip(("crg", "crh", "crc"), right2):
+                    new[k] = st[k].at[cslot].set(r, mode="drop")
             new["num_nodes"] = st["num_nodes"] + 2 * n_split
         return new
 
@@ -882,7 +962,7 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
 
             allow = active & jnp.bool_(cfg.max_depth < 0
                                        or depth + 1 <= cfg.max_depth)
-            gains, feats, bins_, lgs, lhs, lcs, bits_w = vsplit(
+            gains, feats, bins_, lgs, lhs, lcs, bits_w, rights = vsplit(
                 h, tot[:, 0], tot[:, 1], tot[:, 2], cfg, feat_mask_lvl,
                 allow, is_cat)
             gains = jnp.where(active, gains, NEG_INF)
@@ -919,16 +999,20 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
                 ta["is_leaf"] = ta["is_leaf"].at[slot].set(False, mode="drop")
                 ta["gain"] = ta["gain"].at[slot].set(gains, mode="drop")
                 ta["bits"] = ta["bits"].at[slot].set(bits_w, mode="drop")
-                # children stats
+                # children stats: right = parent - left (quantized, exact)
+                # or the winning candidate's own right-side sums (float)
                 parent_g, parent_h, parent_c = tot[:, 0], tot[:, 1], tot[:, 2]
+                if rights is not None:
+                    _note_float_sums("child_totals")
                 lslot = jnp.where(do, lid, M)
                 rslot = jnp.where(do, rid, M)
-                ta["ng"] = ta["ng"].at[lslot].set(lgs, mode="drop")
-                ta["ng"] = ta["ng"].at[rslot].set(parent_g - lgs, mode="drop")
-                ta["nh"] = ta["nh"].at[lslot].set(lhs, mode="drop")
-                ta["nh"] = ta["nh"].at[rslot].set(parent_h - lhs, mode="drop")
-                ta["nc"] = ta["nc"].at[lslot].set(lcs, mode="drop")
-                ta["nc"] = ta["nc"].at[rslot].set(parent_c - lcs, mode="drop")
+                for k, left, parent, i in (("ng", lgs, parent_g, 0),
+                                           ("nh", lhs, parent_h, 1),
+                                           ("nc", lcs, parent_c, 2)):
+                    ta[k] = ta[k].at[lslot].set(left, mode="drop")
+                    ta[k] = ta[k].at[rslot].set(
+                        parent - left if rights is None else rights[i],
+                        mode="drop")
 
             # next frontier: the children, compacted into 2*W slots
             W_next = min(2 * W, L)

@@ -4,9 +4,15 @@ The reference delegates histogram building to LightGBM's C++ (CUDA/CPU) kernels
 behind LGBM_BoosterUpdateOneIter (reference: lightgbm/TrainUtils.scala:246).
 Here ONE resolver (:func:`resolve_engine`, ``MMLSPARK_TPU_HIST_ENGINE``)
 picks the formulation the current backend actually lowers well — all three
-produce equal histograms through the same entry points (count channel
-exact, grad/hess to f32 accumulation tolerance; docs/performance.md
-"Histogram engine selection"):
+produce equal histograms through the same entry points (int8 statistics
+exactly, in int32; float ones to f32 accumulation tolerance, which is a
+cell's own: every ``(feature, stat, bin)`` cell is summed by itself over
+the row blocks, so it is good to a few 1e-6 of its own value at 8340
+blocks, 68 M rows, whatever its neighbours hold, and a count is exact up
+to 2^24 rows a cell. What a caller may not do is subtract two such sums of
+the root's size and expect a leaf's: growth.py sums each side of a split
+from its own cells; PERF.md, PR 33; docs/performance.md "Histogram engine
+selection"):
 
   * ``pallas`` — the TPU kernels below (one-hot in VMEM, MXU contraction);
   * ``onehot`` — the XLA one-hot-matmul fallback below (MXU-shaped, used
@@ -89,8 +95,9 @@ def _use_pallas() -> bool:
 # Engine resolution: pallas (TPU MXU kernel) / onehot (XLA one-hot matmul —
 # the MXU-shaped fallback) / scatter (flattened segment-sum scatter-adds —
 # what XLA CPU/GPU lowers well). One resolver, three engines, identical
-# results through the same entry points (count channel exact, grad/hess to
-# f32 accumulation tolerance) — so `growth.py` never cares which ran.
+# results through the same entry points (int8 exactly; float to each cell's
+# own f32 accumulation tolerance, see the module docstring) — so `growth.py`
+# never cares which ran.
 # ---------------------------------------------------------------------------
 
 _ENGINES = ("pallas", "onehot", "scatter")

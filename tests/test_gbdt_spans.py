@@ -96,6 +96,42 @@ def test_a_fit_is_one_span_tiled_by_its_phases(dataset, path, expected):
         assert a["ts"] + a["dur"] <= b["ts"] + 1.0
 
 
+def _float_sums():
+    series = (metrics.get_registry().snapshot().get(
+        "gbdt_float_sums_total") or {}).get("series", [])
+    return {(s["labels"]["site"], s["labels"]["form"]): s["value"]
+            for s in series}
+
+
+@pytest.mark.parametrize("policy", ["leafwise", "depthwise"])
+def test_fit_span_says_its_stats_and_a_float_build_counts_its_sum_sites(
+        dataset, policy):
+    """``gbdt_fit`` carries ``stats``; building a float fit's program counts
+    every site that sums at the node's own magnitude (once a place it is
+    staged out), and an int8 fit's build counts none."""
+    def fit(seed, **cfg):
+        cfg = growth.GrowConfig(num_leaves=5, min_data_in_leaf=5,
+                                growth_policy=policy, **cfg)
+        return gb.train_booster(dataset=dataset, objective="binary",
+                                num_iterations=2, cfg=cfg, seed=seed)
+
+    before = _float_sums()
+    fit(3301)
+    (span, _), = _fits_and_children()
+    assert span["args"]["stats"] == "bf16"
+    moved = {k for k, v in _float_sums().items() if v > before.get(k, 0)}
+    assert moved == {("right_side", "suffix_sum"),
+                     ("child_totals", "candidate_pair"),
+                     ("node_totals", "own_histogram")}
+    spans.clear_trace()
+    before = _float_sums()
+    fit(3302, quantized_grad=True, quant_warmup_iters=0,
+        quant_renew_leaf=False)
+    (span, _), = _fits_and_children()
+    assert span["args"]["stats"] == "int8"
+    assert _float_sums() == before
+
+
 def test_a_fit_nests_under_the_callers_span(dataset):
     with spans.span("caller"):
         _fit(dataset)
