@@ -33,8 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...ops.histogram import (node_histogram, quant_q_max, quantize_stats,
-                              round_stats)
+from ...ops.histogram import (accumulator_tile, node_histogram, quant_q_max,
+                              quantize_stats, round_stats)
 from ...parallel.compat import axis_size as _axis_size
 
 NEG_INF = jnp.float32(-jnp.inf)
@@ -52,10 +52,11 @@ class GrowConfig(NamedTuple):
     min_gain_to_split: float = 0.0
     # "leafwise" = LightGBM-parity best-first growth. "depthwise" =
     # TPU-throughput mode: one histogram pass per LEVEL with every frontier
-    # node's stats batched into the stat axis (histogram cost is flat in
-    # that axis up to ~128 lanes, so a 31-leaf tree takes ~6 passes instead
-    # of 30); the num_leaves budget is enforced by splitting the best nodes
-    # first.
+    # node's stats batched into the stat axis (a pass scans all rows
+    # whatever the nodes hold and pays for that axis by the MXU operand
+    # tile, see _pass_widths, so a 31-leaf tree takes ~6 passes, each as
+    # wide as its level, instead of 30); the num_leaves budget is enforced
+    # by splitting the best nodes first.
     growth_policy: str = "leafwise"
     # leafwise batching: split the top ``leaf_batch`` pending leaves (by
     # cached gain) per histogram pass instead of one. Splits of distinct
@@ -64,8 +65,11 @@ class GrowConfig(NamedTuple):
     # runs out mid-batch and a child's gain would have outranked a pending
     # leaf's. leaf_batch=1 is exact sequential best-first (LightGBM order);
     # the default trades that tail-order nuance for ~4-5x fewer passes.
-    # A histogram pass scans all rows whatever the nodes hold, so its cost
-    # is flat in the node axis: batching cuts the PASS COUNT.
+    # A histogram pass scans all rows whatever the nodes hold, so batching
+    # cuts the PASS COUNT. Its cost is not flat in the node axis: flat up
+    # to 4 nodes, then paid for by the MXU (a 16-node pass costs 1.7x a
+    # root's at 255 bins), so a round's pass runs at the narrowest staged
+    # width that holds its live children (_pass_widths).
     # Caveat under voting_parallel: the top-2k feature ballot then spans the
     # whole batch's children (one vote per pass, like depthwise's
     # frontier-wide vote) rather than one split's two children, so voting
@@ -200,6 +204,74 @@ def _hist_block_geometry(cfg: GrowConfig, axis_name, n: int):
     return bl, n // bl
 
 
+def _pass_widths(W: int, B: int, quantized: bool) -> tuple:
+    """The node widths a leafwise round's histogram pass is staged at, from
+    static shapes alone: the powers of two from 4 up to ``W = 2 *
+    leaf_batch`` child slots and ``W`` itself, less every width whose kernel
+    would have the next one's accumulator tile
+    (:func:`ops.histogram.accumulator_tile`: the wider of the two then costs
+    the same MXU tiles and holds more). A round runs the narrowest that
+    holds its live children (:func:`_hist_at_width`).
+
+    A pass scans all rows whatever the nodes hold, but its cost is not flat
+    in the node axis: flat up to 4 nodes, then paid for by the MXU, a tile
+    of stats rows at a time (68.3 M rows x 39 at 255 bins, measured on a
+    v5e: int8 0.114 s at the root, 0.117 at 4 nodes, 0.126 at 8, 0.189 at
+    16; bf16 0.225, 0.229, 0.235, 0.369, and 0.226 at 2 nodes: a variant
+    under 4 would buy 3 ms a pass for one more kernel to compile and load;
+    PERF.md §5). A 31-leaf tree at ``leaf_batch`` 8 splits 1, 2, 4, 8, 8, 7
+    leaves in its rounds, so half of them are narrow.
+    ``W = 2`` (``leaf_batch`` 1) has the one width."""
+    itemsize = 1 if quantized else 2
+    widths = []
+    for w in sorted({1 << i for i in range(2, W.bit_length())} | {W}):
+        if widths and (accumulator_tile(B, 3 * widths[-1], itemsize)
+                       == accumulator_tile(B, 3 * w, itemsize)):
+            widths.pop()
+        widths.append(w)
+    return tuple(widths)
+
+
+def _note_pass_width(width: int) -> None:
+    """gbdt_hist_pass_width_total{width}: a width a leafwise round's pass is
+    staged at, counted where that variant is staged out (as
+    :func:`_note_route_lookup` counts), so it tracks program builds. On the
+    device the variants tell themselves apart by their result shapes."""
+    try:
+        from ...observability import metrics as _metrics
+        _metrics.safe_counter("gbdt_hist_pass_width_total",
+                              width=str(width)).inc()
+    except Exception:  # noqa: BLE001 — telemetry must not fail the fit
+        pass
+
+
+def _hist_at_width(hist_of, W: int, live, B: int, quantized: bool):
+    """``hist_of(W)`` — this shard's ``[..., 3 * W, B]`` node histograms —
+    built at the narrowest staged width that holds the ``live`` node
+    positions (a traced count; positions at or past it hold no row) and
+    zero-padded back to ``W``, so everything downstream sees the array it
+    would of a full-width pass: the slots past ``live`` are zero either way.
+    One ``lax.switch`` whose branches differ in the kernel call alone; one
+    width stages no switch."""
+    def staged(w):
+        def branch():
+            _note_pass_width(w)
+            h = hist_of(w)
+            if w == W:
+                return h
+            pad = [(0, 0)] * h.ndim
+            pad[-2] = (0, 3 * (W - w))
+            return jnp.pad(h, pad)
+        return branch
+
+    widths = _pass_widths(W, B, quantized)
+    if len(widths) == 1:
+        return staged(W)()
+    narrower = jnp.asarray(widths[:-1], dtype=jnp.int32)
+    return lax.switch(jnp.sum((live > narrower).astype(jnp.int32)),
+                      [staged(w) for w in widths])
+
+
 def _blocked_fold(parts: jnp.ndarray, axis_name, what: str, per="tree"):
     """Gather per-shard block partials into canonical order and fold them
     left-to-right. ``parts``: [blocks_local, ...] stacked partials; the
@@ -264,20 +336,29 @@ def _quantize_for(cfg: GrowConfig, base_t, qkey, axis_name, blocks_local,
     return quantize_stats(base_t, qkey, amax=amax, q_max=q_max, u=u)
 
 
-def _blocked_node_hist(binned_t, row_pos, base_t, W: int, B: int, qscales,
-                       blocks_local: int, rows_per_block: int, axis_name,
-                       per):
-    """[F, W*3, B] histogram via the canonical blocked reduction: one
-    engine pass per fixed row block (identical shapes on every topology),
-    gathered and folded in block order."""
-    parts = jnp.stack([
+def _block_node_hists(binned_t, row_pos, base_t, W: int, B: int, qscales,
+                      blocks_local: int, rows_per_block: int):
+    """[blocks_local, F, W*3, B]: this shard's part of the canonical blocked
+    reduction, one engine pass per fixed row block (identical shapes on
+    every topology), for :func:`_blocked_fold` to gather and fold in block
+    order."""
+    return jnp.stack([
         node_histogram(
             binned_t[:, j * rows_per_block:(j + 1) * rows_per_block],
             row_pos[j * rows_per_block:(j + 1) * rows_per_block],
             base_t[:, j * rows_per_block:(j + 1) * rows_per_block],
             W, B, scales=qscales)
         for j in range(blocks_local)])
-    return _blocked_fold(parts, axis_name, "hist", per)
+
+
+def _blocked_node_hist(binned_t, row_pos, base_t, W: int, B: int, qscales,
+                       blocks_local: int, rows_per_block: int, axis_name,
+                       per):
+    """[F, W*3, B] histogram via the canonical blocked reduction."""
+    return _blocked_fold(
+        _block_node_hists(binned_t, row_pos, base_t, W, B, qscales,
+                          blocks_local, rows_per_block),
+        axis_name, "hist", per)
 
 
 def _stat_totals(base_t, qscales, axis_name, blocks_local, rows_per_block):
@@ -642,9 +723,12 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                         rpb)
 
     @jax.named_scope("gbdt_hist")
-    def all_hist(row_pos, W, per):
+    def all_hist(row_pos, W, per, live=None):
         """Global per-node histogram [F, W*3, B] + selected-feature mask;
-        ``per``: how often the pass runs (:func:`_allreduce`).
+        ``per``: how often the pass runs (:func:`_allreduce`); ``live``: a
+        round's count of node positions that hold rows, for the pass to run
+        no wider than it must (:func:`_hist_at_width`; the root's pass has
+        the one width).
 
         data_parallel: one full [F, W*3, B] psum — or, under hist_blocks,
         the canonical blocked fold (topology-independent f32 order).
@@ -652,11 +736,18 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         global top-2k features' histograms (scattered back into a zeroed
         full array so downstream split search keeps static shapes;
         unselected features are masked)."""
+        def local(w):
+            if bl:
+                return _block_node_hists(binned_t, row_pos, base_t, w, B,
+                                         qscales, bl, rpb)
+            return node_histogram(binned_t, row_pos, base_t, w, B,
+                                  scales=qscales)
+
+        h = (local(W) if live is None else
+             _hist_at_width(local, W, live, B, qscales is not None))
         if bl:
-            return (_blocked_node_hist(binned_t, row_pos, base_t, W, B,
-                                       qscales, bl, rpb, axis_name, per),
+            return (_blocked_fold(h, axis_name, "hist", per),
                     jnp.ones(F, dtype=bool))
-        h = node_histogram(binned_t, row_pos, base_t, W, B, scales=qscales)
         if axis_name is None or not cfg.voting:
             return (_allreduce(h, axis_name, "hist", per),
                     jnp.ones(F, dtype=bool))
@@ -738,7 +829,10 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
             ).astype(jnp.int32)
 
-        h, sel = all_hist(child_pos, W2, "round")   # [F, W2*3, B]
+        # ``do`` is a prefix of the gain-sorted candidates, so every live
+        # child position is under 2 * n_split
+        h, sel = all_hist(child_pos, W2, "round",   # [F, W2*3, B]
+                          live=2 * n_split)
         hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
 
         # child totals, left from the candidate cache. Quantized: right =
@@ -870,8 +964,9 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
 
     Every node on the level frontier contributes 3 stat channels
     (grad/hess/count x node one-hot), so a single MXU histogram pass covers
-    the whole level — the measured histogram cost is flat in the stat axis,
-    making a 31-leaf tree ~6 passes instead of the 30 sequential passes of
+    the whole level, staged at the level's own width (a pass pays for the
+    stat axis by the operand tile, see :func:`_pass_widths`), making a
+    31-leaf tree ~6 passes instead of the 30 sequential passes of
     best-first growth. The ``num_leaves`` budget is respected by ranking the
     level's candidate splits by gain. Same Tree layout / slot allocation
     discipline as ``grow_tree`` (slot ids in allocation order).

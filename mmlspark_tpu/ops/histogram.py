@@ -578,11 +578,14 @@ def _hist_dot_accumulate(o_ref, b_ref, sb, Fp: int, BP: int, P: int,
 
     The feature loop is a static Python unroll, NOT lax.fori_loop: the
     dynamically-indexed loop measured ~3-5 us of scalar-core overhead per
-    step (flat in B and W — the kernel ran no faster at B=63 than B=255),
-    dominating the whole pass at ~17 ms for F=28 x 1M rows. Unrolled,
-    Mosaic schedules the slices statically. Above _UNROLL_MAX feature
-    groups the loop stays dynamic so very wide datasets don't pay
-    linear-in-F compile time/program size for a sub-us-per-step win.
+    step (that loop's time was flat in B and W — it ran no faster at B=63
+    than B=255), dominating the whole pass at ~17 ms for F=28 x 1M rows.
+    Unrolled, Mosaic schedules the slices statically, and a pass costs its
+    tiles: one-hot tiles a feature, operand tiles of stats rows (PERF.md
+    §5: at 255 bins int8 0.114 s at W = 1, 0.127 at 8, 0.191 at 16).
+    Above _UNROLL_MAX feature groups the loop stays dynamic so very wide
+    datasets don't pay linear-in-F compile time/program size for a
+    sub-us-per-step win.
     """
     acc = jnp.int32 if sb.dtype == jnp.int8 else jnp.float32
     if fold_k:
@@ -633,8 +636,9 @@ def _hist_group_dot(o_ref, b_ref, sb, g, BP: int, P: int, acc):
     orientation (``row[:, None] == iota[RB, BP]``) forces a lane->sublane
     relayout of the [RB] bin row for every feature in every grid step;
     measured on v5e that relayout dominated the whole kernel — 2.4x slower
-    per pass at 1M rows x 28 features x 255 bins, with pass time flat in
-    both bin count and stats dtype (the signature of a non-MXU bottleneck).
+    per pass at 1M rows x 28 features x 255 bins, with that pass's time
+    flat in both bin count and stats dtype (the signature of a non-MXU
+    bottleneck; transposed, neither is flat any more, nor is the node axis).
     """
     # widen narrow bin storage (uint8/int16) per block, in VMEM only
     rows = [b_ref[g * P + p, :].astype(jnp.int32) for p in range(P)]
@@ -744,6 +748,19 @@ def _hist_fold_dot(o_ref, b_ref, words, g, k: int, dtype, acc):
     o_ref[g] += h
 
 
+def accumulator_tile(B: int, S: int, itemsize: int):
+    """(rows, lanes) of one feature's accumulator block in a kernel of ``S``
+    stats rows: the stats operand's height (the folded layout's stacked
+    words, or the stats padded to a 16-sublane tile) by the one-hot's
+    sublanes. The height is what the MXU pays for once it passes the first
+    tile, and the pair is how two widths of a pass are known to be the same
+    kernel (``growth._pass_widths``)."""
+    fold_k = _fold_words(B, S, itemsize)
+    if fold_k:
+        return _fold_rows(fold_k, itemsize), 128
+    return -(-S // 16) * 16, _bin_packing(B)[0]    # pad stats to sublane tile
+
+
 def _stage_layout(B: int, S: int, Fp: int, dtype):
     """(fold_k, stats rows Sp, accumulator block) of a kernel being staged
     out, counted in hist_kernel_layout_total and, by the build its one-hot
@@ -756,11 +773,9 @@ def _stage_layout(B: int, S: int, Fp: int, dtype):
     _count_build("hist_kernel_onehot_total",
                  onehot="packed" if _onehot_packed(dtype, BP, P)
                  else "compare")
-    if fold_k:
-        return (fold_k, fold_k * 4 // itemsize,
-                (Fp, _fold_rows(fold_k, itemsize), 128))
-    Sp = -(-S // 16) * 16                          # pad stats to sublane tile
-    return 0, Sp, (Fp, Sp, BP)
+    rows, lanes = accumulator_tile(B, S, itemsize)
+    return (fold_k, fold_k * 4 // itemsize if fold_k else rows,
+            (Fp, rows, lanes))
 
 
 def _to_hist(out, F: int, S: int, B: int, fold_k: int, Sp: int):

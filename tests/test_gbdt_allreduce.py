@@ -202,9 +202,13 @@ def _one_device_text(policy, debug_info=False):
     return re.sub(r"module @\S+", "module @m", text, count=1)
 
 
+_PR28_LEAFWISE = (
+    "de6db80d6ad95970565e39f339e4460a5b83108f8ce754f66765e430c9e0febe")
+
+
 @pytest.mark.parametrize("policy,sha", [
     ("leafwise",
-     "de6db80d6ad95970565e39f339e4460a5b83108f8ce754f66765e430c9e0febe"),
+     "0c9b71463a4ea563123ec56bf869828ccab185e574713bffd6f3a9904da3e37e"),
     ("depthwise",
      "d88f6387f517ba32c9630a366c6975b10feaf12ad3f4e1bb04f22de0670b94a0"),
 ])
@@ -218,7 +222,15 @@ def test_one_device_program_is_the_parent_s(policy, sha, monkeypatch):
     only the first three are held. (On a CPU that keeps the compile-cache
     entries, keyed on the text without its locations. On the chip it does
     not: a Mosaic kernel's body is serialized with its locations, so a line
-    moved in any frame above the ``pallas_call`` is a new key.)"""
+    moved in any frame above the ``pallas_call`` is a new key.)
+
+    PR 34 changed the leafwise text by design, and re-pinned it: a round's
+    histogram pass is staged at the widths of ``growth._pass_widths`` (4 and
+    8 at these shapes) inside one ``lax.switch``, where it was one call at
+    ``2 * leaf_batch``. The new hash is the text of that program. With the
+    rule held to the single width it is still the text PRs 28 to 33 pinned,
+    byte for byte, so the switch is all that changed; the depthwise text
+    did not move."""
     text = _one_device_text(policy)
     assert "gbdt_allreduce/" not in _one_device_text(policy, debug_info=True)
     found = _collectives(jax.make_jaxpr(_grow_fn(
@@ -229,3 +241,7 @@ def test_one_device_program_is_the_parent_s(policy, sha, monkeypatch):
     assert _one_device_text(policy) == text
     if jax.__version__ == _PARENT_JAX:
         assert hashlib.sha256(text.encode()).hexdigest() == sha
+        monkeypatch.setattr(growth, "_pass_widths", lambda W, B_, q: (W,))
+        if policy == "leafwise":
+            assert hashlib.sha256(_one_device_text(
+                policy).encode()).hexdigest() == _PR28_LEAFWISE

@@ -1,0 +1,262 @@
+"""A leafwise round's histogram pass, as wide as the round's frontier
+(``growth._pass_widths``, ``growth._hist_at_width``; PERF.md, PR 34).
+
+A pass over ``W2 = 2 * leaf_batch`` child slots costs the MXU its operand's
+height, and the first rounds of a tree hold 2, 4 and 8 children. The round's
+pass is staged at a few static widths and runs the narrowest that holds its
+live children; the slots past them were zeros and are zeros. So the trees
+have to be the ones the single width ``W2`` grows, which was the program
+before: to the bit, for int8 and for float statistics, on one shard and on
+four, under ``hist_blocks`` and ``voting_parallel``.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mmlspark_tpu.models.gbdt import growth
+from mmlspark_tpu.models.gbdt.growth import GrowConfig
+from mmlspark_tpu.observability import metrics
+from mmlspark_tpu.ops.histogram import node_histogram
+from mmlspark_tpu.parallel import mesh as meshlib
+from mmlspark_tpu.parallel.compat import shard_map
+from mmlspark_tpu.parallel.placement import pspec
+
+# -- the rule -----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("B,stats,leaf_batch,widths", [
+    # no width under 4: measured, a pass is flat up to 4 nodes (at 255 bins
+    # int8, 2 and 4 nodes share the root's 32-row operand anyway)
+    (255, "int8", 1, (2,)),
+    (255, "int8", 2, (4,)),
+    (255, "int8", 4, (4, 8)),
+    (255, "int8", 8, (4, 8, 16)),
+    (255, "int8", 10, (4, 8, 16, 20)),
+    # the plain layout takes over at 96 rows too (W > 21), with two tiles a
+    # feature: as high as the folded 16, and not the same kernel
+    (255, "int8", 15, (4, 8, 16, 30)),
+    # bf16 packs two rows a word: other heights, the same widths
+    (255, "bf16", 1, (2,)),
+    (255, "bf16", 2, (4,)),
+    (255, "bf16", 4, (4, 8)),
+    (255, "bf16", 8, (4, 8, 16)),
+    (255, "bf16", 10, (4, 8, 16, 20)),
+    # the plain layout pads the stats to 16 rows: 10 nodes are as high as 8
+    (63, "int8", 1, (2,)),
+    (63, "int8", 2, (4,)),
+    (63, "int8", 4, (4, 8)),
+    (63, "int8", 5, (4, 10)),
+    (63, "int8", 8, (4, 8, 16)),
+    (63, "int8", 10, (4, 8, 16, 20)),
+    (63, "bf16", 8, (4, 8, 16)),
+])
+def test_the_widths_are_a_rule_of_static_shapes(B, stats, leaf_batch, widths):
+    got = growth._pass_widths(2 * leaf_batch, B, stats == "int8")
+    assert got == widths
+    assert got[-1] == 2 * leaf_batch and list(got) == sorted(set(got))
+
+
+# -- one pass, every count of live positions ----------------------------------
+
+
+@pytest.mark.parametrize("stats", ["int8", "float"])
+@pytest.mark.parametrize("live", [1, 2, 3, 4, 5, 8, 9, 14, 16])
+def test_a_pass_at_the_live_width_is_the_full_width_pass(live, stats):
+    """Rows sit at positions under ``live`` or at none: the histogram of the
+    narrowest width that holds them, padded, is the 16-wide one, every bit."""
+    rng = np.random.default_rng(live)
+    n, F, B, W = 4096, 5, 63, 16
+    binned = jnp.asarray(rng.integers(0, B, (F, n)), jnp.uint8)
+    pos = jnp.asarray(rng.integers(-1, live, n), jnp.int32)
+    base = rng.normal(size=(3, n)).astype(np.float32)
+    scales = None
+    if stats == "int8":
+        base, scales = np.round(base * 40).astype(np.int8), jnp.ones(3)
+    base = jnp.asarray(base)
+
+    def hist_of(w):
+        return node_histogram(binned, pos, base, w, B, scales=scales)
+
+    got = jax.jit(lambda k: growth._hist_at_width(
+        hist_of, W, k, B, stats == "int8"))(jnp.int32(live))
+    want = hist_of(W)
+    assert got.shape == want.shape == (F, 3 * W, B)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert not np.asarray(got)[:, 3 * live:].any()
+    assert np.asarray(got)[:, :3 * live].any()
+
+
+# -- trees --------------------------------------------------------------------
+
+N, F, B = 4096, 6, 63
+
+
+def _rows(seed=0):
+    """Two numeric steps, an interaction and one category subset, so that a
+    tree of 15 leaves has real splits to find in every round."""
+    rng = np.random.default_rng(seed)
+    X = rng.integers(0, B, (F, N))
+    X[5] = rng.integers(0, 12, N)
+    logit = (2.0 * (X[0] > 20) - 1.5 * (X[1] < 30) * (X[2] > 40)
+             + 1.2 * np.isin(X[5], [1, 4, 7]) + 0.8 * (X[3] > 50))
+    y = (rng.uniform(size=N) < 1 / (1 + np.exp(0.5 - logit))).astype(
+        np.float32)
+    p = 0.5
+    return X.astype(np.uint8), (p - y).astype(np.float32), np.full(
+        N, p * (1 - p), np.float32)
+
+
+def _grow(widths, *, stats="int8", shards=1, cat=False, rows=None, **cfg):
+    """One tree as numpy arrays; ``widths`` None is the rule as shipped, a
+    tuple stands where ``growth._pass_widths`` stands."""
+    binned, grad, hess = rows or _rows()
+    cfg = GrowConfig(**dict(dict(
+        num_leaves=15, num_bins=B, min_data_in_leaf=5, leaf_batch=4,
+        quantized_grad=stats == "int8", quant_renew_leaf=False), **cfg))
+    is_cat = jnp.asarray([False] * (F - 1) + [True]) if cat else None
+    axis = "data" if shards > 1 else None
+
+    def fn(b, g, h, v, fm, key):
+        return growth.grow_tree(b, g, h, v, fm, cfg, axis, is_cat, key)[0]
+
+    if shards > 1:
+        fn = shard_map(fn, mesh=meshlib.make_mesh(
+            devices=jax.devices()[:shards]),
+            in_specs=(pspec(None, "data"),) + (pspec("data"),) * 3
+            + (pspec(), pspec()), out_specs=pspec(), check_vma=False)
+    real = growth._pass_widths
+    if widths is not None:
+        growth._pass_widths = lambda W, B_, q: widths
+    try:
+        tree = jax.jit(fn)(jnp.asarray(binned), jnp.asarray(grad),
+                           jnp.asarray(hess), jnp.ones(N), jnp.ones(F, bool),
+                           jax.random.PRNGKey(0))
+    finally:
+        growth._pass_widths = real
+    return jax.tree_util.tree_map(np.asarray, tree)
+
+
+def _same_tree(a, b):
+    for name, x, y in zip(a._fields, a, b):
+        assert x.tobytes() == y.tobytes(), name
+
+
+@pytest.mark.parametrize("cat", [False, True], ids=["numeric", "categorical"])
+@pytest.mark.parametrize("stats", ["int8", "float"])
+@pytest.mark.parametrize("shards,cfg", [
+    (1, {}), (4, {}), (1, dict(hist_blocks=4)), (4, dict(hist_blocks=4)),
+    (4, dict(voting=True, top_k=2)),
+], ids=["1", "4", "1-blocks4", "4-blocks4", "4-voting"])
+def test_trees_are_the_single_width_s_to_the_bit(shards, cfg, stats, cat):
+    """The rule monkeypatched to the one width ``W2`` stages the program
+    this PR's parent staged."""
+    ours = _grow(None, stats=stats, shards=shards, cat=cat, **cfg)
+    parents = _grow((8,), stats=stats, shards=shards, cat=cat, **cfg)
+    assert int(ours.node_count) >= 2 * 8 - 1        # rounds of 1, 2, 4 splits
+    _same_tree(ours, parents)
+
+
+@pytest.mark.parametrize("stats", ["int8", "float"])
+def test_a_tree_that_ends_in_a_round_of_one_live_split(stats):
+    """Feature 0 splits the rows, feature 1 splits the left half again and
+    nothing else is worth ``min_gain_to_split``: the second round has two
+    candidates and one split, the third none."""
+    rng = np.random.default_rng(3)
+    X = rng.integers(0, B, (F, N))
+    left = X[0] <= 30
+    y = np.where(left, np.where(X[1] <= 30, 0.9, 0.6), 0.1)
+    rows = (X.astype(np.uint8), (0.5 - y).astype(np.float32),
+            np.full(N, 0.25, np.float32))
+    ours = _grow(None, stats=stats, rows=rows, min_gain_to_split=5.0)
+    parents = _grow((8,), stats=stats, rows=rows, min_gain_to_split=5.0)
+    assert int(ours.node_count) == 5
+    assert sorted(ours.feat[~ours.is_leaf]) == [0, 1]
+    _same_tree(ours, parents)
+
+
+# -- the program --------------------------------------------------------------
+
+
+def _lower_args(n=2048):
+    return (jnp.zeros((F, n), jnp.uint8), jnp.zeros(n), jnp.ones(n),
+            jnp.ones(n), jnp.ones(F, bool), jax.random.PRNGKey(0))
+
+
+def _grow_fn(**cfg):
+    cfg = GrowConfig(**dict(dict(num_leaves=31, num_bins=255,
+                                 quantized_grad=True,
+                                 quant_renew_leaf=False), **cfg))
+    return lambda b, g, h, v, fm, k: growth.grow_tree(b, g, h, v, fm, cfg,
+                                                      None, None, k)[0]
+
+
+def _kernel_calls(jaxpr):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _kernel_calls(sub)
+    return n
+
+
+def _switches(jaxpr, found):
+    """Every ``cond`` all of whose branches call the kernel."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            calls = [_kernel_calls(b.jaxpr) for b in eqn.params["branches"]]
+            if all(calls):
+                found.append(calls)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _switches(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("stats,widths", [("int8", (4, 8, 16)),
+                                          ("float", (4, 8, 16))])
+def test_a_round_holds_one_switch_of_one_kernel_call_a_branch(
+        stats, widths, monkeypatch):
+    """The cells' rounds (255 bins, ``leaf_batch`` 8) under the Pallas engine:
+    the root's call, and one switch whose branches hold one call each. The
+    routing, the split search and the tree update are staged once."""
+    monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "pallas")
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
+    jaxpr = jax.make_jaxpr(_grow_fn(quantized_grad=stats == "int8"))(
+        *_lower_args()).jaxpr
+    assert _switches(jaxpr, []) == [[1] * len(widths)]
+    assert _kernel_calls(jaxpr) == 1 + len(widths)
+    assert str(jaxpr).count("gbdt_route") == str(jax.make_jaxpr(_grow_fn(
+        quantized_grad=stats == "int8", leaf_batch=1))(
+            *_lower_args()).jaxpr).count("gbdt_route")
+
+
+def test_leaf_batch_one_stages_no_switch(monkeypatch):
+    """``W2 = 2`` has the one width: the text is the text without the
+    mechanism."""
+    def text():
+        return jax.jit(_grow_fn(leaf_batch=1, num_bins=B)).lower(
+            *_lower_args()).as_text()
+
+    shipped = text()
+    monkeypatch.setattr(growth, "_hist_at_width",
+                        lambda hist_of, W, *a: hist_of(W))
+    assert text() == shipped
+
+
+def _staged():
+    reg = metrics.get_registry().snapshot().get(
+        "gbdt_hist_pass_width_total") or {}
+    return {s["labels"]["width"]: s["value"] for s in reg.get("series", [])}
+
+
+@pytest.mark.parametrize("leaf_batch,stats,widths", [
+    (8, "int8", ("4", "8", "16")), (8, "float", ("4", "8", "16")),
+    (1, "int8", ("2",))])
+def test_a_build_counts_each_staged_width_once(leaf_batch, stats, widths):
+    before = _staged()
+    jax.jit(_grow_fn(leaf_batch=leaf_batch,
+                     quantized_grad=stats == "int8")).lower(*_lower_args())
+    after = _staged()
+    assert {w: after[w] - before.get(w, 0) for w in after
+            if after[w] != before.get(w, 0)} == {w: 1 for w in widths}

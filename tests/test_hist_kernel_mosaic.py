@@ -6,8 +6,9 @@ Mosaic refuses (an unaligned slice, a scoped-VMEM overrun at the row block
 these cases say nothing about results or speed — the interpreter cases of
 tests/test_histogram.py hold the results, chip_smoke.py both on the chip.
 Shapes are the benchmark cells' (BENCHMARK.json: 68,321,280 x 39 uint8 bins,
-W = 1 for a root and 16 for a leafwise round at ``leaf_batch = 8``) and the
-widths on both sides of the folded layout's gate.
+W = 1 for a root and 4, 8, 16 for the widths a leafwise round is staged at
+with ``leaf_batch = 8``, 2 beside them) and the widths on both sides of the
+folded layout's gate.
 
 All in one file, the topology described inside a fixture: one process at a
 time may load the TPU's library, and pytest-xdist gives a file to one worker.
@@ -56,6 +57,17 @@ CASES = [
     (31, 16, "int8", "plain", "s32[40,48,32]"),
     (16, 16, "int8", "plain", "s32[40,48,16]"),
     (8, 16, "int8", "plain", "s32[48,48,8]"),
+    # the narrow widths of a leafwise round (growth._pass_widths, PR 34: 4
+    # and 8; 2 nodes run as 4, and stay here as the shape under the rule)
+    (255, 2, "int8", "folded", "s32[39,32,128]"),
+    (255, 4, "int8", "folded", "s32[39,32,128]"),
+    (255, 8, "int8", "folded", "s32[39,64,128]"),
+    (255, 2, "bf16", "folded", "f32[39,16,128]"),
+    (255, 4, "bf16", "folded", "f32[39,32,128]"),
+    (255, 8, "bf16", "folded", "f32[39,48,128]"),
+    (63, 2, "int8", "plain", "s32[40,16,64]"),
+    (63, 4, "int8", "plain", "s32[40,16,64]"),
+    (63, 8, "int8", "plain", "s32[40,32,64]"),
 ]
 
 

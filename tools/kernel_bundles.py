@@ -11,6 +11,9 @@ the pass: 39,818 bundles a 4096-row step gave 0.4428 s for the 255-bin
 W = 16 pass that measured 0.444 s on the chip, 35,251 gave 0.3920 for the
 root's 0.391 (PERF.md §5, PR 29). The slot table says which unit a pass is
 bound by: on v5e the 4 VALU slots that build the one-hot, not the MXU's.
+``--nodes 4|8`` are shapes the program runs since PR 34: the widths a
+leafwise round's pass is staged at (``growth._pass_widths``; 2 is not: a
+2-node pass measured 1-3 ms under the 4-node one).
 
 A computed figure, not a measurement: report it as "static", never as a
 device time. The compile runs in a child process, because the dump ends in
