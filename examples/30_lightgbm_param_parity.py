@@ -48,8 +48,8 @@ def main():
     print(f"stopped after {len(hist['auc'])} evaluated iterations, "
           f"best AUC {max(hist['auc']):.4f} "
           f"(model truncated to {model.booster.num_iterations} trees)")
-    print(f"train logloss path: {hist['training_binary_logloss'][0]:.3f} "
-          f"-> {hist['training_binary_logloss'][-1]:.3f}")
+    print(f"train AUC path: {hist['training_auc'][0]:.3f} "
+          f"-> {hist['training_auc'][-1]:.3f}")
     assert max(hist["auc"]) > 0.9
 
     native = model.get_native_model()

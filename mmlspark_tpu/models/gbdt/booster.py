@@ -47,8 +47,8 @@ from . import quantize as _quantize
 from .growth import (GrowConfig, Tree, bitset_words, grow_tree,
                      grow_tree_depthwise, predict_forest_raw,
                      predict_tree_binned)
-from .objectives import (HIGHER_IS_BETTER, Objective, eval_metric,
-                         get_objective, score_transform)
+from .objectives import (GLOBAL_EVAL_METRICS, HIGHER_IS_BETTER, Objective,
+                         eval_metric, get_objective, score_transform)
 
 
 # bounded LRU of compiled boosting steps: one executable per
@@ -684,6 +684,16 @@ class LightGBMDataset:
     def num_features(self) -> int:
         return int(self.Xbt_d.shape[0])
 
+    def eval_weight(self):
+        """The rows' weight with validity folded in (padding and dead rows
+        weigh nothing), as a metric over these rows takes it. Default unit
+        weights ARE the validity mask."""
+        if self.w_d is self.vmask_d:
+            return self.w_d
+        return _cached_program(
+            ("eval_weight", self.w_d.shape, self.mesh),
+            lambda: jax.jit(jnp.multiply))(self.w_d, self.vmask_d)
+
     @classmethod
     def construct(cls, X=None, y=None, weight=None, *, max_bin: int = 255,
                   bin_sample_count: int = 200_000, seed: int = 0,
@@ -691,7 +701,20 @@ class LightGBMDataset:
                   row_valid: Optional[np.ndarray] = None,
                   bin_dtype=None, path=None, label_path=None,
                   weight_path=None, chunk_rows: Optional[int] = None,
-                  max_bin_by_feature=None) -> "LightGBMDataset":
+                  max_bin_by_feature=None,
+                  reference: Optional["LightGBMDataset"] = None
+                  ) -> "LightGBMDataset":
+        """``reference`` (LightGBM's ``Dataset(reference=train)``): bin these
+        rows with that dataset's binner, into its storage dtype, on its mesh,
+        with no binner fit: how a validation set is built once, for every
+        fit against ``reference`` to hold (``train_booster(valid_set=...)``).
+        """
+        if reference is not None:
+            if path is not None:
+                raise ValueError("reference= takes in-memory arrays")
+            max_bin, mesh = reference.max_bin, reference.mesh
+            categorical_features = reference.categorical_features
+            bin_dtype = reference.Xbt_d.dtype
         if path is None and (label_path is not None
                              or weight_path is not None
                              or chunk_rows is not None):
@@ -733,6 +756,10 @@ class LightGBMDataset:
         X = np.asarray(X, dtype=np.float32)
         y = np.asarray(y, dtype=np.float32)
         n, F = X.shape
+        if reference is not None and F != reference.num_features:
+            raise ValueError(
+                f"the reference dataset has {reference.num_features} "
+                f"features, these rows {F}")
         bad_cats = [int(i) for i in categorical_features
                     if not (0 <= int(i) < F)]
         if bad_cats:
@@ -744,9 +771,10 @@ class LightGBMDataset:
         with _spans.span("gbdt_dataset", rows=n, features=F) as ds, \
                 _Phases(ds) as phases:
             phases.enter("gbdt_binner_fit")
-            binner = QuantileBinner(max_bin, bin_sample_count, seed,
-                                    categorical_features,
-                                    max_bin_by_feature).fit(X)
+            binner = reference.binner if reference is not None else \
+                QuantileBinner(max_bin, bin_sample_count, seed,
+                               categorical_features,
+                               max_bin_by_feature).fit(X)
             # transfers are asynchronous: what of the raw matrix's upload
             # outlasts this phase shows in gbdt_dataset_bin, which waits
             phases.enter("gbdt_dataset_xfer")
@@ -1465,10 +1493,9 @@ def _fused_es_scan(one_iter, state0, num_iterations: int,
     metric f32 scalar)`` — metric is ignored when ``track_metric`` is
     False. Returns ``(buf [T, Tp], mbuf [T], n_done i32, best_it i32)``;
     without metric tracking the scan runs every iteration and
-    ``best_it = -1``. With it, iteration 0 runs inline (its packed length
-    sizes the static buffer) and a ``lax.while_loop`` applies the same
-    stopping bookkeeping the host loops use. ``tol`` is the
-    improvementTolerance: an iteration only counts as improved when it
+    ``best_it = -1``. With it, a ``lax.while_loop`` runs every iteration
+    and applies the same stopping bookkeeping the host loops use. ``tol`` is
+    the improvementTolerance: an iteration only counts as improved when it
     beats the best metric by more than tol. The default 0.0 mirrors the
     host's strict compare; note a device-side tol below one f32 ulp of
     the metric value vanishes (the compare runs in f32, the host's in
@@ -1493,14 +1520,19 @@ def _fused_es_scan(one_iter, state0, num_iterations: int,
                 jnp.where(improved, it, best_it),
                 jnp.where(improved, 0, rni + 1))
 
-    it0 = jnp.int32(0)
-    state, packed0, m0 = one_iter(it0, state0)
-    buf = jnp.zeros((num_iterations, packed0.shape[0]),
-                    packed0.dtype).at[0].set(packed0)
-    mbuf = jnp.full((num_iterations,), jnp.nan, jnp.float32).at[0].set(m0)
-    init_best = jnp.float32(-jnp.inf if higher_is_better else jnp.inf)
-    best, best_it, rni = track(init_best, jnp.int32(-1), jnp.int32(0),
-                               m0, it0)
+    # one_iter (a whole tree's growth) is traced once, here, to a jaxpr whose
+    # result shapes size the static buffers; the while body replays that
+    # jaxpr, so the round is staged, lowered and compiled once and every
+    # iteration, the first included, runs in the loop
+    closed, shapes = jax.make_jaxpr(one_iter, return_shape=True)(
+        jnp.int32(0), state0)
+    out_tree = jax.tree_util.tree_structure(shapes)
+    packed_s = shapes[1]
+
+    def staged_iter(it, state):
+        return jax.tree_util.tree_unflatten(out_tree, jax.core.eval_jaxpr(
+            closed.jaxpr, closed.consts,
+            *jax.tree_util.tree_leaves((it, state))))
 
     def cond(carry):
         it = carry[0]
@@ -1511,14 +1543,18 @@ def _fused_es_scan(one_iter, state0, num_iterations: int,
 
     def body(carry):
         it, state, best, best_it, rni, buf, mbuf = carry
-        state, packed, m = one_iter(it, state)
+        state, packed, m = staged_iter(it, state)
         buf = lax.dynamic_update_index_in_dim(buf, packed, it, 0)
         mbuf = mbuf.at[it].set(m)
         best, best_it, rni = track(best, best_it, rni, m, it)
         return it + 1, state, best, best_it, rni, buf, mbuf
 
-    it, _, _, best_it, _, buf, mbuf = lax.while_loop(
-        cond, body, (jnp.int32(1), state, best, best_it, rni, buf, mbuf))
+    it, _, _, best_it, _, buf, mbuf = lax.while_loop(cond, body, (
+        jnp.int32(0), state0,
+        jnp.float32(-jnp.inf if higher_is_better else jnp.inf),
+        jnp.int32(-1), jnp.int32(0),
+        jnp.zeros((num_iterations,) + packed_s.shape, packed_s.dtype),
+        jnp.full((num_iterations,), jnp.nan, jnp.float32)))
     return buf, mbuf, it, best_it
 
 
@@ -1544,6 +1580,31 @@ def _grow_with_warmup(grow, it_scalar, cfg, qk, binned_t, grad_k, hess_k,
                      axis_name=axis_name, is_cat=is_cat, qkey=None),
         lambda: grow(binned_t, grad_k, hess_k, row_mask, fmask, cfg,
                      axis_name=axis_name, is_cat=is_cat, qkey=qk))
+
+
+def _note_valid_evals(metric: str, where: str, evals: int, rows: int) -> None:
+    """``gbdt_valid_metric_total{metric, where=device|host}``: evaluations of
+    the validation metric that a fit's history records, by where the round
+    loop read them (the host loop one a round on the host, the fused path all
+    ``n_done`` in the device's ``while_loop``), and ``gbdt_valid_rows_total``,
+    the held-out rows scored for them."""
+    _metrics.safe_counter("gbdt_valid_metric_total", metric=metric,
+                          where=where).inc(evals)
+    _metrics.safe_counter("gbdt_valid_rows_total").inc(evals * rows)
+
+
+def _combine_metric(local, local_wsum, metric_name: str):
+    """One shard's ``eval_metric`` value to the whole set's (inside
+    ``shard_map`` over ``data``): a weighted mean is combined by weight,
+    ``rmse`` through its square; a metric that ``eval_metric`` already took
+    over every shard's rows (``GLOBAL_EVAL_METRICS``) is passed through."""
+    if metric_name in GLOBAL_EVAL_METRICS:
+        return local
+    wsum = jax.lax.psum(local_wsum, "data")
+    if metric_name == "rmse":
+        return jnp.sqrt(jax.lax.psum(local * local * local_wsum, "data")
+                        / wsum)
+    return jax.lax.psum(local * local_wsum, "data") / wsum
 
 
 def _grow_axis_for(mesh, cfg) -> "str | None":
@@ -1576,7 +1637,7 @@ def train_booster(
     bagging_fraction: float = 1.0,
     bagging_freq: int = 0,
     seed: int = 0,
-    valid_set: Optional[Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]] = None,
+    valid_set: "Optional[LightGBMDataset | Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]]" = None,
     early_stopping_rounds: int = 0,
     init_booster: Optional[Booster] = None,
     boost_from_average: bool = True,
@@ -1619,6 +1680,16 @@ def train_booster(
     ``max_bin``/``bin_sample_count``/``categorical_features``/``row_valid``/
     ``mesh`` are taken from the dataset (``X`` may still be passed alongside
     for ``init_booster`` warm starts, which score raw rows).
+
+    ``valid_set`` is the held-out rows: a :class:`LightGBMDataset` built once
+    with ``construct(..., reference=<the training dataset>)`` (binned by the
+    training set's binner, resident, sharded like the training rows: what
+    LightGBM's ``Dataset(reference=train)`` is), or ``(X, y, weight)`` host
+    arrays, from which one is built for this fit. After every iteration the
+    new trees score them and the metric (``eval_metric_name``, else the
+    objective's default) is taken on the device, ``auc`` included; without
+    callbacks, checkpoints or a longer ``metric_eval_period`` the whole
+    early-stopped fit is one dispatch (``path`` ``fused_valid``).
     """
     phases, fit = _phases, _phases.parent       # the gbdt_fit span's own
     phases.enter("gbdt_fit_prepare")
@@ -1716,7 +1787,6 @@ def train_booster(
     # objective family before anything traces
     requested_metric = (eval_metric_name or "").strip() or None
     eval_override = requested_metric
-    auc_host = False
     if eval_override:
         from .objectives import SUPPORTED_EVAL_METRICS
         fam = objective if objective in ("binary", "multiclass",
@@ -1730,14 +1800,6 @@ def train_booster(
             raise ValueError("metric overrides are not supported with "
                              "dart (its fused drop-schedule eval keeps the "
                              "objective default)")
-        auc_host = eval_override == "auc"
-        if auc_host:
-            eval_override = None      # device steps keep the default metric
-            if jax.process_count() > 1:
-                raise ValueError(
-                    "metric='auc' computes the exact rank statistic on "
-                    "the host and needs the validation scores addressable "
-                    "in one process")
 
     ckpt_mgr = None
     ckpt_fingerprint = None
@@ -1902,28 +1964,44 @@ def train_booster(
         scores_d = _device_tile_scores(jnp.zeros(K, jnp.float32), n_pad, K,
                                        mesh)
 
+    # the validation set is a dataset binned once by the training set's
+    # binner (LightGBM's ``Dataset(reference=train)``); raw arrays are the
+    # thin case that builds one here
     has_valid = valid_set is not None
     valid_fp = None
+    nv = 0
     if has_valid:
-        Xv, yv, wv = valid_set
-        Xv = np.asarray(_densify(Xv), np.float32)
-        yv = np.asarray(yv, np.float32)
-        wv = np.ones_like(yv) if wv is None else np.asarray(wv, np.float32)
-        nv = len(yv)
-        if ckpt_mgr is not None:
-            # the valid set is NOT part of the resume fingerprint (a
-            # changed eval set must not discard training progress), so
-            # the exact-state vscores restore needs its own identity
-            # check — restoring V1's accumulated scores against V2's
-            # labels would silently corrupt early stopping
-            from ...utils.checkpoint import data_fingerprint as _vfp
-            valid_fp = _vfp(Xv, yv, wv)
-        Xvb_d, _ = placement.shard_rows(binner.transform(Xv), mesh)
-        yv_d, _ = placement.shard_rows(yv, mesh)
-        # fold validity into the weight so padded rows don't count
-        wv_pad, _ = meshlib.pad_rows(wv, nshards)
-        wv_pad = wv_pad * meshlib.validity_mask(nv, len(wv_pad))
-        wv_d, _ = placement.shard_rows(wv_pad, mesh)
+        Xv = None
+        if isinstance(valid_set, LightGBMDataset):
+            valid_ds = valid_set
+            if valid_ds.binner is not binner or valid_ds.mesh is not mesh:
+                raise ValueError(
+                    "a pre-built validation dataset has to be constructed "
+                    "with reference=<the training dataset>: it shares its "
+                    "binner and its mesh")
+            if init_booster is not None:
+                raise ValueError(
+                    "init_booster warm start scores raw validation rows: "
+                    "pass valid_set=(X, y, w) arrays")
+        else:
+            Xv, yv, wv = valid_set
+            Xv = np.asarray(_densify(Xv), np.float32)
+            yv = np.asarray(yv, np.float32)
+            if ckpt_mgr is not None:
+                # the valid set is NOT part of the resume fingerprint (a
+                # changed eval set must not discard training progress), so
+                # the exact-state vscores restore needs its own identity
+                # check — restoring V1's accumulated scores against V2's
+                # labels would silently corrupt early stopping
+                from ...utils.checkpoint import data_fingerprint as _vfp
+                valid_fp = _vfp(Xv, yv, np.ones_like(yv) if wv is None
+                                else np.asarray(wv, np.float32))
+            valid_ds = LightGBMDataset.construct(Xv, yv, wv,
+                                                 reference=dataset)
+        nv, nv_pad = valid_ds.n, valid_ds.n_pad
+        Xvb_d, yv_d = valid_ds.Xbt_d, valid_ds.y_d
+        # validity folded into the weight so padded rows don't count
+        wv_d = valid_ds.eval_weight()
         # same exact-state rule as the training scores above — but only
         # when the checkpoint was written against THIS valid set
         resume_vscores = (None if resume_state is None
@@ -1931,12 +2009,14 @@ def train_booster(
         if (resume_vscores is not None and valid_fp is not None
                 and resume_state.get("valid_fingerprint") == valid_fp
                 and np.asarray(resume_vscores).shape == (nv, K)):
-            vscores0 = np.asarray(resume_vscores, np.float32)
+            vscores_d, _ = placement.shard_rows(
+                np.asarray(resume_vscores, np.float32), mesh)
         elif init_booster is not None:
-            vscores0 = init_booster.predict_raw(Xv)
+            vscores_d, _ = placement.shard_rows(
+                init_booster.predict_raw(Xv).astype(np.float32), mesh)
         else:
-            vscores0 = np.tile(base[None, :], (nv, 1))
-        vscores_d, _ = placement.shard_rows(vscores0.astype(np.float32), mesh)
+            vscores_d = _device_tile_scores(jnp.asarray(base), nv_pad, K,
+                                            mesh)
     else:
         Xvb_d = yv_d = wv_d = vscores_d = None
 
@@ -1947,13 +2027,17 @@ def train_booster(
     is_rf = boosting_type == "rf"
     use_bagging = ((not use_goss) and bagging_freq > 0
                    and (bagging_fraction < 1.0 or stratified_bagging))
-    # device-side metric name (what the step computes); the published
-    # early-stopping metric name diverges only for host-computed auc
-    device_metric_name = eval_metric(
-        obj, jnp.zeros((1, K)) if K > 1 else jnp.zeros(1),
-        jnp.zeros(1), jnp.ones(1), metric=eval_override,
-        **objective_kwargs)[0]
-    metric_name = "auc" if auc_host else device_metric_name
+    # the metric's name as the history records it: an override's own, else
+    # the objective's default (a one-row probe names it)
+    metric_name = (eval_override if eval_override in GLOBAL_EVAL_METRICS
+                   else eval_metric(
+                       obj, jnp.zeros((1, K)) if K > 1 else jnp.zeros(1),
+                       jnp.zeros(1), jnp.ones(1), metric=eval_override,
+                       **objective_kwargs)[0])
+    # a rank statistic is taken over every shard's rows at once
+    metric_axis = "data" if nshards > 1 else None
+    if has_valid:
+        fit.set(valid_rows=nv, metric=metric_name)
 
     if boosting_type == "dart":
         return _train_dart(
@@ -2055,21 +2139,15 @@ def train_booster(
             # margin, combined across shards exactly like the valid metric
             tsc = scores if K > 1 else scores[:, 0]
             _, tnum = eval_metric(obj, tsc, yl, wl * vmask,
-                                  metric=eval_override, **objective_kwargs)
-            twsum = jax.lax.psum(jnp.sum(wl * vmask), "data")
-            tlocal = jnp.sum(wl * vmask)
-            if device_metric_name == "rmse":
-                metrics["train"] = jnp.sqrt(
-                    jax.lax.psum(tnum * tnum * tlocal, "data") / twsum)
-            else:
-                metrics["train"] = (jax.lax.psum(tnum * tlocal, "data")
-                                    / twsum)
+                                  metric=eval_override, axis_name=metric_axis,
+                                  **objective_kwargs)
+            metrics["train"] = _combine_metric(tnum, jnp.sum(wl * vmask),
+                                               metric_name)
         if has_valid:
             for k in range(K):
                 tr = jax.tree_util.tree_map(lambda a: a[k], trees_stacked)
                 vscores = vscores.at[:, k].add(
-                    predict_tree_binned(tr, vbinned, depth_cap,
-                                        is_cat=is_cat_j))
+                    predict_tree_binned(tr, vbinned, is_cat=is_cat_j))
             if is_rf:
                 # ensemble-so-far = base + average of accumulated raw trees
                 vbase = jnp.asarray(base)[None, :]
@@ -2078,25 +2156,15 @@ def train_booster(
                 veval = vscores
             sc = veval if K > 1 else veval[:, 0]
             _, num = eval_metric(obj, sc, vy, vw, metric=eval_override,
-                                 **objective_kwargs)
-            # metric is a weighted mean: combine across shards. The combine
-            # rule keys off the DEVICE-computed metric name — with a
-            # host-computed early-stopping metric (auc) the step still
-            # evaluates the objective default here
-            wsum = jax.lax.psum(jnp.sum(vw), "data")
-            local_wsum = jnp.sum(vw)
-            if device_metric_name == "rmse":
-                local = num * num * local_wsum
-                metrics["valid"] = jnp.sqrt(jax.lax.psum(local, "data") / wsum)
-            else:
-                metrics["valid"] = jax.lax.psum(num * local_wsum, "data") / wsum
+                                 axis_name=metric_axis, **objective_kwargs)
+            metrics["valid"] = _combine_metric(num, jnp.sum(vw), metric_name)
         return scores, vscores if has_valid else jnp.zeros((1, 1)), trees_stacked, metrics
 
     row_spec = P("data")
     row2_spec = P("data", None)
     col_spec = P(None, "data")
     in_specs = (col_spec, row_spec, row_spec, row_spec, row2_spec,
-                row2_spec if has_valid else P(), row_spec if has_valid else P(),
+                col_spec if has_valid else P(), row_spec if has_valid else P(),
                 row_spec if has_valid else P(), row2_spec if has_valid else P(),
                 P(), P(), P())
     out_specs = (row2_spec, row2_spec if has_valid else P(), P(), P())
@@ -2252,7 +2320,7 @@ def train_booster(
     # MMLSPARK_TPU_DISABLE_FUSED_VALID=1 forces the host loop.
     fuse_es = (has_valid and iteration_callback is None and ckpt_mgr is None
                and iterations_done == 0 and metric_eval_period == 1
-               and not provide_training_metric and not auc_host
+               and not provide_training_metric
                and not os.environ.get("MMLSPARK_TPU_DISABLE_FUSED_VALID"))  # graftlint: disable=resolve-before-cache-key (gates the fused path off entirely; never feeds a key)
     if fuse_es:
         fuse_key = (cache_key, num_iterations, seed, early_stopping_rounds,
@@ -2280,7 +2348,7 @@ def train_booster(
             return jax.jit(shard_map(
                 multi_local, mesh=mesh,
                 in_specs=(col_spec, row_spec, row_spec, row_spec, row2_spec,
-                          row2_spec, row_spec, row_spec, row2_spec),
+                          col_spec, row_spec, row_spec, row2_spec),
                 out_specs=(P(), P(), P(), P()), check_vma=False))
 
         multi_v = phases.program("fused_valid", fuse_key, build_multi_valid)
@@ -2298,6 +2366,7 @@ def train_booster(
         # not cross the host link
         mbuf = np.asarray(mbuf_dev[:n_done])
         history[metric_name].extend(float(x) for x in mbuf)
+        _note_valid_evals(metric_name, "device", n_done, nv)
         rows = np.asarray(buf_dev[:n_done])
         for it in range(n_done):
             # each buffer row is one iteration's pack of K stacked trees —
@@ -2391,22 +2460,12 @@ def train_booster(
 
             if provide_training_metric and (it % metric_eval_period == 0
                                             or it == num_iterations - 1):
-                # the train history records what the device step computes —
-                # with metric='auc' that is the objective default, so key by
-                # the device metric name, not the early-stopping one
-                history.setdefault(f"training_{device_metric_name}", []).append(
+                history.setdefault(f"training_{metric_name}", []).append(
                     float(metrics["train"]))  # graftlint: disable=hot-path-host-sync (deliberate per-eval-period metric download)
 
             if has_valid and (it % metric_eval_period == 0 or it == num_iterations - 1):
-                if auc_host:
-                    # exact weighted tie-handled AUC from the downloaded
-                    # validation margin (rank statistics don't psum)
-                    from .objectives import auc_weighted
-                    # (no rf rescale: AUC is rank-based, invariant under the
-                    # strictly increasing average-so-far transform)
-                    m = auc_weighted(np.asarray(vscores_d)[:nv, 0], yv, wv)  # graftlint: disable=hot-path-host-sync (deliberate: host AUC needs the validation margin)
-                else:
-                    m = float(metrics["valid"])  # graftlint: disable=hot-path-host-sync (deliberate per-eval-period metric download)
+                m = float(metrics["valid"])  # graftlint: disable=hot-path-host-sync (deliberate per-eval-period metric download)
+                _note_valid_evals(metric_name, "host", 1, nv)
                 history[metric_name].append(m)
                 _watchdog.report_training_metric("gbdt", it, loss=m,
                                                  metric_name=metric_name)
@@ -2553,7 +2612,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
             vc = jnp.stack(
                 [predict_tree_binned(
                     jax.tree_util.tree_map(lambda a: a[k], trees_stacked),
-                    vbinned, depth_cap, is_cat=is_cat_j)
+                    vbinned, is_cat=is_cat_j)
                  for k in range(K)], axis=1)
             vcontribs = lax.dynamic_update_slice(
                 vcontribs, vc[None], (it_i, 0, 0))
@@ -2564,12 +2623,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
         sc2 = base_j[None, :] + jnp.einsum("t,tnk->nk", scales, vcontribs)
         sc = sc2 if K > 1 else sc2[:, 0]
         _, num = eval_metric(obj, sc, vy, vw, **objective_kwargs)
-        wsum = jax.lax.psum(jnp.sum(vw), "data")
-        local_wsum = jnp.sum(vw)
-        if metric_name == "rmse":
-            return jnp.sqrt(jax.lax.psum(num * num * local_wsum, "data")
-                            / wsum)
-        return jax.lax.psum(num * local_wsum, "data") / wsum
+        return _combine_metric(num, jnp.sum(vw), metric_name)
 
     row_spec, row2_spec = P("data"), P("data", None)
     col_spec = P(None, "data")
@@ -2592,7 +2646,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
         dstep = jax.jit(shard_map(
             dart_step_local, mesh=mesh,
             in_specs=(col_spec, row_spec, row_spec, row_spec, c_spec, P(),
-                      row2_spec if has_valid else P(),
+                      col_spec if has_valid else P(),
                       c_spec if has_valid else P(), P(), P(), P()),
             out_specs=(c_spec, c_spec if has_valid else P(), P()),
             check_vma=False))
@@ -2609,7 +2663,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
     contribs_d = placement.device_put(
         np.zeros((T_max, npad, K), np.float32), sh(c_spec))
     vcontribs_d = (placement.device_put(
-        np.zeros((T_max, Xvb_d.shape[0], K), np.float32), sh(c_spec))
+        np.zeros((T_max, Xvb_d.shape[1], K), np.float32), sh(c_spec))
         if has_valid else np.zeros((), np.float32))
     dummy = np.zeros((), np.float32)
 
@@ -2686,7 +2740,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
             return jax.jit(shard_map(
                 multi_local, mesh=mesh,
                 in_specs=(col_spec, row_spec, row_spec, row_spec, c_spec,
-                          row2_spec if has_valid else P(),
+                          col_spec if has_valid else P(),
                           c_spec if has_valid else P(), P(), P(),
                           row_spec if has_valid else P(),
                           row_spec if has_valid else P()),

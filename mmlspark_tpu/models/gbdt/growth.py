@@ -1160,25 +1160,43 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
     return tree, row_node
 
 
-def predict_tree_binned(tree: Tree, binned: jnp.ndarray, depth_cap: int,
+def predict_tree_binned(tree: Tree, binned_t: jnp.ndarray,
                         is_cat: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Evaluate one tree on binned rows: [n, F] -> [n] leaf values."""
-    n = binned.shape[0]
-    node = jnp.zeros(n, dtype=jnp.int32)
+    """Evaluate one grown tree on binned rows: ``[F, n]`` (column-major, the
+    training matrix's layout) -> ``[n]`` leaf values.
 
-    def body(_, node):
-        f = tree.feat[node]
-        t = tree.thr_bin[node]
-        x = jnp.take_along_axis(binned, f[:, None], axis=1)[:, 0]
-        go_left = x <= t
+    A walk over the tree's allocated node slots in order, not over the rows'
+    depth: the growers hand out child slots above their parent's, so by the
+    time slot ``j`` is reached every row that will ever sit on it does, and
+    one pass moves them to its children. A step reads scalars of node ``j``
+    (no ``table[node]`` over the rows), one row of the matrix
+    (``dynamic_index_in_dim``, no ``take_along_axis``) and tests a category
+    through the select chain that training's routing uses
+    (``_bitset_words_of_rows``), so the whole scorer is ``node_count``
+    elementwise passes over ``n`` rows with no per-row gather, and a tree
+    that stopped at 11 nodes costs 11 of them."""
+    n = binned_t.shape[1]
+
+    def body(j, carry):
+        node, value = carry
+        x = lax.dynamic_index_in_dim(binned_t, tree.feat[j], 0,
+                                     keepdims=False).astype(jnp.int32)
+        go_left = x <= tree.thr_bin[j]
         if is_cat is not None:
-            go_left = jnp.where(is_cat[f],
-                                bit_test(tree.cat_bitset[node], x), go_left)
-        nxt = jnp.where(go_left, tree.left[node], tree.right[node])
-        return jnp.where(tree.is_leaf[node], node, nxt)
+            word = _bitset_words_of_rows(
+                lax.dynamic_index_in_dim(tree.cat_bitset, j, 0), x[None, :])[0]
+            member = ((word >> (x.astype(jnp.uint32) & 31)) & 1).astype(bool)
+            go_left = jnp.where(is_cat[tree.feat[j]], member, go_left)
+        here = node == j
+        # a row's value is that of the last slot it sat on, which is its leaf
+        value = jnp.where(here, tree.leaf_value[j], value)
+        nxt = jnp.where(go_left, tree.left[j], tree.right[j])
+        return jnp.where(here & ~tree.is_leaf[j], nxt, node), value
 
-    node = lax.fori_loop(0, depth_cap, body, node)
-    return tree.leaf_value[node]
+    with jax.named_scope("gbdt_valid_score"):
+        return lax.fori_loop(
+            0, tree.node_count, body,
+            (jnp.zeros(n, jnp.int32), jnp.zeros(n, tree.leaf_value.dtype)))[1]
 
 
 def raw_to_cat_bin(x: jnp.ndarray, max_bin_idx: int) -> jnp.ndarray:

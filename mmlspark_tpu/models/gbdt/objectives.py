@@ -13,6 +13,7 @@ from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 class Objective(NamedTuple):
@@ -309,9 +310,10 @@ def score_transform(objective: str, num_class: int = 1, **kwargs):
 HIGHER_IS_BETTER = {"ndcg", "auc", "map"}
 
 # metric-param override support (reference: LightGBMParams `metric`): which
-# eval metrics each objective family accepts. "auc" is host-computed (exact
-# rank statistic — not a weighted mean, so it cannot ride the psum combine);
-# everything else evaluates on device, fused early stopping included.
+# eval metrics each objective family accepts. All evaluate on device, fused
+# early stopping included. "auc" is a rank statistic, not a weighted mean:
+# it is taken over all shards' rows at once (:func:`auc_device`) and comes
+# back replicated, so it skips the psum combine of the others.
 SUPPORTED_EVAL_METRICS = {
     "binary": ("binary_logloss", "binary_error", "auc"),
     "multiclass": ("multi_logloss", "multi_error"),
@@ -323,14 +325,17 @@ SUPPORTED_EVAL_METRICS = {
 def eval_metric(objective: Objective, scores, y, w,
                 group_size: int = 0, max_position: int = 20,
                 eval_at: int = 0, metric: str = None,
-                label_gain=None, **_unused) -> Tuple[str, jnp.ndarray]:
+                label_gain=None, axis_name: str = None,
+                **_unused) -> Tuple[str, jnp.ndarray]:
     """Per-objective eval metric (higher_is_better handled by caller).
 
     ``metric`` overrides the objective's default with another supported
     metric of the same family (LightGBM `metric` param; validated by the
     caller against SUPPORTED_EVAL_METRICS). Every value returned here is a
     LOCAL weighted mean — the training step re-combines across shards by
-    weight, with the "rmse" name square/sqrt special case.
+    weight, with the "rmse" name square/sqrt special case — except "auc"
+    (:data:`GLOBAL_EVAL_METRICS`): with ``axis_name`` the rows of all
+    shards are gathered first and every shard returns the same, final value.
 
     ``eval_at`` (the reference's evalAt positions) truncates the NDCG metric
     independently of the lambdarank training truncation ``max_position``.
@@ -341,6 +346,8 @@ def eval_metric(objective: Objective, scores, y, w,
             "lambdarank training/evaluation requires group_size (padded "
             "group width); a model loaded for scoring cannot train")
     if metric:
+        if name == "binary" and metric == "auc":
+            return "auc", auc_device(scores, y, w, axis_name=axis_name)
         if name == "binary" and metric == "binary_error":
             miss = ((scores > 0.0) != (y > 0.5)).astype(jnp.float32)
             return "binary_error", jnp.sum(miss * w) / jnp.sum(w)
@@ -358,8 +365,7 @@ def eval_metric(objective: Objective, scores, y, w,
                 # LightGBM l2 is MSE (not RMSE) — plain weighted mean, so
                 # the cross-shard combine needs no special case
                 return "l2", jnp.sum((pred - y) ** 2 * w) / jnp.sum(w)
-        # remaining supported values are the family defaults (or host-side
-        # auc, which never reaches this function)
+        # remaining supported values are the family defaults
     if name == "lambdarank":
         S = int(group_size)
         if scores.shape[0] < S or scores.shape[0] % S != 0:
@@ -380,27 +386,65 @@ def eval_metric(objective: Objective, scores, y, w,
     return "rmse", jnp.sqrt(jnp.sum(se * w) / jnp.sum(w))
 
 
-def auc_weighted(scores, y, w) -> float:
-    """Exact weighted AUC with tie-averaged ranks (host numpy; LightGBM's
-    binary `auc` metric semantics). Used for metric="auc" early stopping —
-    an exact rank statistic can't ride the device weighted-mean combine."""
-    import numpy as np
+# metrics that :func:`eval_metric` returns whole (over every shard's rows,
+# replicated) and not as a local weighted mean to be combined
+GLOBAL_EVAL_METRICS = frozenset({"auc"})
 
-    scores = np.asarray(scores, np.float64)
-    pos = np.asarray(y, np.float64) > 0.5
-    w = (np.ones_like(scores) if w is None
-         else np.asarray(w, np.float64))
-    order = np.argsort(scores, kind="mergesort")
-    s, p, ww = scores[order], pos[order], w[order]
-    wpos = np.where(p, ww, 0.0)
-    wneg = np.where(p, 0.0, ww)
-    # tie groups: runs of equal score share a rank; a positive in a group
-    # is "above" all lighter negatives plus half the group's own negatives
-    starts = np.flatnonzero(np.concatenate([[True], np.diff(s) != 0]))
-    gpos = np.add.reduceat(wpos, starts)
-    gneg = np.add.reduceat(wneg, starts)
-    cneg_before = np.concatenate([[0.0], np.cumsum(gneg)[:-1]])
-    tp, tn = wpos.sum(), wneg.sum()
-    if tp <= 0 or tn <= 0:
-        return 0.5               # degenerate: single class (LightGBM: NaN)
-    return float(np.sum(gpos * (cneg_before + 0.5 * gneg)) / (tp * tn))
+_AUC_BLOCK = 1024
+
+
+def _blocked_sum(x):
+    """Sum of ``x`` [n] (n a multiple of ``_AUC_BLOCK``) in two levels, so
+    that no partial sum is carried across more than a block's, then a block
+    count's, additions whatever order the backend reduces in."""
+    return jnp.sum(jnp.sum(x.reshape(-1, _AUC_BLOCK), axis=1))
+
+
+def auc_device(scores, y, w, axis_name: str = None):
+    """Exact weighted AUC with ties counted half (LightGBM's binary ``auc``),
+    on the device: f32 scalar; 0.5 where a class is absent.
+
+    One sort of the margin carries the rows' weight along, signed by class. With ``C`` the running sum of negative weight in that order, a
+    positive row is above the negative weight before its tie group and level
+    with the group's own, so it scores ``(C before the group + C through the
+    group) / 2``; both are handed to every row of a group by a running
+    maximum from the group's first row and a reverse running minimum from
+    its last (``C`` never falls), so there is no gather and no segment
+    arithmetic. Accumulation is f32 in **blocked sums**: the running sum is
+    log-depth, exact for whole-number weights below 2^24 in all (unit
+    weights up to 16.7 M rows); each row's score is divided by the negative
+    weight before the last sum, so what is summed lies in [0, 1], and that
+    sum is taken in blocks of 1024 and then over the blocks: at 2.95 M rows
+    the rounding left is a few 1e-8 of the value.
+
+    Inside ``shard_map`` give ``axis_name``: margin, label and weight are
+    gathered over it first, and every shard computes the same value from the
+    same arrays in the same order, so a loop condition that reads it stays
+    replicated."""
+    with jax.named_scope("gbdt_valid_metric"):
+        scores = scores.astype(jnp.float32)
+        pos = y > 0.5
+        w = w.astype(jnp.float32)
+        if axis_name is not None:
+            scores, pos, w = (lax.all_gather(a, axis_name, tiled=True)
+                              for a in (scores, pos, w))
+        pad = -scores.shape[0] % _AUC_BLOCK
+        # weightless rows at +inf: last in the order, nothing in any sum.
+        # One payload, the weight signed by the class (a weightless row has
+        # no class to keep); the order inside a tie group is free
+        scores = jnp.pad(scores, (0, pad), constant_values=jnp.inf)
+        s, signed = lax.sort((scores, jnp.pad(jnp.where(pos, w, -w),
+                                              (0, pad))),
+                             num_keys=1, is_stable=False)
+        wpos, wneg = jnp.maximum(signed, 0.0), jnp.maximum(-signed, 0.0)
+        edge, end = s[1:] != s[:-1], jnp.ones(1, bool)
+        first = jnp.concatenate([end, edge])
+        last = jnp.concatenate([edge, end])
+        through = jnp.cumsum(wneg)
+        below = lax.cummax(jnp.where(first, through - wneg, -jnp.inf))
+        level = lax.cummin(jnp.where(last, through, jnp.inf), reverse=True)
+        tp, tn = _blocked_sum(wpos), _blocked_sum(wneg)
+        rank = _blocked_sum(wpos * (0.5 * (below + level)
+                                    / jnp.maximum(tn, 1e-30)))
+        return jnp.where((tp > 0) & (tn > 0),
+                         rank / jnp.maximum(tp, 1e-30), jnp.float32(0.5))

@@ -247,3 +247,78 @@ def test_device_program_names_its_layers(lowered_text, name):
     assert hits, name
     if name not in ("gbdt_grad", "gbdt_score_update", "gbdt_hist_kernel"):
         assert {0, 1} <= set(hits)      # both growth policies carry it
+
+
+def _valid_counts():
+    snap = metrics.get_registry().snapshot()
+    evals = {(s["labels"]["metric"], s["labels"]["where"]): s["value"]
+             for s in (snap.get("gbdt_valid_metric_total") or {}).get(
+                 "series", [])}
+    rows = sum(s["value"] for s in (snap.get("gbdt_valid_rows_total")
+                                    or {}).get("series", []))
+    return evals, rows
+
+
+@pytest.mark.parametrize("path,where", [("fused_valid", "device"),
+                                        ("host_loop", "host")])
+def test_validated_fit_says_its_rows_and_metric_and_counts_its_evaluations(
+        dataset, path, where):
+    """``gbdt_fit`` gains ``valid_rows`` and ``metric``;
+    ``gbdt_valid_metric_total{metric, where}`` counts once an evaluation the
+    history records, by where the round loop read it, and
+    ``gbdt_valid_rows_total`` the held-out rows scored for them."""
+    Xv, yv = _data(1)
+    held = gb.LightGBMDataset.construct(Xv[:520], yv[:520],
+                                        reference=dataset)
+    spans.clear_trace()
+    (evals0, rows0), kw = _valid_counts(), {}
+    if path == "host_loop":
+        kw["iteration_callback"] = lambda it, m: None
+    b = gb.train_booster(
+        dataset=dataset, objective="binary", num_iterations=4, seed=3501,
+        cfg=growth.GrowConfig(num_leaves=5, min_data_in_leaf=5),
+        valid_set=held, eval_metric_name="auc", early_stopping_rounds=3,
+        **kw)
+    (fit, _), = _fits_and_children()
+    assert fit["args"]["path"] == path
+    assert (fit["args"]["valid_rows"], fit["args"]["metric"]) == (520, "auc")
+    recorded = len(b.eval_history["auc"])
+    assert 1 <= recorded <= 4
+    evals, rows = _valid_counts()
+    moved = {k: v - evals0.get(k, 0) for k, v in evals.items()
+             if v != evals0.get(k, 0)}
+    assert moved == {("auc", where): recorded}
+    assert rows - rows0 == recorded * 520
+
+
+def test_unvalidated_fit_has_no_validation_attributes(dataset):
+    evals0, rows0 = _valid_counts()
+    _fit(dataset, seed=3502)
+    (fit, _), = _fits_and_children()
+    assert "valid_rows" not in fit["args"] and "metric" not in fit["args"]
+    assert _valid_counts() == (evals0, rows0)
+
+
+def test_validated_program_names_its_scorer_and_metric(dataset):
+    Xv, yv = _data(1)
+    held = gb.LightGBMDataset.construct(Xv[:520], yv[:520],
+                                        reference=dataset)
+    gb.train_booster(
+        dataset=dataset, objective="binary", num_iterations=2, seed=3503,
+        cfg=growth.GrowConfig(num_leaves=5, min_data_in_leaf=5),
+        valid_set=held, eval_metric_name="auc", early_stopping_rounds=2)
+    key, = [k for k in gb._STEP_CACHE
+            if k[-1] == "fused_valid" and k[2] == 3503]
+    scores = gb._device_tile_scores(jnp.zeros(1, jnp.float32),
+                                    dataset.n_pad, 1, dataset.mesh)
+    vscores = gb._device_tile_scores(jnp.zeros(1, jnp.float32),
+                                     held.n_pad, 1, held.mesh)
+    text = gb._STEP_CACHE[key].lower(
+        dataset.Xbt_d, dataset.y_d, dataset.w_d, dataset.vmask_d, scores,
+        held.Xbt_d, held.y_d, held.eval_weight(), vscores).as_text(
+            debug_info=True)
+    for name in ("gbdt_valid_score", "gbdt_valid_metric", "gbdt_hist",
+                 "gbdt_route"):
+        assert re.search(r"\b" + name + r"\b", text), name
+    # the round is in the loop's body and nowhere else: staged once
+    assert text.count("stablehlo.while") >= 1

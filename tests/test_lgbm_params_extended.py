@@ -260,7 +260,7 @@ class TestMetricOverride:
         np.testing.assert_allclose(host.booster.eval_history["binary_error"],
                                    hist, rtol=1e-6)
 
-    def test_auc_host_early_stopping(self):
+    def test_auc_device_early_stopping(self):
         X, y = _binary()
         vi = (np.arange(len(y)) % 4 == 0)
         m = LightGBMClassifier(numIterations=15, numLeaves=15, maxBin=63,
@@ -270,19 +270,28 @@ class TestMetricOverride:
         hist = m.booster.eval_history["auc"]
         assert len(hist) >= 1 and max(hist) > 0.9
         assert all(0.0 <= v <= 1.0 for v in hist)
+        # one dispatch: the metric no longer forces the host loop
+        from mmlspark_tpu.observability import spans
+        fits = [e for e in spans.get_trace_events()
+                if e["ph"] == "X" and e["name"] == "gbdt_fit"]
+        assert fits[-1]["args"]["path"] == "fused_valid"
+        assert fits[-1]["args"]["metric"] == "auc"
 
     def test_auc_matches_sklearn(self):
         from sklearn.metrics import roc_auc_score
 
-        from mmlspark_tpu.models.gbdt.objectives import auc_weighted
+        from _gbdt_reference import auc_float64
+        from mmlspark_tpu.models.gbdt.objectives import auc_device
 
         rng = np.random.default_rng(0)
         s = np.round(rng.normal(size=500), 1)     # rounding forces ties
         y = (s + rng.normal(scale=1.0, size=500) > 0).astype(float)
         w = rng.random(500) + 0.1
-        ours = auc_weighted(s, y, w)
         ref = roc_auc_score(y, s, sample_weight=w)
-        assert abs(ours - ref) < 1e-10
+        # the tests' float64 reference is sklearn's; the device's is it to f32
+        assert abs(auc_float64(s, y, w) - ref) < 1e-10
+        ours = float(auc_device(np.float32(s), np.float32(y), np.float32(w)))
+        assert abs(ours - ref) < 1e-6
 
     def test_mae_regression(self):
         from mmlspark_tpu.models.gbdt.api import LightGBMRegressor

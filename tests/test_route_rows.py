@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _gbdt_reference import walk_by_gathers
 from mmlspark_tpu.models.gbdt import growth
 from mmlspark_tpu.models.gbdt.booster import LightGBMDataset, train_booster
 from mmlspark_tpu.models.gbdt.growth import (GrowConfig, Tree,
@@ -145,7 +146,9 @@ def _cat_table(n=3000, seed=0):
 def test_fit_counts_equal_predict_path_counts(policy):
     """Tier-1 twin of the benchmark's ``count_gap``: each leaf's ``node_cnt``
     is the number of rows the predict walk sends there. Growth routes with
-    the select chain, ``predict_tree_binned`` with ``bit_test``."""
+    the select chain; the walk is checked twice, ``predict_tree_binned``
+    (the node-slot walk, the chain again) and the gather walk with
+    ``bit_test`` that it replaced."""
     X, y = _cat_table()
     ds = LightGBMDataset.construct(X, y, max_bin=63,
                                    categorical_features=(0, 2))
@@ -165,8 +168,10 @@ def test_fit_counts_equal_predict_path_counts(policy):
     for t in range(b.trees.feat.shape[0]):
         tree = Tree(*(jnp.asarray(a[t]) for a in b.trees))
         ids = tree._replace(leaf_value=jnp.arange(M, dtype=jnp.float32))
-        leaf = np.asarray(predict_tree_binned(ids, binned, b.depth_cap,
+        leaf = np.asarray(predict_tree_binned(ids, binned.T,
                                               is_cat=is_cat)).astype(int)
+        np.testing.assert_array_equal(leaf, np.asarray(walk_by_gathers(
+            ids, binned.astype(jnp.int32), b.depth_cap, is_cat)).astype(int))
         walked = np.bincount(leaf, minlength=M)
         leaves = np.flatnonzero(np.asarray(tree.is_leaf)
                                 & (np.arange(M) < int(tree.node_count)))
