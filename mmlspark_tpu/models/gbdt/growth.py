@@ -10,10 +10,11 @@ sets per leaf, the TPU formulation keeps everything static-shape:
   * each row carries its current node id (``row_node``), updated by masked
     ``where`` — no repartitioning;
   * each of the ``num_leaves - 1`` split rounds is one ``fori_loop`` step:
-    pick the cached best leaf, build both children's histograms in a single
-    MXU pass (6 stats: grad/hess/count × left/right), find their best splits,
-    record the split — all data-dependent choices via argmax + where, never
-    Python control flow.
+    pick the cached best leaves, build their children's histograms in a
+    single MXU pass (float statistics: grad/hess/count of both children;
+    int8: of the left child, the right one being its parent's int32 sums
+    less the left's), find their best splits, record the split — all
+    data-dependent choices via argmax + where, never Python control flow.
 
 Layout: the binned matrix rides **column-major** (``binned_t``: [F, n]) for
 the whole training run — histogram row blocks and per-feature column reads
@@ -32,9 +33,11 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
-from ...ops.histogram import (accumulator_tile, node_histogram, quant_q_max,
-                              quantize_stats, round_stats)
+from ...ops.histogram import (accumulator_tile, dequantize_node_histogram,
+                              node_histogram, node_histogram_sums,
+                              quant_q_max, quantize_stats, round_stats)
 from ...parallel.compat import axis_size as _axis_size
 
 NEG_INF = jnp.float32(-jnp.inf)
@@ -179,6 +182,27 @@ def _allreduce(x, axis_name, what: str, per: str = "tree", op=lax.psum,
     return out
 
 
+def _allreduce_round_hist(h, axis_name):
+    """``psum`` of a derived round's ``[F, 3 * W, B]`` f32 histograms, summed
+    over the shards in the order the both-children program summed them in.
+
+    Each shard's operand is that program's to the bit, but an f32 ``psum``
+    is only as stable as its operand's layout: XLA adds the shards in an
+    order that follows an element's place in memory (ROADMAP D14), and the
+    compiler lays the operand out from what surrounds it. So the operand is
+    handed over as the ``[W, F, 3, B]`` array split search reads, in the
+    layout the both-children program's had on a v5e (stat-major: ``3, F, W,
+    B`` in memory), and the trees of a sharded int8 fit stay that program's
+    to the bit. Values do not depend on it, last bits do."""
+    if axis_name is None:
+        return h
+    F, S, B = h.shape
+    x = h.reshape(F, S // 3, 3, B).transpose(1, 0, 2, 3)
+    x = with_layout_constraint(x, Layout(major_to_minor=(2, 1, 0, 3)))
+    x = _allreduce(x, axis_name, "hist", "round")
+    return x.transpose(1, 0, 2, 3).reshape(F, S, B)
+
+
 def _hist_block_geometry(cfg: GrowConfig, axis_name, n: int):
     """(blocks_local, rows_per_block) for the blocked reduction; (0, n) on
     the plain psum path. Raises when a pinned block count cannot tile this
@@ -206,12 +230,13 @@ def _hist_block_geometry(cfg: GrowConfig, axis_name, n: int):
 
 def _pass_widths(W: int, B: int, quantized: bool) -> tuple:
     """The node widths a leafwise round's histogram pass is staged at, from
-    static shapes alone: the powers of two from 4 up to ``W = 2 *
-    leaf_batch`` child slots and ``W`` itself, less every width whose kernel
-    would have the next one's accumulator tile
-    (:func:`ops.histogram.accumulator_tile`: the wider of the two then costs
-    the same MXU tiles and holds more). A round runs the narrowest that
-    holds its live children (:func:`_hist_at_width`).
+    static shapes alone: the powers of two from 4 up to the round's ``W``
+    node slots (``2 * leaf_batch`` children; ``leaf_batch`` left children
+    where the siblings are derived, :func:`_sibling_is_derived`) and ``W``
+    itself, less every width whose kernel would have the next one's
+    accumulator tile (:func:`ops.histogram.accumulator_tile`: the wider of
+    the two then costs the same MXU tiles and holds more). A round runs the
+    narrowest that holds its live positions (:func:`_hist_at_width`).
 
     A pass scans all rows whatever the nodes hold, but its cost is not flat
     in the node axis: flat up to 4 nodes, then paid for by the MXU, a tile
@@ -220,8 +245,9 @@ def _pass_widths(W: int, B: int, quantized: bool) -> tuple:
     16; bf16 0.225, 0.229, 0.235, 0.369, and 0.226 at 2 nodes: a variant
     under 4 would buy 3 ms a pass for one more kernel to compile and load;
     PERF.md §5). A 31-leaf tree at ``leaf_batch`` 8 splits 1, 2, 4, 8, 8, 7
-    leaves in its rounds, so half of them are narrow.
-    ``W = 2`` (``leaf_batch`` 1) has the one width."""
+    leaves in its rounds: the int8 pass's live positions, run at 4, 4, 4, 8,
+    8, 8, and half the float pass's, run at 4, 4, 8, 16, 16, 16.
+    ``leaf_batch`` 1 has the one width."""
     itemsize = 1 if quantized else 2
     widths = []
     for w in sorted({1 << i for i in range(2, W.bit_length())} | {W}):
@@ -270,6 +296,48 @@ def _hist_at_width(hist_of, W: int, live, B: int, quantized: bool):
     narrower = jnp.asarray(widths[:-1], dtype=jnp.int32)
     return lax.switch(jnp.sum((live > narrower).astype(jnp.int32)),
                       [staged(w) for w in widths])
+
+
+def _sibling_is_derived(quantized: bool) -> bool:
+    """Whether a leafwise round histograms one child of every split and takes
+    the other as its parent's histogram less that one
+    (:func:`_derive_siblings`). It follows from the statistics' type alone.
+    int8 statistics sum exactly in int32, so the difference is the sibling's
+    own sum to the bit and the round's pass needs ``leaf_batch`` node slots
+    where both children need twice that (a pass is paid for in node slots,
+    :func:`_pass_widths`). A float difference at the parent's magnitude is
+    the fault :func:`_stat_totals` describes: float statistics sum both
+    children."""
+    return quantized
+
+
+def _note_sibling(sibling: str) -> None:
+    """gbdt_hist_sibling_total{sibling=derived|summed}: how a leafwise round
+    gets the second child of a split, counted where the round is staged out
+    (as :func:`_note_pass_width` counts), so it tracks program builds."""
+    try:
+        from ...observability import metrics as _metrics
+        _metrics.safe_counter("gbdt_hist_sibling_total",
+                              sibling=sibling).inc()
+    except Exception:  # noqa: BLE001 — telemetry must not fail the fit
+        pass
+
+
+@jax.named_scope("gbdt_hist")
+def _derive_siblings(left, hsum, slots, do):
+    """The int32 sums of both children of a round's ``KB`` candidates, from
+    the pass over the left ones. ``left``: ``[..., F, 3 * KB, B]``, node
+    position ``i`` the rows candidate ``i`` sends left; ``hsum``: ``[..., F,
+    M, 3, B]``, every node's own sums by node slot; ``slots``: ``[KB]``, the
+    candidates' slots; ``do``: ``[KB]``, which of them split. Returns
+    ``[..., F, 2 * KB, 3, B]``, child ``2 i`` the left one and ``2 i + 1`` =
+    parent - left the right one, zeros where ``do`` is false (as a pass over
+    both children leaves the slots that hold no row)."""
+    parents = jnp.take(hsum, slots, axis=-3)
+    left = left.reshape(parents.shape)
+    right = jnp.where(do[:, None, None], parents - left, 0)
+    kids = jnp.stack([left, right], axis=-3)         # [..., F, KB, 2, 3, B]
+    return kids.reshape(kids.shape[:-4] + (-1,) + kids.shape[-2:])
 
 
 def _blocked_fold(parts: jnp.ndarray, axis_name, what: str, per="tree"):
@@ -337,17 +405,22 @@ def _quantize_for(cfg: GrowConfig, base_t, qkey, axis_name, blocks_local,
 
 
 def _block_node_hists(binned_t, row_pos, base_t, W: int, B: int, qscales,
-                      blocks_local: int, rows_per_block: int):
+                      blocks_local: int, rows_per_block: int,
+                      sums: bool = False):
     """[blocks_local, F, W*3, B]: this shard's part of the canonical blocked
     reduction, one engine pass per fixed row block (identical shapes on
     every topology), for :func:`_blocked_fold` to gather and fold in block
-    order."""
+    order. ``sums``: each block's int32 sums of int8 statistics, not scaled
+    yet (they widen to f32 before the fold, never after)."""
+    def block(*segs):
+        if sums:
+            return node_histogram_sums(*segs, W, B, quantized=True)
+        return node_histogram(*segs, W, B, scales=qscales)
+
     return jnp.stack([
-        node_histogram(
-            binned_t[:, j * rows_per_block:(j + 1) * rows_per_block],
-            row_pos[j * rows_per_block:(j + 1) * rows_per_block],
-            base_t[:, j * rows_per_block:(j + 1) * rows_per_block],
-            W, B, scales=qscales)
+        block(binned_t[:, j * rows_per_block:(j + 1) * rows_per_block],
+              row_pos[j * rows_per_block:(j + 1) * rows_per_block],
+              base_t[:, j * rows_per_block:(j + 1) * rows_per_block])
         for j in range(blocks_local)])
 
 
@@ -722,13 +795,34 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         base_t, qscales = _quantize_for(cfg, base_t, qkey, axis_name, bl,
                                         rpb)
 
+    derive = _sibling_is_derived(qscales is not None)
+
     @jax.named_scope("gbdt_hist")
-    def all_hist(row_pos, W, per, live=None):
-        """Global per-node histogram [F, W*3, B] + selected-feature mask;
-        ``per``: how often the pass runs (:func:`_allreduce`); ``live``: a
-        round's count of node positions that hold rows, for the pass to run
-        no wider than it must (:func:`_hist_at_width`; the root's pass has
-        the one width).
+    def local_hist(row_pos, W, live=None):
+        """This shard's node histograms ``[F, W*3, B]`` (``[bl, F, W*3, B]``,
+        one a block, under hist_blocks) of one pass over all rows: f32, or
+        where a round derives its siblings the int32 sums, which
+        ``global_hist`` scales. ``live``: a round's count of node positions
+        that hold rows, for the pass to run no wider than it must
+        (:func:`_hist_at_width`; the root's pass has the one width)."""
+        def local(w):
+            if bl:
+                return _block_node_hists(binned_t, row_pos, base_t, w, B,
+                                         qscales, bl, rpb, sums=derive)
+            if derive:
+                return node_histogram_sums(binned_t, row_pos, base_t, w, B,
+                                           quantized=True)
+            return node_histogram(binned_t, row_pos, base_t, w, B,
+                                  scales=qscales)
+
+        return (local(W) if live is None else
+                _hist_at_width(local, W, live, B, qscales is not None))
+
+    @jax.named_scope("gbdt_hist")
+    def global_hist(h, W, per):
+        """Global per-node histogram [F, W*3, B] + selected-feature mask of
+        ``local_hist``'s; ``per``: how often the pass runs
+        (:func:`_allreduce`).
 
         data_parallel: one full [F, W*3, B] psum — or, under hist_blocks,
         the canonical blocked fold (topology-independent f32 order).
@@ -736,24 +830,20 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         global top-2k features' histograms (scattered back into a zeroed
         full array so downstream split search keeps static shapes;
         unselected features are masked)."""
-        def local(w):
-            if bl:
-                return _block_node_hists(binned_t, row_pos, base_t, w, B,
-                                         qscales, bl, rpb)
-            return node_histogram(binned_t, row_pos, base_t, w, B,
-                                  scales=qscales)
-
-        h = (local(W) if live is None else
-             _hist_at_width(local, W, live, B, qscales is not None))
+        if derive:
+            h = dequantize_node_histogram(h, qscales)
         if bl:
             return (_blocked_fold(h, axis_name, "hist", per),
                     jnp.ones(F, dtype=bool))
         if axis_name is None or not cfg.voting:
-            return (_allreduce(h, axis_name, "hist", per),
-                    jnp.ones(F, dtype=bool))
+            h = (_allreduce_round_hist(h, axis_name)
+                 if derive and per == "round" else
+                 _allreduce(h, axis_name, "hist", per))
+            return h, jnp.ones(F, dtype=bool)
         return _voting_select(h, feat_mask, cfg, axis_name, W, per)
 
-    root_hist, sel0 = all_hist(jnp.zeros(n, dtype=jnp.int32), 1, "tree")
+    root_local = local_hist(jnp.zeros(n, dtype=jnp.int32), 1)
+    root_hist, sel0 = global_hist(root_local, 1, "tree")
     # totals from the raw stats (not the histogram: under voting_parallel an
     # unselected feature's rows are zeroed there). Quantized mode totals the
     # DEQUANTIZED stats so node stats stay consistent with histogram sums;
@@ -791,10 +881,21 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         _note_float_sums("child_totals")
         state.update(crg=zf.at[0].set(right0[0]), crh=zf.at[0].set(right0[1]),
                      crc=zf.at[0].set(right0[2]))
+    if derive:
+        # this shard's int32 sums of every node, by node slot, for a round
+        # to take its right children from: ``[F, M, 3, B]``, the node axis
+        # where a pass has it (under hist_blocks ``[bl, F, M, 3, B]``: the
+        # blocks' sums widen to f32 before they fold). Kept from before the
+        # reduction, so that voting_parallel's zeroed features are whole in
+        # here.
+        state["hsum"] = jnp.zeros(
+            root_local.shape[:-2] + (M, 3, B), jnp.int32
+        ).at[..., 0, :, :].set(root_local)
 
     # Batched best-first: each round splits the top ``leaf_batch`` pending
     # leaves by cached gain in ONE fused histogram pass (their 2*KB children
-    # ride the flat stat axis). Leaves' row sets are disjoint, so batched
+    # ride the flat stat axis; the KB left ones where the siblings are
+    # derived). Leaves' row sets are disjoint, so batched
     # splits are exactly the splits sequential best-first would take — the
     # only divergence is split ORDER near num_leaves exhaustion (see
     # GrowConfig.leaf_batch). KB=1 reproduces strict sequential growth.
@@ -820,19 +921,31 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         new_row_node, move, goleft_k = _route_rows_to_children(
             binned_t, st["row_node"], slots, do, feats, bins_, bits_k, lid,
             is_cat)
-        # child position in [0, 2*KB): 2i = left child of candidate i
+        # a row's position in the round's pass. Both children summed: in
+        # [0, 2*KB), 2i = left child of candidate i. The sibling derived:
+        # in [0, KB), i = left child of candidate i, and a row that goes
+        # right rides the pass at no position, like one outside the frontier
+        _note_sibling("derived" if derive else "summed")
         with jax.named_scope("gbdt_route"):
-            cpos = jnp.where(goleft_k, 2 * arange_kb[:, None],
-                             2 * arange_kb[:, None] + 1)
+            if derive:
+                cpos = jnp.where(goleft_k, arange_kb[:, None], -1)
+            else:
+                cpos = jnp.where(goleft_k, 2 * arange_kb[:, None],
+                                 2 * arange_kb[:, None] + 1)
             in_any = jnp.any(move, axis=0)
             child_pos = jnp.where(
                 in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
             ).astype(jnp.int32)
 
         # ``do`` is a prefix of the gain-sorted candidates, so every live
-        # child position is under 2 * n_split
-        h, sel = all_hist(child_pos, W2, "round",   # [F, W2*3, B]
-                          live=2 * n_split)
+        # position is under n_split (2 * n_split with both children summed)
+        if derive:
+            kids = _derive_siblings(local_hist(child_pos, KB, live=n_split),
+                                    st["hsum"], slots, do)
+            h = kids.reshape(kids.shape[:-3] + (3 * W2, B))
+        else:
+            h = local_hist(child_pos, W2, live=2 * n_split)
+        h, sel = global_hist(h, W2, "round")               # [F, W2*3, B]
         hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
 
         # child totals, left from the candidate cache. Quantized: right =
@@ -888,6 +1001,9 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             if float_sums:
                 for k, r in zip(("crg", "crh", "crc"), right2):
                     new[k] = st[k].at[cslot].set(r, mode="drop")
+            if derive:
+                new["hsum"] = st["hsum"].at[..., cslot, :, :].set(
+                    kids, mode="drop")
             new["num_nodes"] = st["num_nodes"] + 2 * n_split
         return new
 

@@ -274,7 +274,31 @@ def quantize_stats(base_t: jnp.ndarray, key=None, *, amax=None, q_max=None,
 def node_histogram(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
                    base_t: jnp.ndarray, num_nodes: int,
                    num_bins: int, scales=None) -> jnp.ndarray:
-    """Per-frontier-node histograms in one fused pass: ``[F, W*3, B]``.
+    """Per-frontier-node histograms in one fused pass: ``[F, W*3, B]``
+    f32; the int8 sums of :func:`node_histogram_sums` dequantized by
+    ``scales`` (:func:`dequantize_node_histogram`), the float ones as they
+    are."""
+    out = node_histogram_sums(binned_t, row_pos, base_t, num_nodes, num_bins,
+                              quantized=scales is not None)
+    return out if scales is None else dequantize_node_histogram(out, scales)
+
+
+def dequantize_node_histogram(sums: jnp.ndarray, scales) -> jnp.ndarray:
+    """``[..., W*3, B]`` int32 sums of int8 statistics to f32, each channel
+    by its own scale of :func:`quantize_stats`."""
+    chan_scale = scales[jnp.arange(sums.shape[-2]) % 3]
+    return sums.astype(jnp.float32) * chan_scale[None, :, None]
+
+
+def node_histogram_sums(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
+                        base_t: jnp.ndarray, num_nodes: int, num_bins: int,
+                        quantized: bool = False) -> jnp.ndarray:
+    """Per-frontier-node sums in one fused pass: ``[F, W*3, B]``, f32 for
+    float statistics and, ``quantized``, the exact int32 sums of int8 ones,
+    still to be scaled. A sum over a subset of rows is then the sum over all
+    of them less the sum over the rest, to the bit: leafwise growth takes
+    one child of a split from a pass and the other from its parent
+    (``models.gbdt.growth.grow_tree``).
 
     binned_t: [F, n] int32/int16/uint8; row_pos: [n] int32 in [-1, W) — each row's
     position in the frontier (-1: row is at a finished leaf, contributes
@@ -288,14 +312,13 @@ def node_histogram(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
     level are just binned_t + [n] positions + [3, n] stats, vs the
     [3W, n] materialization the XLA fallback does).
 
-    ``scales`` (with int8 ``base_t`` from :func:`quantize_stats`) switches to
-    quantized-gradient histograms: int8 x int8 MXU contractions with int32
-    accumulation (2x bf16 throughput on v5e+), dequantized on return.
+    ``quantized`` (with int8 ``base_t`` from :func:`quantize_stats`) switches
+    to quantized-gradient histograms: int8 x int8 MXU contractions with int32
+    accumulation (2x bf16 throughput on v5e+).
     """
     F, n = binned_t.shape
     W = int(num_nodes)
     B = int(num_bins)
-    quantized = scales is not None
     eng = _select_engine(n, F, 3 * W, B, fused_w=W, quantized=quantized)
     if eng == "pallas":
         out = _node_hist_pallas(binned_t, row_pos, base_t, W, B,
@@ -325,9 +348,6 @@ def node_histogram(binned_t: jnp.ndarray, row_pos: jnp.ndarray,
             sb = jnp.where(woh[:, None, :], base_t[None, :, :], 0.0)
             return _hist_xla(binned_t,
                              sb.reshape(3 * W, n).astype(jnp.bfloat16), B)
-    if quantized:
-        chan_scale = scales[jnp.arange(3 * W) % 3]
-        out = out.astype(jnp.float32) * chan_scale[None, :, None]
     return out
 
 

@@ -8,7 +8,17 @@ live children; the slots past them were zeros and are zeros. So the trees
 have to be the ones the single width ``W2`` grows, which was the program
 before: to the bit, for int8 and for float statistics, on one shard and on
 four, under ``hist_blocks`` and ``voting_parallel``.
+
+With int8 statistics a round's pass holds one child of every split and the
+sibling is its parent's int32 sums less that child's
+(``growth._sibling_is_derived``, ``growth._derive_siblings``; PERF.md, PR 36):
+``leaf_batch`` node slots, not ``2 * leaf_batch``, and again the same trees
+to the bit, now against the build that sums both children. Float statistics
+keep the program they had.
 """
+
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +28,7 @@ import pytest
 from mmlspark_tpu.models.gbdt import growth
 from mmlspark_tpu.models.gbdt.growth import GrowConfig
 from mmlspark_tpu.observability import metrics
-from mmlspark_tpu.ops.histogram import node_histogram
+from mmlspark_tpu.ops.histogram import node_histogram, node_histogram_sums
 from mmlspark_tpu.parallel import mesh as meshlib
 from mmlspark_tpu.parallel.compat import shard_map
 from mmlspark_tpu.parallel.placement import pspec
@@ -58,6 +68,16 @@ def test_the_widths_are_a_rule_of_static_shapes(B, stats, leaf_batch, widths):
     assert got[-1] == 2 * leaf_batch and list(got) == sorted(set(got))
 
 
+@pytest.mark.parametrize("B", [255, 63])
+@pytest.mark.parametrize("leaf_batch,widths", [
+    (1, (1,)), (2, (2,)), (4, (4,)), (5, (5,)), (8, (4, 8)), (10, (4, 10)),
+    (15, (4, 8, 15))])
+def test_a_derived_round_is_staged_at_leaf_batch_slots(B, leaf_batch, widths):
+    """int8 statistics: the pass holds the left children alone, so the rule
+    is asked for ``leaf_batch`` slots; the same rule, no new one."""
+    assert growth._pass_widths(leaf_batch, B, True) == widths
+
+
 # -- one pass, every count of live positions ----------------------------------
 
 
@@ -88,6 +108,47 @@ def test_a_pass_at_the_live_width_is_the_full_width_pass(live, stats):
     assert np.asarray(got)[:, :3 * live].any()
 
 
+@pytest.mark.parametrize("live", range(1, 9))
+@pytest.mark.parametrize("blocks", [0, 2], ids=["plain", "blocks2"])
+def test_derived_children_are_the_two_children_pass_s(live, blocks):
+    """``live`` of 8 candidates split. The pass over their left children at
+    the narrowest width that holds them, the right ones taken from the
+    parents' sums: the ``[F, 48, B]`` int32 array of the pass over all 16
+    children, every bit; a candidate that does not split leaves zeros."""
+    rng = np.random.default_rng(live)
+    n, F, B, KB = 4096, 5, 63, 8
+    binned = jnp.asarray(rng.integers(0, B, (F, n)), jnp.uint8)
+    base = jnp.asarray(rng.integers(-127, 128, (3, n)), jnp.int8)
+    cand = rng.integers(-1, KB, n)                # -1: outside the frontier
+    goes_left = rng.uniform(size=n) < 0.4
+    do = jnp.arange(KB) < live
+    splits = (cand >= 0) & (cand < live)
+    both = np.where(splits, 2 * cand + ~goes_left, -1)
+    lefts = np.where(splits & goes_left, cand, -1)
+
+    def sums(pos, w):
+        pos = jnp.asarray(pos, jnp.int32)
+        if not blocks:
+            return node_histogram_sums(binned, pos, base, w, B,
+                                       quantized=True)
+        return growth._block_node_hists(binned, pos, base, w, B, None, blocks,
+                                        n // blocks, sums=True)
+
+    slots = jnp.asarray(rng.permutation(2 * KB + 3)[:KB])   # in some cache
+    hsum = jnp.zeros((blocks,) * bool(blocks) + (F, 2 * KB + 3, 3, B),
+                     jnp.int32).at[..., slots, :, :].set(
+        sums(cand, KB).reshape((blocks,) * bool(blocks) + (F, KB, 3, B)))
+    left = jax.jit(lambda k: growth._hist_at_width(
+        lambda w: sums(lefts, w), KB, k, B, True))(jnp.int32(live))
+    got = growth._derive_siblings(left, hsum, slots, do)
+    want = sums(both, 2 * KB)
+    got = got.reshape(want.shape)
+    assert got.dtype == want.dtype == jnp.int32 and got.shape == want.shape
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert np.asarray(got)[..., 3 * 2 * live - 3:3 * 2 * live, :].any()
+    assert not np.asarray(got)[..., 3 * 2 * live:, :].any()
+
+
 # -- trees --------------------------------------------------------------------
 
 N, F, B = 4096, 6, 63
@@ -108,9 +169,11 @@ def _rows(seed=0):
         N, p * (1 - p), np.float32)
 
 
-def _grow(widths, *, stats="int8", shards=1, cat=False, rows=None, **cfg):
+def _grow(widths, *, stats="int8", shards=1, cat=False, rows=None,
+          derive=True, **cfg):
     """One tree as numpy arrays; ``widths`` None is the rule as shipped, a
-    tuple stands where ``growth._pass_widths`` stands."""
+    tuple stands where ``growth._pass_widths`` stands; ``derive`` False
+    sums both children of every split whatever the statistics."""
     binned, grad, hess = rows or _rows()
     cfg = GrowConfig(**dict(dict(
         num_leaves=15, num_bins=B, min_data_in_leaf=5, leaf_batch=4,
@@ -126,15 +189,17 @@ def _grow(widths, *, stats="int8", shards=1, cat=False, rows=None, **cfg):
             devices=jax.devices()[:shards]),
             in_specs=(pspec(None, "data"),) + (pspec("data"),) * 3
             + (pspec(), pspec()), out_specs=pspec(), check_vma=False)
-    real = growth._pass_widths
+    real = growth._pass_widths, growth._sibling_is_derived
     if widths is not None:
         growth._pass_widths = lambda W, B_, q: widths
+    if not derive:
+        growth._sibling_is_derived = lambda quantized: False
     try:
         tree = jax.jit(fn)(jnp.asarray(binned), jnp.asarray(grad),
                            jnp.asarray(hess), jnp.ones(N), jnp.ones(F, bool),
                            jax.random.PRNGKey(0))
     finally:
-        growth._pass_widths = real
+        growth._pass_widths, growth._sibling_is_derived = real
     return jax.tree_util.tree_map(np.asarray, tree)
 
 
@@ -150,11 +215,33 @@ def _same_tree(a, b):
     (4, dict(voting=True, top_k=2)),
 ], ids=["1", "4", "1-blocks4", "4-blocks4", "4-voting"])
 def test_trees_are_the_single_width_s_to_the_bit(shards, cfg, stats, cat):
-    """The rule monkeypatched to the one width ``W2`` stages the program
-    this PR's parent staged."""
+    """The rule monkeypatched to the one width ``W2``, both children
+    summed, stages the program PR 34's parent staged."""
     ours = _grow(None, stats=stats, shards=shards, cat=cat, **cfg)
-    parents = _grow((8,), stats=stats, shards=shards, cat=cat, **cfg)
+    parents = _grow((8,), stats=stats, shards=shards, cat=cat, derive=False,
+                    **cfg)
     assert int(ours.node_count) >= 2 * 8 - 1        # rounds of 1, 2, 4 splits
+    _same_tree(ours, parents)
+
+
+@pytest.mark.parametrize("cat", [False, True], ids=["numeric", "categorical"])
+@pytest.mark.parametrize("leaf_batch", [1, 5, 8])
+@pytest.mark.parametrize("shards,cfg", [
+    (1, {}), (4, {}), (1, dict(hist_blocks=4)), (4, dict(hist_blocks=4)),
+    (4, dict(voting=True, top_k=2)),
+], ids=["1", "4", "1-blocks4", "4-blocks4", "4-voting"])
+def test_int8_trees_are_the_two_children_build_s_to_the_bit(shards, cfg,
+                                                            leaf_batch, cat):
+    """The same build with the derivation held off sums both children of
+    every split at PR 34's widths, which is this PR's parent. The sibling is
+    derived from this shard's (this block's) int32 sums before the scale and
+    before anything crosses shards, so the f32 array that is reduced, voted
+    on and searched is the one it was."""
+    ours = _grow(None, shards=shards, cat=cat, leaf_batch=leaf_batch,
+                 num_leaves=21, **cfg)
+    parents = _grow(None, shards=shards, cat=cat, leaf_batch=leaf_batch,
+                    num_leaves=21, derive=False, **cfg)
+    assert int(ours.node_count) >= 2 * 12 - 1
     _same_tree(ours, parents)
 
 
@@ -162,7 +249,8 @@ def test_trees_are_the_single_width_s_to_the_bit(shards, cfg, stats, cat):
 def test_a_tree_that_ends_in_a_round_of_one_live_split(stats):
     """Feature 0 splits the rows, feature 1 splits the left half again and
     nothing else is worth ``min_gain_to_split``: the second round has two
-    candidates and one split, the third none."""
+    candidates and one split, the third none. The parent's build: one width,
+    both children summed."""
     rng = np.random.default_rng(3)
     X = rng.integers(0, B, (F, N))
     left = X[0] <= 30
@@ -170,7 +258,8 @@ def test_a_tree_that_ends_in_a_round_of_one_live_split(stats):
     rows = (X.astype(np.uint8), (0.5 - y).astype(np.float32),
             np.full(N, 0.25, np.float32))
     ours = _grow(None, stats=stats, rows=rows, min_gain_to_split=5.0)
-    parents = _grow((8,), stats=stats, rows=rows, min_gain_to_split=5.0)
+    parents = _grow((8,), stats=stats, rows=rows, min_gain_to_split=5.0,
+                    derive=False)
     assert int(ours.node_count) == 5
     assert sorted(ours.feat[~ours.is_leaf]) == [0, 1]
     _same_tree(ours, parents)
@@ -213,13 +302,14 @@ def _switches(jaxpr, found):
     return found
 
 
-@pytest.mark.parametrize("stats,widths", [("int8", (4, 8, 16)),
+@pytest.mark.parametrize("stats,widths", [("int8", (4, 8)),
                                           ("float", (4, 8, 16))])
 def test_a_round_holds_one_switch_of_one_kernel_call_a_branch(
         stats, widths, monkeypatch):
     """The cells' rounds (255 bins, ``leaf_batch`` 8) under the Pallas engine:
-    the root's call, and one switch whose branches hold one call each. The
-    routing, the split search and the tree update are staged once."""
+    the root's call, and one switch whose branches hold one call each (int8:
+    at 4 and 8 slots, the left children's). The routing, the split search
+    and the tree update are staged once."""
     monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "pallas")
     monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
     jaxpr = jax.make_jaxpr(_grow_fn(quantized_grad=stats == "int8"))(
@@ -229,6 +319,78 @@ def test_a_round_holds_one_switch_of_one_kernel_call_a_branch(
     assert str(jaxpr).count("gbdt_route") == str(jax.make_jaxpr(_grow_fn(
         quantized_grad=stats == "int8", leaf_batch=1))(
             *_lower_args()).jaxpr).count("gbdt_route")
+
+
+def _kernel_results(jaxpr, found):
+    """The result shapes of every kernel call."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(tuple(eqn.outvars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_results(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("bins,leaf_batch", [(255, 8), (63, 8), (255, 5),
+                                             (63, 5)])
+def test_an_int8_round_stages_no_kernel_wider_than_leaf_batch(
+        bins, leaf_batch, monkeypatch):
+    """Under the Pallas engine the int8 build's kernels are the root's and
+    those of ``_pass_widths(leaf_batch)`` node slots; the build that sums
+    both children has a higher one (``2 * leaf_batch`` slots), which is the
+    launch a device trace no longer shows."""
+    monkeypatch.setenv("MMLSPARK_TPU_HIST_ENGINE", "pallas")
+    monkeypatch.setenv("MMLSPARK_TPU_PALLAS_INTERPRET", "1")
+    binned, _, _, pos = _lower_args()[:4]
+
+    def kernel_of(w):
+        return _kernel_results(jax.make_jaxpr(
+            lambda: node_histogram_sums(
+                binned, pos.astype(jnp.int32), jnp.zeros((3, pos.size),
+                                                         jnp.int8),
+                w, bins, quantized=True))().jaxpr, [])[0]
+
+    def staged():
+        return _kernel_results(jax.make_jaxpr(_grow_fn(
+            num_bins=bins, leaf_batch=leaf_batch))(*_lower_args()).jaxpr, [])
+
+    derived = staged()
+    assert derived == [kernel_of(1)] + [
+        kernel_of(w) for w in growth._pass_widths(leaf_batch, bins, True)]
+    monkeypatch.setattr(growth, "_sibling_is_derived", lambda q: False)
+    rows = lambda shapes: max(s[-2] for s in shapes[1:])
+    assert kernel_of(2 * leaf_batch) in staged()
+    assert kernel_of(2 * leaf_batch) not in derived
+    assert rows(derived) <= kernel_of(leaf_batch)[-2] <= rows(staged())
+
+
+_PARENT_JAX = "0.9.0"      # the jax the parent's texts were hashed under
+
+
+@pytest.mark.parametrize("cfg,sha", [
+    (dict(), "d454b04d99b93fb038b79762d3dbd882026c8fe2755efdaf9c47095176605077"),
+    (dict(leaf_batch=1),
+     "87ea756ea434ce2a5ebe31f99df3ce654fed04dac08664f41d944b727349211e"),
+    (dict(leaf_batch=5, num_bins=B),
+     "8f5c5803327f1a4f48952cc1ad142ded1c94b8a417632f8b94e63fb00f16c07a"),
+], ids=["cells", "leaf_batch1", "63bins-leaf_batch5"])
+def test_the_float_program_is_the_parent_s(cfg, sha, monkeypatch):
+    """Float statistics sum both children, as they did: the lowered text of
+    the float build is the text of PR 36's parent (873e312), byte for byte,
+    by its hash under the jax it was lowered with; under any jax it is the
+    text of the build with the derivation held off, and it never reaches
+    ``_derive_siblings``."""
+    def text():
+        return re.sub(r"module @\S+", "module @m", jax.jit(_grow_fn(
+            quantized_grad=False, **cfg)).lower(*_lower_args()).as_text(),
+            count=1)
+
+    monkeypatch.setattr(growth, "_derive_siblings", None)
+    shipped = text()
+    if jax.__version__ == _PARENT_JAX:
+        assert hashlib.sha256(shipped.encode()).hexdigest() == sha
+    monkeypatch.setattr(growth, "_sibling_is_derived", lambda q: False)
+    assert text() == shipped
 
 
 def test_leaf_batch_one_stages_no_switch(monkeypatch):
@@ -244,15 +406,22 @@ def test_leaf_batch_one_stages_no_switch(monkeypatch):
     assert text() == shipped
 
 
+def _counted(name, label):
+    reg = metrics.get_registry().snapshot().get(name) or {}
+    return {s["labels"][label]: s["value"] for s in reg.get("series", [])}
+
+
 def _staged():
-    reg = metrics.get_registry().snapshot().get(
-        "gbdt_hist_pass_width_total") or {}
-    return {s["labels"]["width"]: s["value"] for s in reg.get("series", [])}
+    return _counted("gbdt_hist_pass_width_total", "width")
+
+
+def _siblings():
+    return _counted("gbdt_hist_sibling_total", "sibling")
 
 
 @pytest.mark.parametrize("leaf_batch,stats,widths", [
-    (8, "int8", ("4", "8", "16")), (8, "float", ("4", "8", "16")),
-    (1, "int8", ("2",))])
+    (8, "int8", ("4", "8")), (8, "float", ("4", "8", "16")),
+    (1, "int8", ("1",)), (1, "float", ("2",))])
 def test_a_build_counts_each_staged_width_once(leaf_batch, stats, widths):
     before = _staged()
     jax.jit(_grow_fn(leaf_batch=leaf_batch,
@@ -260,3 +429,17 @@ def test_a_build_counts_each_staged_width_once(leaf_batch, stats, widths):
     after = _staged()
     assert {w: after[w] - before.get(w, 0) for w in after
             if after[w] != before.get(w, 0)} == {w: 1 for w in widths}
+
+
+@pytest.mark.parametrize("leaf_batch", [1, 8])
+@pytest.mark.parametrize("stats,sibling", [("int8", "derived"),
+                                           ("float", "summed")])
+def test_a_build_counts_how_a_round_gets_its_siblings(stats, sibling,
+                                                      leaf_batch):
+    """Once a staged round (a tree's rounds are one ``fori_loop`` body)."""
+    before = _siblings()
+    jax.jit(_grow_fn(leaf_batch=leaf_batch,
+                     quantized_grad=stats == "int8")).lower(*_lower_args())
+    after = _siblings()
+    assert {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)} == {sibling: 1}

@@ -50,7 +50,11 @@ def _auc_on_mesh(shards: int, s, y, w):
 @pytest.mark.parametrize("ties", ["constant", "two_values", "leaf_sums",
                                   "distinct"])
 def test_device_auc_is_the_float64_rank_statistic(ties, weights, shards):
-    rng = np.random.default_rng(hash((ties, weights)) % 2 ** 31)
+    # a literal a case: ``hash`` of a string is salted per process, and the
+    # draw decided whether a case met its bound
+    rng = np.random.default_rng(
+        [{"constant": 1, "two_values": 2, "leaf_sums": 3, "distinct": 4}[ties],
+         weights == "random"])
     n = 4096 + 8 * 37                   # not a multiple of the sum's block
     s = _margin(ties, n, rng)
     y = (rng.uniform(size=n) < 0.3 + 0.2 * np.tanh(s)).astype(np.float32)
@@ -61,7 +65,13 @@ def test_device_auc_is_the_float64_rank_statistic(ties, weights, shards):
     want = auc_float64(s, y, w)
     if ties == "constant":
         assert want == 0.5
-    assert abs(got - want) < (1e-7 if weights == "unit" else 1e-6)
+    # unit weights: the sums are whole numbers, exact in f32, and what is left
+    # is the result's own rounding: the metric is an f32 in [0.5, 1) (or its
+    # mirror image under 0.5), where an ulp is 6e-8. Two ulps, 1.2e-7; the
+    # benchmark's held-out day reads up to 1.57e-7 on sound runs (PERF.md,
+    # PR 35) and its control, the margin carried in bfloat16, 7e-4 and up:
+    # four orders over either bound.
+    assert abs(got - want) < (1.2e-7 if weights == "unit" else 1e-6)
 
 
 @pytest.mark.parametrize("shards", [1, 4])
