@@ -45,8 +45,9 @@ from ...parallel.compat import shard_map
 from ...parallel.placement import pspec as P
 from . import quantize as _quantize
 from .growth import (GrowConfig, Tree, bitset_words, grow_tree,
-                     grow_tree_depthwise, predict_forest_raw,
-                     predict_tree_binned)
+                     grow_tree_depthwise, hist_blocks_per_shard,
+                     predict_forest_raw, predict_tree_binned,
+                     run_tally_layout)
 from .objectives import (GLOBAL_EVAL_METRICS, HIGHER_IS_BETTER, Objective,
                          eval_metric, get_objective, score_transform)
 
@@ -142,8 +143,11 @@ _TREE_FIELD_DTYPES = dict(
     split_gain=np.float32, node_value=np.float32, cat_bitset=np.uint32)
 
 
-def pack_trees(trees: Tree) -> jnp.ndarray:
-    """Flatten a (possibly stacked) Tree into one int32 device buffer.
+def pack_trees(trees: Tree, tally=None) -> jnp.ndarray:
+    """Flatten a (possibly stacked) Tree into one int32 device buffer, a
+    fit's run tallies (``_grow_with_warmup``) its tail: what the trees' growth
+    ran reaches the host in the transfer that brings the trees
+    (:func:`_unpack_fit`).
 
     The buffer is int32, not f32: small integers bitcast to f32 are
     subnormals, and the TPU flushes subnormals to zero somewhere in the
@@ -157,6 +161,8 @@ def pack_trees(trees: Tree) -> jnp.ndarray:
         if arr.dtype != jnp.int32:
             arr = lax.bitcast_convert_type(arr, jnp.int32)
         parts.append(arr.reshape(-1))
+    if tally is not None:
+        parts.append(tally.reshape(-1))
     return jnp.concatenate(parts)
 
 
@@ -189,6 +195,66 @@ def unpack_trees(flat: np.ndarray, lead: Tuple[int, ...], M: int,
         f"unpack_trees: buffer has {flat.size} elements, layout expects "
         f"{off} — num_leaves/num_bins mismatch between pack and unpack")
     return Tree(**fields)
+
+
+def _grow_variants(cfg: GrowConfig) -> Tuple[GrowConfig, ...]:
+    """The grow configurations a fit of ``cfg`` stages
+    (:func:`_grow_with_warmup`): the full-precision one of the quantized
+    warm-up first, where there is one."""
+    if cfg.quantized_grad and cfg.quant_warmup_iters > 0:
+        return cfg._replace(quantized_grad=False), cfg
+    return (cfg,)
+
+
+def _tally_words(cfg: GrowConfig) -> Tuple[int, ...]:
+    """int32 words of each variant's run tally (``growth.run_tally_layout``);
+    a tree's tally is the variants' end to end."""
+    return tuple(1 + len(run_tally_layout(c)) for c in _grow_variants(cfg))
+
+
+def _unpack_fit(flat: np.ndarray, lead: Tuple[int, ...], cfg: GrowConfig):
+    """A fit's download (:func:`pack_trees` with its tail) as ``(trees,
+    tallies)``: trees with leading dims ``lead`` and their run tallies
+    ``[*lead, words]``."""
+    tail = flat.size - int(np.prod(lead)) * sum(_tally_words(cfg))
+    return (unpack_trees(flat[:tail], lead, 2 * cfg.num_leaves - 1,
+                         bitset_words(cfg.num_bins)),
+            flat[tail:].reshape(lead + (-1,)))
+
+
+def _tell_run_tally(fit, cfg: GrowConfig, shards: int, K: int,
+                    tallies) -> None:
+    """What the fit ran, counted on the device where it ran
+    (``growth.run_tally_layout``), told on its ``gbdt_fit`` span: ``passes``
+    (histogram passes, the roots' included), ``launches`` (kernel launches a
+    shard: a pass is one launch a block under ``hist_blocks``), ``slots``
+    (node slots the passes ran at), ``live`` (positions of them that held
+    rows) and ``iterations`` (boosting iterations run: an early stop says
+    where). ``gbdt_hist_passes_run_total{width}`` moves by the runs, the
+    roots' under ``width="root"``. ``tallies``: the downloads' ``[...,
+    words]`` arrays (none where no iteration ran). With telemetry off the
+    tail is dropped."""
+    if not (_metrics.enabled() and tallies):
+        return
+    words = _tally_words(cfg)
+    rows = np.concatenate([np.reshape(t, (-1, sum(words))) for t in tallies])
+    total, at = rows.sum(axis=0), 0
+    passes = slots = live = 0
+    for c, n in zip(_grow_variants(cfg), words):
+        widths = run_tally_layout(c)
+        seg, at = total[at:at + n], at + n
+        runs = seg[1:]
+        live += int(seg[0])
+        passes += int(runs.sum())
+        slots += int(runs @ np.asarray(widths))
+        for i, (width, run) in enumerate(zip(widths, runs)):
+            if run:
+                _metrics.safe_counter(
+                    "gbdt_hist_passes_run_total",
+                    width="root" if i == 0 else str(width)).inc(int(run))
+    fit.set(passes=passes,
+            launches=passes * max(1, hist_blocks_per_shard(cfg, shards)),
+            slots=slots, live=live, iterations=len(rows) // K)
 
 
 # --- device-resident inference hot path -------------------------------------
@@ -1566,20 +1632,27 @@ def _grow_with_warmup(grow, it_scalar, cfg, qk, binned_t, grad_k, hess_k,
     variants live in ONE ``lax.cond`` so the fused scans and the
     early-stopping while_loop keep their traced iteration index; the
     predicate derives from the replicated scan counter, so the branch cannot
-    diverge across shards."""
-    if not cfg.quantized_grad:
-        return grow(binned_t, grad_k, hess_k, row_mask, fmask, cfg,
-                    axis_name=axis_name, is_cat=is_cat, qkey=None)
-    if cfg.quant_warmup_iters <= 0:
-        return grow(binned_t, grad_k, hess_k, row_mask, fmask, cfg,
-                    axis_name=axis_name, is_cat=is_cat, qkey=qk)
-    fp_cfg = cfg._replace(quantized_grad=False)
-    return lax.cond(
-        it_scalar < cfg.quant_warmup_iters,
-        lambda: grow(binned_t, grad_k, hess_k, row_mask, fmask, fp_cfg,
-                     axis_name=axis_name, is_cat=is_cat, qkey=None),
-        lambda: grow(binned_t, grad_k, hess_k, row_mask, fmask, cfg,
-                     axis_name=axis_name, is_cat=is_cat, qkey=qk))
+    diverge across shards. Returns ``(tree, row_node, tally)``: the tree's
+    run tally, the variants' tallies (:func:`_grow_variants`,
+    :func:`_tally_words`) end to end and zero where a variant did not run."""
+    variants, words = _grow_variants(cfg), _tally_words(cfg)
+
+    def grown(i):
+        def run():
+            got = []
+            tree, row_node = grow(
+                binned_t, grad_k, hess_k, row_mask, fmask, variants[i],
+                axis_name=axis_name, is_cat=is_cat,
+                qkey=qk if variants[i].quantized_grad else None,
+                run_tally=got)
+            return tree, row_node, jnp.concatenate([
+                got[0] if j == i else jnp.zeros(w, jnp.int32)
+                for j, w in enumerate(words)])
+        return run
+
+    if len(variants) == 1:
+        return grown(0)()
+    return lax.cond(it_scalar < cfg.quant_warmup_iters, grown(0), grown(1))
 
 
 def _note_valid_evals(metric: str, where: str, evals: int, rows: int) -> None:
@@ -2109,7 +2182,7 @@ def train_booster(
             else:
                 row_mask = vmask
 
-        trees_out = []
+        trees_out, tallies = [], []
         fmask = jnp.ones(F, dtype=bool)
         if feature_fraction < 1.0:
             # derived from the replicated iteration key: identical on all shards
@@ -2120,10 +2193,11 @@ def train_booster(
         grow = (grow_tree_depthwise if cfg.growth_policy == "depthwise"
                 else grow_tree)
         for k in range(K):
-            tree, row_node = _grow_with_warmup(
+            tree, row_node, tally = _grow_with_warmup(
                 grow, it_f, cfg, jax.random.fold_in(key, 13 + k),
                 binned_t, grad[:, k], hess[:, k], row_mask, fmask,
                 axis_name=grow_axis, is_cat=is_cat_j)
+            tallies.append(tally)
             if not is_rf:
                 # rf: trees are independent (gradients stay at the base
                 # score); gbdt/goss: boost on the updated margin
@@ -2158,7 +2232,8 @@ def train_booster(
             _, num = eval_metric(obj, sc, vy, vw, metric=eval_override,
                                  axis_name=metric_axis, **objective_kwargs)
             metrics["valid"] = _combine_metric(num, jnp.sum(vw), metric_name)
-        return scores, vscores if has_valid else jnp.zeros((1, 1)), trees_stacked, metrics
+        return (scores, vscores if has_valid else jnp.zeros((1, 1)),
+                trees_stacked, metrics, jnp.stack(tallies))
 
     row_spec = P("data")
     row2_spec = P("data", None)
@@ -2190,9 +2265,9 @@ def train_booster(
                  # datasets would reuse the wrong base
                  tuple(np.asarray(base).tolist()) if is_rf else None)
     def step_packed(*args):
-        scores, vscores, trees_stacked, metrics = step_local(*args)
+        scores, vscores, trees_stacked, metrics, tally = step_local(*args)
         # one flat download buffer instead of 13 per-field transfers
-        return scores, vscores, pack_trees(trees_stacked), metrics
+        return scores, vscores, pack_trees(trees_stacked, tally), metrics
 
     # donate the per-round score buffers: the host loop immediately rebinds
     # scores_d/vscores_d to the step outputs, so XLA can update them in
@@ -2216,6 +2291,7 @@ def train_booster(
         check_vma=False), donate_argnums=donate))
 
     all_trees: List[Tree] = []
+    tallies: List[np.ndarray] = []    # the run tally of every download
     history: Dict[str, List[float]] = {metric_name: []}
     higher_is_better = metric_name in HIGHER_IS_BETTER
     es_tol = float(early_stopping_tolerance)
@@ -2261,16 +2337,16 @@ def train_booster(
                 def it_body(scores_c, it):
                     key, bag_key = _iter_keys(base_key, it)
                     d = jnp.zeros((), jnp.float32)
-                    scores_c, _, trees_stacked, _ = step_local(
+                    scores_c, _, trees_stacked, _, tally = step_local(
                         binned_l, yl, wl, vmask_l, scores_c, d, d, d, d,
                         key, bag_key, it.astype(jnp.float32))
-                    return scores_c, trees_stacked
+                    return scores_c, (trees_stacked, tally)
 
-                _, trees_seq = lax.scan(
+                _, (trees_seq, tallies) = lax.scan(
                     it_body, scores_l,
                     jnp.arange(num_iterations, dtype=jnp.int32))
                 # one flat download buffer instead of 13 per-field transfers
-                return pack_trees(trees_seq)
+                return pack_trees(trees_seq, tallies)
 
             return jax.jit(shard_map(
                 multi_local, mesh=mesh,
@@ -2285,11 +2361,10 @@ def train_booster(
         phases.enter("gbdt_fit_wait")
         jax.block_until_ready(trees_dev)
         phases.enter("gbdt_fit_download")
-        trees_seq = unpack_trees(np.asarray(trees_dev),
-                                 (num_iterations, K),
-                                 2 * cfg.num_leaves - 1,
-                                 bitset_words(cfg.num_bins))
+        trees_seq, tallies = _unpack_fit(np.asarray(trees_dev),
+                                         (num_iterations, K), cfg)
         phases.enter("gbdt_fit_finalize")
+        _tell_run_tally(fit, cfg, nshards, K, [tallies])
         all_seq: List[Tree] = []
         for it in range(num_iterations):
             for k in range(K):
@@ -2334,11 +2409,13 @@ def train_booster(
                 def one_iter(it, state):
                     scores_c, vscores_c = state
                     key, bag_key = _iter_keys(base_key, it)
-                    scores_c, vscores_c, trees_stacked, metrics = step_local(
+                    (scores_c, vscores_c, trees_stacked, metrics,
+                     tally) = step_local(
                         binned_l, yl, wl, vmask_l, scores_c, vbinned_l,
                         vy_l, vw_l, vscores_c, key, bag_key,
                         it.astype(jnp.float32))
-                    return ((scores_c, vscores_c), pack_trees(trees_stacked),
+                    return ((scores_c, vscores_c),
+                            pack_trees(trees_stacked, tally),
                             metrics["valid"].astype(jnp.float32))
 
                 return _fused_es_scan(one_iter, (scores_l, vscores_l),
@@ -2371,9 +2448,8 @@ def train_booster(
         for it in range(n_done):
             # each buffer row is one iteration's pack of K stacked trees —
             # the same layout the host loop downloads per iteration
-            trees_host = unpack_trees(rows[it], (K,),
-                                      2 * cfg.num_leaves - 1,
-                                      bitset_words(cfg.num_bins))
+            trees_host, tally = _unpack_fit(rows[it], (K,), cfg)
+            tallies.append(tally)
             for k in range(K):
                 all_trees.append(jax.tree_util.tree_map(
                     lambda a: a[k], trees_host))
@@ -2452,9 +2528,8 @@ def train_booster(
                 key, bag_key, np.float32(it))
             if has_valid:
                 vscores_d = vscores_d_new
-            trees_host = unpack_trees(np.asarray(trees_packed), (K,),  # graftlint: disable=hot-path-host-sync (deliberate: one tree download per round grows the host forest)
-                                      2 * cfg.num_leaves - 1,
-                                      bitset_words(cfg.num_bins))
+            trees_host, tally = _unpack_fit(np.asarray(trees_packed), (K,), cfg)  # graftlint: disable=hot-path-host-sync (deliberate: one tree download per round grows the host forest)
+            tallies.append(tally)
             for k in range(K):
                 all_trees.append(jax.tree_util.tree_map(lambda a: a[k], trees_host))
 
@@ -2520,6 +2595,7 @@ def train_booster(
         if unregister_dump is not None:
             unregister_dump()
     phases.enter("gbdt_fit_finalize")
+    _tell_run_tally(fit, cfg, nshards, K, tallies)
     booster = _finalize(all_trees)
     # early-stop truncation applies to fresh runs and checkpoint resumes
     # alike (the checkpoint's trees carry global iteration indices); only a
@@ -2571,6 +2647,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
     """
     F, npad = Xbt_d.shape
     T_max = num_iterations
+    shards = meshlib.num_shards(mesh)
     grow = (grow_tree_depthwise if cfg.growth_policy == "depthwise"
             else grow_tree)
     grow_axis = _grow_axis_for(mesh, cfg)
@@ -2596,14 +2673,15 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
             fkey = jax.random.fold_in(key, 7)
             u = jax.random.uniform(fkey, (F,))
             fmask = (u < feature_fraction).at[jnp.argmin(u)].set(True)
-        trees_out, new_contrib = [], []
+        trees_out, new_contrib, tallies = [], [], []
         for k in range(K):
-            tree, row_node = _grow_with_warmup(
+            tree, row_node, tally = _grow_with_warmup(
                 grow, it_i, cfg, jax.random.fold_in(key, 13 + k),
                 binned_t, grad[:, k], hess[:, k], row_mask, fmask,
                 axis_name=grow_axis, is_cat=is_cat_j)
             new_contrib.append(tree.leaf_value[row_node])
             trees_out.append(tree)
+            tallies.append(tally)
         nc = jnp.stack(new_contrib, axis=1)                # [n_local, K]
         contribs = lax.dynamic_update_slice(contribs, nc[None], (it_i, 0, 0))
         trees_stacked = jax.tree_util.tree_map(
@@ -2617,7 +2695,8 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
             vcontribs = lax.dynamic_update_slice(
                 vcontribs, vc[None], (it_i, 0, 0))
         # one flat download buffer instead of 13 per-field transfers
-        return contribs, vcontribs, pack_trees(trees_stacked)
+        return contribs, vcontribs, pack_trees(trees_stacked,
+                                               jnp.stack(tallies))
 
     def dart_eval_local(vcontribs, scales, vy, vw):
         sc2 = base_j[None, :] + jnp.einsum("t,tnk->nk", scales, vcontribs)
@@ -2670,6 +2749,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
     scales = np.zeros(T_max, np.float32)
     rng_drop = np.random.default_rng(drop_seed)
     all_trees: List[Tree] = []
+    tallies: List[np.ndarray] = []    # the run tally of every download
     history: Dict[str, List[float]] = {metric_name: []}
     higher_is_better = metric_name in HIGHER_IS_BETTER
     es_tol = float(early_stopping_tolerance)
@@ -2766,9 +2846,8 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
                 float(x) for x in np.asarray(mbuf_dev[:n_done]))
         rows = np.asarray(buf_dev[:n_done])
         for it in range(n_done):
-            trees_host = unpack_trees(rows[it], (K,),
-                                      2 * cfg.num_leaves - 1,
-                                      bitset_words(cfg.num_bins))
+            trees_host, tally = _unpack_fit(rows[it], (K,), cfg)
+            tallies.append(tally)
             for k in range(K):
                 all_trees.append(jax.tree_util.tree_map(
                     lambda a: a[k], trees_host))
@@ -2776,6 +2855,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
         # executed iteration — identical to the host loop's final `scales`
         scales = post_rows[n_done - 1].copy()
         phases.enter("gbdt_fit_finalize")
+        _tell_run_tally(phases.parent, cfg, shards, K, tallies)
         booster = _finalize_trees(all_trees, binner, max_bin, K, base,
                                   objective, depth_cap, objective_kwargs,
                                   best_iter, history, None)
@@ -2799,9 +2879,8 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
                 key, bag_key, np.int32(it))
             if has_valid:
                 vcontribs_d = vcontribs_new
-            trees_host = unpack_trees(np.asarray(trees_packed), (K,),  # graftlint: disable=hot-path-host-sync (deliberate: one tree download per round grows the host forest)
-                                      2 * cfg.num_leaves - 1,
-                                      bitset_words(cfg.num_bins))
+            trees_host, tally = _unpack_fit(np.asarray(trees_packed), (K,), cfg)  # graftlint: disable=hot-path-host-sync (deliberate: one tree download per round grows the host forest)
+            tallies.append(tally)
             for k in range(K):
                 all_trees.append(jax.tree_util.tree_map(lambda a: a[k],
                                                         trees_host))
@@ -2834,6 +2913,7 @@ def _train_dart(*, mesh, cfg, K, obj, objective, objective_kwargs,
     finally:
         hb.close()
     phases.enter("gbdt_fit_finalize")
+    _tell_run_tally(phases.parent, cfg, shards, K, tallies)
     booster = _finalize_trees(all_trees, binner, max_bin, K, base, objective,
                               depth_cap, objective_kwargs, best_iter, history,
                               None)

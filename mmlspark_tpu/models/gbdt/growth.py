@@ -203,28 +203,38 @@ def _allreduce_round_hist(h, axis_name):
     return x.transpose(1, 0, 2, 3).reshape(F, S, B)
 
 
+def hist_blocks_per_shard(cfg: GrowConfig, shards: int) -> int:
+    """Blocks of the blocked reduction on each of ``shards`` data shards: the
+    kernel launches one histogram pass is there. 0 on the plain psum path,
+    where a pass is one launch. Raises when a pinned block count cannot be
+    dealt to the shards."""
+    hb = cfg.hist_blocks
+    if hb == "auto" or not hb or (isinstance(hb, int) and hb <= 1):
+        return 0
+    if cfg.voting:
+        raise ValueError(
+            "hist_blocks does not compose with voting_parallel (the "
+            "shard-local ballot is inherently topology-dependent)")
+    if hb % shards:
+        raise ValueError(
+            f"hist_blocks={hb} is not a multiple of the {shards}-shard "
+            "data axis")
+    return hb // shards
+
+
 def _hist_block_geometry(cfg: GrowConfig, axis_name, n: int):
     """(blocks_local, rows_per_block) for the blocked reduction; (0, n) on
     the plain psum path. Raises when a pinned block count cannot tile this
     shard (train_booster resolves these cases up front via
     placement.resolve_hist_blocks; direct growth callers fail loudly)."""
-    hb = cfg.hist_blocks
-    if hb == "auto" or not hb or (isinstance(hb, int) and hb <= 1):
-        return 0, n
-    if cfg.voting:
-        raise ValueError(
-            "hist_blocks does not compose with voting_parallel (the "
-            "shard-local ballot is inherently topology-dependent)")
     axis_sz = _axis_size(axis_name) if axis_name is not None else 1
-    if hb % axis_sz:
-        raise ValueError(
-            f"hist_blocks={hb} is not a multiple of the {axis_sz}-shard "
-            "data axis")
-    bl = hb // axis_sz
+    bl = hist_blocks_per_shard(cfg, axis_sz)
+    if not bl:
+        return 0, n
     if n % bl:
         raise ValueError(
             f"shard row count {n} does not tile into {bl} blocks "
-            f"(hist_blocks={hb} over {axis_sz} shards)")
+            f"(hist_blocks={cfg.hist_blocks} over {axis_sz} shards)")
     return bl, n // bl
 
 
@@ -258,30 +268,19 @@ def _pass_widths(W: int, B: int, quantized: bool) -> tuple:
     return tuple(widths)
 
 
-def _note_pass_width(width: int) -> None:
-    """gbdt_hist_pass_width_total{width}: a width a leafwise round's pass is
-    staged at, counted where that variant is staged out (as
-    :func:`_note_route_lookup` counts), so it tracks program builds. On the
-    device the variants tell themselves apart by their result shapes."""
-    try:
-        from ...observability import metrics as _metrics
-        _metrics.safe_counter("gbdt_hist_pass_width_total",
-                              width=str(width)).inc()
-    except Exception:  # noqa: BLE001 — telemetry must not fail the fit
-        pass
-
-
 def _hist_at_width(hist_of, W: int, live, B: int, quantized: bool):
-    """``hist_of(W)`` — this shard's ``[..., 3 * W, B]`` node histograms —
-    built at the narrowest staged width that holds the ``live`` node
-    positions (a traced count; positions at or past it hold no row) and
+    """``(hist_of(W), which)`` — this shard's ``[..., 3 * W, B]`` node
+    histograms, built at the narrowest staged width that holds the ``live``
+    node positions (a traced count; positions at or past it hold no row) and
     zero-padded back to ``W``, so everything downstream sees the array it
-    would of a full-width pass: the slots past ``live`` are zero either way.
-    One ``lax.switch`` whose branches differ in the kernel call alone; one
-    width stages no switch."""
+    would of a full-width pass: the slots past ``live`` are zero either way;
+    and the index into ``_pass_widths(W, B, quantized)`` of the width that
+    ran. One ``lax.switch`` whose branches differ in the kernel call alone,
+    on ``which``, so a count kept by it (the run tally,
+    :func:`run_tally_layout`) cannot part from what ran; one width stages
+    no switch."""
     def staged(w):
         def branch():
-            _note_pass_width(w)
             h = hist_of(w)
             if w == W:
                 return h
@@ -292,10 +291,10 @@ def _hist_at_width(hist_of, W: int, live, B: int, quantized: bool):
 
     widths = _pass_widths(W, B, quantized)
     if len(widths) == 1:
-        return staged(W)()
+        return staged(W)(), 0
     narrower = jnp.asarray(widths[:-1], dtype=jnp.int32)
-    return lax.switch(jnp.sum((live > narrower).astype(jnp.int32)),
-                      [staged(w) for w in widths])
+    which = jnp.sum((live > narrower).astype(jnp.int32))
+    return lax.switch(which, [staged(w) for w in widths]), which
 
 
 def _sibling_is_derived(quantized: bool) -> bool:
@@ -311,16 +310,42 @@ def _sibling_is_derived(quantized: bool) -> bool:
     return quantized
 
 
-def _note_sibling(sibling: str) -> None:
-    """gbdt_hist_sibling_total{sibling=derived|summed}: how a leafwise round
-    gets the second child of a split, counted where the round is staged out
-    (as :func:`_note_pass_width` counts), so it tracks program builds."""
-    try:
-        from ...observability import metrics as _metrics
-        _metrics.safe_counter("gbdt_hist_sibling_total",
-                              sibling=sibling).inc()
-    except Exception:  # noqa: BLE001 — telemetry must not fail the fit
-        pass
+def _level_widths(cfg: GrowConfig) -> tuple:
+    """The node width of every level's pass of a depthwise tree, the root's
+    first. Without an explicit max_depth, two levels of slack beyond the
+    balanced depth let moderately skewed trees still spend the leaf budget
+    (extreme skew is leafwise's domain — a perfectly unbalanced chain would
+    need num_leaves-1 levels and defeat the batching)."""
+    L = int(cfg.num_leaves)
+    depth_cap = (cfg.max_depth if cfg.max_depth > 0
+                 else min(L - 1, (L - 1).bit_length() + 2))
+    return tuple(min(2 ** depth, L) for depth in range(depth_cap))
+
+
+def run_tally_layout(cfg: GrowConfig) -> tuple:
+    """The node widths a tree's run tally counts passes at, from the static
+    configuration alone, for the host to label what the device counted.
+
+    A grower carries the tally through its rounds, an int32 vector of ``1 +
+    len(widths)`` scalars: ``tally[0]`` the sum over the tree's histogram
+    passes of the node positions that held rows, ``tally[1 + i]`` how many
+    passes ran at ``widths[i]`` node slots. ``widths[0]`` is the root's pass
+    (one slot, one live position, run once, by construction); the others are
+    a leafwise round's staged widths (:func:`_pass_widths`, the entry that
+    :func:`_hist_at_width` ran) or a depthwise tree's levels under the root,
+    one entry a level (:func:`_level_widths`). A round or level that is
+    skipped adds nothing; under ``hist_blocks`` a pass is still one pass
+    (:func:`hist_blocks_per_shard` launches). A round's live positions are
+    its splits where the sibling is derived (:func:`_sibling_is_derived`),
+    both children of every split where it is summed; a level's are its
+    frontier's nodes. Every shard carries the same tally: what it counts by
+    is reduced over the shards already."""
+    if cfg.growth_policy == "depthwise":
+        return _level_widths(cfg)
+    KB = max(1, min(int(cfg.leaf_batch), int(cfg.num_leaves) - 1))
+    derive = _sibling_is_derived(cfg.quantized_grad)
+    return (1,) + _pass_widths(KB if derive else 2 * KB, int(cfg.num_bins),
+                               cfg.quantized_grad)
 
 
 @jax.named_scope("gbdt_hist")
@@ -772,14 +797,17 @@ class Tree(NamedTuple):
 def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
               valid: jnp.ndarray, feat_mask: jnp.ndarray, cfg: GrowConfig,
               axis_name: Optional[str] = None,
-              is_cat: Optional[jnp.ndarray] = None, qkey=None):
+              is_cat: Optional[jnp.ndarray] = None, qkey=None,
+              run_tally: Optional[list] = None):
     """Grow one tree on (possibly sharded) rows.
 
     binned_t: [F, n] int32/int16/uint8 (column-major); grad/hess: [n] f32; valid: [n] f32
     row mask (0 for padding / bagged-out rows); feat_mask: [F] bool
     (feature_fraction). With ``axis_name`` set (inside shard_map), histograms
     are psum'd so every shard takes identical split decisions —
-    data_parallel GBDT semantics.
+    data_parallel GBDT semantics. ``run_tally``: a list that the tree's run
+    tally (:func:`run_tally_layout`) is appended to; a caller that takes
+    none stages the program without it.
     """
     F, n = binned_t.shape
     L = int(cfg.num_leaves)
@@ -802,9 +830,10 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         """This shard's node histograms ``[F, W*3, B]`` (``[bl, F, W*3, B]``,
         one a block, under hist_blocks) of one pass over all rows: f32, or
         where a round derives its siblings the int32 sums, which
-        ``global_hist`` scales. ``live``: a round's count of node positions
-        that hold rows, for the pass to run no wider than it must
-        (:func:`_hist_at_width`; the root's pass has the one width)."""
+        ``global_hist`` scales; and which staged width ran. ``live``: a
+        round's count of node positions that hold rows, for the pass to run
+        no wider than it must (:func:`_hist_at_width`; the root's pass has
+        the one width)."""
         def local(w):
             if bl:
                 return _block_node_hists(binned_t, row_pos, base_t, w, B,
@@ -815,7 +844,7 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             return node_histogram(binned_t, row_pos, base_t, w, B,
                                   scales=qscales)
 
-        return (local(W) if live is None else
+        return ((local(W), 0) if live is None else
                 _hist_at_width(local, W, live, B, qscales is not None))
 
     @jax.named_scope("gbdt_hist")
@@ -842,7 +871,7 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             return h, jnp.ones(F, dtype=bool)
         return _voting_select(h, feat_mask, cfg, axis_name, W, per)
 
-    root_local = local_hist(jnp.zeros(n, dtype=jnp.int32), 1)
+    root_local, _ = local_hist(jnp.zeros(n, dtype=jnp.int32), 1)
     root_hist, sel0 = global_hist(root_local, 1, "tree")
     # totals from the raw stats (not the histogram: under voting_parallel an
     # unselected feature's rows are zeroed there). Quantized mode totals the
@@ -876,6 +905,10 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         tbits=zbits,
         gain=zf,
         num_nodes=jnp.int32(1),
+        # what the tree ran (run_tally_layout): the root's pass so far, one
+        # live position and one run
+        tally=jnp.zeros(1 + len(run_tally_layout(cfg)),
+                        jnp.int32).at[:2].set(1),
     )
     if float_sums:
         _note_float_sums("child_totals")
@@ -925,7 +958,6 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # [0, 2*KB), 2i = left child of candidate i. The sibling derived:
         # in [0, KB), i = left child of candidate i, and a row that goes
         # right rides the pass at no position, like one outside the frontier
-        _note_sibling("derived" if derive else "summed")
         with jax.named_scope("gbdt_route"):
             if derive:
                 cpos = jnp.where(goleft_k, arange_kb[:, None], -1)
@@ -939,12 +971,13 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
         # ``do`` is a prefix of the gain-sorted candidates, so every live
         # position is under n_split (2 * n_split with both children summed)
+        live = n_split if derive else 2 * n_split
         if derive:
-            kids = _derive_siblings(local_hist(child_pos, KB, live=n_split),
-                                    st["hsum"], slots, do)
+            left, which = local_hist(child_pos, KB, live=live)
+            kids = _derive_siblings(left, st["hsum"], slots, do)
             h = kids.reshape(kids.shape[:-3] + (3 * W2, B))
         else:
-            h = local_hist(child_pos, W2, live=2 * n_split)
+            h, which = local_hist(child_pos, W2, live=live)
         h, sel = global_hist(h, W2, "round")               # [F, W2*3, B]
         hw = h.reshape(F, W2, 3, B).transpose(1, 0, 2, 3)  # [W2,F,3,B]
 
@@ -972,6 +1005,7 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
         new = dict(st)
         new["row_node"] = new_row_node
+        new["tally"] = st["tally"].at[0].add(live).at[2 + which].add(1)
 
         with jax.named_scope("gbdt_tree_update"):
             # record splits; index M is out of bounds -> dropped for non-splits
@@ -1037,6 +1071,8 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         node_count=state["num_nodes"], node_grad=state["ng"],
         node_hess=state["nh"], node_cnt=state["nc"], split_gain=state["gain"],
         node_value=node_value, cat_bitset=state["tbits"])
+    if run_tally is not None:
+        run_tally.append(state["tally"])
     # row_node is each row's final leaf: leaf_value[row_node] is this tree's
     # prediction for the training rows — no traversal needed during boosting.
     return tree, state["row_node"]
@@ -1075,7 +1111,8 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
                         hess: jnp.ndarray, valid: jnp.ndarray,
                         feat_mask: jnp.ndarray, cfg: GrowConfig,
                         axis_name: Optional[str] = None,
-                        is_cat: Optional[jnp.ndarray] = None, qkey=None):
+                        is_cat: Optional[jnp.ndarray] = None, qkey=None,
+                        run_tally: Optional[list] = None):
     """Level-synchronous growth: one histogram pass per level.
 
     Every node on the level frontier contributes 3 stat channels
@@ -1085,19 +1122,15 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
     31-leaf tree ~6 passes instead of the 30 sequential passes of
     best-first growth. The ``num_leaves`` budget is respected by ranking the
     level's candidate splits by gain. Same Tree layout / slot allocation
-    discipline as ``grow_tree`` (slot ids in allocation order).
+    discipline as ``grow_tree`` (slot ids in allocation order), and the same
+    ``run_tally``.
     """
     F, n = binned_t.shape
     L = int(cfg.num_leaves)
     M = 2 * L - 1
     B = int(cfg.num_bins)
     BW = bitset_words(B)
-    # Without an explicit max_depth, allow two levels of slack beyond the
-    # balanced depth so moderately skewed trees can still spend the leaf
-    # budget (extreme skew is leafwise's domain — a perfectly unbalanced
-    # chain would need num_leaves-1 levels and defeat the batching).
-    depth_cap = (cfg.max_depth if cfg.max_depth > 0
-                 else min(L - 1, (L - 1).bit_length() + 2))
+    level_widths = _level_widths(cfg)
 
     vm = valid.astype(jnp.float32)
     base_t = jnp.stack([grad * vm, hess * vm, vm], axis=0)   # [3, n]
@@ -1131,9 +1164,13 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
 
     def make_level(depth: int, W: int):
         def level_work(state):
-            row_node, frontier, num_nodes, leaves, tree_arrays = state
+            row_node, frontier, num_nodes, leaves, tree_arrays, tally = state
             fr = frontier[:W]
             active = fr >= 0
+            # what the tree ran (run_tally_layout): this level's pass and the
+            # frontier positions that hold nodes
+            tally = tally.at[0].add(jnp.sum(active.astype(jnp.int32))).at[
+                1 + depth].add(1)
 
             # per-row frontier position (rows at finished leaves get -1);
             # index M is out of bounds -> dropped for inactive slots
@@ -1238,18 +1275,20 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
                 compacted[:W_next])
 
             return (row_node, frontier, num_nodes + 2 * n_split,
-                    leaves + n_split, ta)
+                    leaves + n_split, ta, tally)
 
         return level_work
 
-    state = (row_node, frontier, num_nodes, leaves, tree_arrays)
-    for depth in range(depth_cap):           # static unroll: W varies by level
-        W = min(2 ** depth, L)
+    state = (row_node, frontier, num_nodes, leaves, tree_arrays,
+             jnp.zeros(1 + len(level_widths), jnp.int32))
+    for depth, W in enumerate(level_widths):  # static unroll: W varies by level
         # runtime skip: once the budget is spent or the frontier is empty,
         # the remaining (slack) levels cost nothing
         pred = (state[3] < jnp.int32(L)) & jnp.any(state[1] >= 0)
         state = lax.cond(pred, make_level(depth, W), lambda s: s, state)
-    row_node, frontier, num_nodes, leaves, tree_arrays = state
+    row_node, frontier, num_nodes, leaves, tree_arrays, tally = state
+    if run_tally is not None:
+        run_tally.append(tally)
 
     if cfg.quantized_grad and cfg.quant_renew_leaf:
         tree_arrays = _renew_leaf_stats(

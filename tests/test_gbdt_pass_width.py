@@ -27,7 +27,6 @@ import pytest
 
 from mmlspark_tpu.models.gbdt import growth
 from mmlspark_tpu.models.gbdt.growth import GrowConfig
-from mmlspark_tpu.observability import metrics
 from mmlspark_tpu.ops.histogram import node_histogram, node_histogram_sums
 from mmlspark_tpu.parallel import mesh as meshlib
 from mmlspark_tpu.parallel.compat import shard_map
@@ -99,8 +98,10 @@ def test_a_pass_at_the_live_width_is_the_full_width_pass(live, stats):
     def hist_of(w):
         return node_histogram(binned, pos, base, w, B, scales=scales)
 
-    got = jax.jit(lambda k: growth._hist_at_width(
+    got, which = jax.jit(lambda k: growth._hist_at_width(
         hist_of, W, k, B, stats == "int8"))(jnp.int32(live))
+    assert growth._pass_widths(W, B, stats == "int8")[int(which)] == min(
+        w for w in (4, 8, 16) if w >= live)
     want = hist_of(W)
     assert got.shape == want.shape == (F, 3 * W, B)
     assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
@@ -138,7 +139,7 @@ def test_derived_children_are_the_two_children_pass_s(live, blocks):
     hsum = jnp.zeros((blocks,) * bool(blocks) + (F, 2 * KB + 3, 3, B),
                      jnp.int32).at[..., slots, :, :].set(
         sums(cand, KB).reshape((blocks,) * bool(blocks) + (F, KB, 3, B)))
-    left = jax.jit(lambda k: growth._hist_at_width(
+    left, _ = jax.jit(lambda k: growth._hist_at_width(
         lambda w: sums(lefts, w), KB, k, B, True))(jnp.int32(live))
     got = growth._derive_siblings(left, hsum, slots, do)
     want = sums(both, 2 * KB)
@@ -402,44 +403,67 @@ def test_leaf_batch_one_stages_no_switch(monkeypatch):
 
     shipped = text()
     monkeypatch.setattr(growth, "_hist_at_width",
-                        lambda hist_of, W, *a: hist_of(W))
+                        lambda hist_of, W, *a: (hist_of(W), 0))
     assert text() == shipped
 
 
-def _counted(name, label):
-    reg = metrics.get_registry().snapshot().get(name) or {}
-    return {s["labels"][label]: s["value"] for s in reg.get("series", [])}
+def _run_tally(leaf_batch, stats):
+    """``(widths, runs)`` of one 31-leaf tree on the toy rows: the layout's
+    widths under the root and how often a pass ran at each."""
+    cfg = GrowConfig(num_leaves=31, num_bins=255, min_data_in_leaf=5,
+                     leaf_batch=leaf_batch, quantized_grad=stats == "int8",
+                     quant_renew_leaf=False)
+    binned, grad, hess = _rows()
 
+    def fn(b, g, h, k):
+        got = []
+        tree, _ = growth.grow_tree(b, g, h, jnp.ones(N), jnp.ones(F, bool),
+                                   cfg, None, None, k, run_tally=got)
+        return tree.node_count, got[0]
 
-def _staged():
-    return _counted("gbdt_hist_pass_width_total", "width")
-
-
-def _siblings():
-    return _counted("gbdt_hist_sibling_total", "sibling")
+    nodes, tally = jax.jit(fn)(jnp.asarray(binned), jnp.asarray(grad),
+                               jnp.asarray(hess), jax.random.PRNGKey(0))
+    assert int(nodes) == 61
+    widths = growth.run_tally_layout(cfg)
+    assert widths[0] == 1 and int(tally[1]) == 1            # the root's
+    return widths[1:], [int(r) for r in tally[2:]]
 
 
 @pytest.mark.parametrize("leaf_batch,stats,widths", [
-    (8, "int8", ("4", "8")), (8, "float", ("4", "8", "16")),
-    (1, "int8", ("1",)), (1, "float", ("2",))])
-def test_a_build_counts_each_staged_width_once(leaf_batch, stats, widths):
-    before = _staged()
-    jax.jit(_grow_fn(leaf_batch=leaf_batch,
-                     quantized_grad=stats == "int8")).lower(*_lower_args())
-    after = _staged()
-    assert {w: after[w] - before.get(w, 0) for w in after
-            if after[w] != before.get(w, 0)} == {w: 1 for w in widths}
+    (8, "int8", (4, 8)), (8, "float", (4, 8, 16)),
+    (1, "int8", (1,)), (1, "float", (2,))])
+def test_a_run_counts_each_staged_width_it_ran_at(leaf_batch, stats, widths):
+    """A 31-leaf tree's rounds split 1, 2, 4, 8, 8, 7 leaves at ``leaf_batch``
+    8 and one leaf each at 1: every staged width runs, and the tally's
+    entries are the staged widths (that staged is a superset of run is the
+    lowered text's to hold, above)."""
+    staged, runs = _run_tally(leaf_batch, stats)
+    assert staged == widths
+    assert runs == {(8, "int8"): [3, 3], (8, "float"): [2, 1, 3],
+                    (1, "int8"): [30], (1, "float"): [30]}[leaf_batch, stats]
 
 
 @pytest.mark.parametrize("leaf_batch", [1, 8])
-@pytest.mark.parametrize("stats,sibling", [("int8", "derived"),
-                                           ("float", "summed")])
-def test_a_build_counts_how_a_round_gets_its_siblings(stats, sibling,
-                                                      leaf_batch):
-    """Once a staged round (a tree's rounds are one ``fori_loop`` body)."""
-    before = _siblings()
-    jax.jit(_grow_fn(leaf_batch=leaf_batch,
-                     quantized_grad=stats == "int8")).lower(*_lower_args())
-    after = _siblings()
-    assert {k: after[k] - before.get(k, 0) for k in after
-            if after[k] != before.get(k, 0)} == {sibling: 1}
+@pytest.mark.parametrize("stats,per_split", [("int8", 1), ("float", 2)])
+def test_a_fit_says_how_a_round_gets_its_siblings(stats, per_split,
+                                                  leaf_batch):
+    """On the fit's own span, by the live positions it tells: a round that
+    derives its siblings (int8) holds its splits, one that sums both
+    children (float) holds two a split; a root is one."""
+    from mmlspark_tpu.models.gbdt.booster import train_booster
+    from mmlspark_tpu.observability import spans
+
+    binned, grad, _ = _rows()
+    spans.clear_trace()
+    booster = train_booster(
+        binned.T.astype(np.float32), (grad < 0).astype(np.float32),
+        objective="binary", num_iterations=2, max_bin=B,
+        mesh=meshlib.make_mesh(devices=jax.devices()[:1]),
+        cfg=GrowConfig(num_leaves=15, min_data_in_leaf=5,
+                       leaf_batch=leaf_batch, quant_warmup_iters=0,
+                       quantized_grad=stats == "int8",
+                       quant_renew_leaf=False))
+    fit, = [e["args"] for e in spans.get_trace_events()
+            if e["name"] == "gbdt_fit"]
+    splits = int((~booster.trees.is_leaf).sum())
+    assert fit["live"] == 2 + splits * per_split
