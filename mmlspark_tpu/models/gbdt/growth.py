@@ -163,7 +163,7 @@ def _allreduce(x, axis_name, what: str, per: str = "tree", op=lax.psum,
     device trace tells the wire from the work.
 
     gbdt_allreduce_bytes_total{what, per}: the result's bytes on one shard,
-    counted where the reduction is staged out (as ``_note_route_lookup``
+    counted where the reduction is staged out (as ``_note_route``
     counts), so it tracks program builds and costs nothing on the device.
     ``per`` says how often the built program runs the site: once a ``tree``,
     once a leafwise ``round`` that runs, or once for the depthwise ``level``
@@ -523,7 +523,7 @@ FLOAT_SUM_SITES = {"right_side": "suffix_sum",
 
 def _note_float_sums(site: str) -> None:
     """gbdt_float_sums_total{site, form}: one of ``FLOAT_SUM_SITES``, counted
-    where it is staged out (as :func:`_note_route_lookup` counts), so it
+    where it is staged out (as :func:`_note_route` counts), so it
     tracks program builds. The quantized path counts nothing: its int32 sums
     subtract exactly."""
     try:
@@ -715,13 +715,13 @@ def _best_split(hist, tot_g, tot_h, tot_c, cfg: GrowConfig, feat_mask, allow,
 _ROUTE_SELECT_MAX_WORDS = 256
 
 
-def _note_route_lookup(lookup: str) -> None:
-    """gbdt_route_lookup_total{lookup}: counted where the routing is staged
-    out (the choice follows from the bitset's static width), so it tracks
-    program builds and costs nothing on the device."""
+def _note_route(what: str, how: str) -> None:
+    """gbdt_route_lookup_total{lookup} and gbdt_route_fetch_total{fetch}:
+    counted where the routing is staged out (the choice follows from static
+    shapes), so they track program builds and cost nothing on the device."""
     try:
         from ...observability import metrics as _metrics
-        _metrics.safe_counter("gbdt_route_lookup_total", lookup=lookup).inc()
+        _metrics.safe_counter(f"gbdt_route_{what}_total", **{what: how}).inc()
     except Exception:  # noqa: BLE001 — telemetry must not fail the fit
         pass
 
@@ -734,9 +734,9 @@ def _bitset_words_of_rows(bits_k, rows):
     BW = bits_k.shape[1]
     widx = rows >> 5
     if BW > _ROUTE_SELECT_MAX_WORDS:
-        _note_route_lookup("gather")
+        _note_route("lookup", "gather")
         return jnp.take_along_axis(bits_k, widx, axis=1)
-    _note_route_lookup("select")
+    _note_route("lookup", "select")
     word = jnp.broadcast_to(bits_k[:, :1], rows.shape)
     for j in range(1, BW):
         word = jnp.where(widx == j, bits_k[:, j:j + 1], word)
@@ -745,35 +745,57 @@ def _bitset_words_of_rows(bits_k, rows):
 
 @jax.named_scope("gbdt_route")
 def _route_rows_to_children(binned_t, row_node, slots, do, feats, bins_,
-                            bits_k, lid, is_cat):
-    """Shared [W, n] row-routing for batched growth (leafwise rounds and
-    depthwise levels): rows whose current node is a splitting candidate move
-    to its left/right child slot (``lid``/``lid+1``). The category test is a
-    select over the bitset's words (``_bitset_words_of_rows``), so beyond
-    fetching the W candidate feature rows (``binned_t[feats]``) the routing
-    is elementwise [W, n] + reduce, which XLA fuses with its consumers'
-    reductions into a few passes over the rows.
+                            bits_k, lid, is_cat, sibling_derived=False):
+    """Row routing of batched growth (leafwise rounds and depthwise levels):
+    a row's candidate found once. The ``[W, n]`` work is elementwise (W = 8
+    fills a vector register's sublanes: a walk over ``[1, n]`` vectors fills
+    one of eight and measured 42-63 ms a round at the cells' rows, PERF.md,
+    PR 38) and ends in ONE reduction over ``W``, the row's *code*: ``2w`` if
+    candidate ``w`` (a node slot in ``slots`` with ``do`` set; slots are
+    distinct, ``-1`` marks an inactive one and matches no row) holds the row
+    and the row goes left, ``2w + 1`` if it goes right, ``-1`` if no
+    candidate holds it. A candidate's test is ``row <= bin``, or the
+    bitset's bit through ``_bitset_words_of_rows``, chosen by a [W, F]
+    one-hot test of ``is_cat`` (no gather, however small). One pass over
+    ``[n]`` vectors then reads both results from the code and ``row_node``.
 
-    Returns (new_row_node, move [W, n], goleft_k [W, n]).
+    Returns ``(new_row_node, child_pos)``: rows a candidate holds move to its
+    child slots ``lid[w]`` / ``lid[w] + 1``; ``child_pos`` is the row's
+    position in the round's histogram pass. Both children summed: the code
+    itself, in ``[0, 2W)``, ``2w`` = left child of candidate ``w``. The
+    sibling derived (``sibling_derived``): ``w`` for a row that goes left,
+    and a row that goes right rides the pass at no position (``-1``), like
+    one outside the frontier.
     """
-    pos_oh = row_node[None, :] == slots[:, None]
-    move = pos_oh & do[:, None]
+    W = slots.shape[0]
+    _note_route("fetch", "gather")
     # widen narrow bin storage once into a [W, n] transient (W is small)
     rows = binned_t[feats].astype(jnp.int32)         # [W, n]
-    goleft_k = rows <= bins_[:, None]
+    goleft = rows <= bins_[:, None]
     if is_cat is not None:
         word = _bitset_words_of_rows(bits_k, rows)
         member = ((word >> (rows.astype(jnp.uint32) & 31)) & 1).astype(bool)
-        # a [W, F] one-hot test, not is_cat[feats]: no gather, however small
         cat_k = jnp.any((feats[:, None] == jnp.arange(is_cat.shape[0]))
                         & is_cat[None, :], axis=1)
-        goleft_k = jnp.where(cat_k[:, None], member, goleft_k)
-    in_any = jnp.any(move, axis=0)
-    go_left_row = jnp.any(move & goleft_k, axis=0)
-    lid_row = jnp.sum(jnp.where(move, lid[:, None], 0), axis=0)
-    new_row_node = jnp.where(
-        in_any, jnp.where(go_left_row, lid_row, lid_row + 1), row_node)
-    return new_row_node, move, goleft_k
+        goleft = jnp.where(cat_k[:, None], member, goleft)
+    # a candidate that does not split holds no row
+    holds = row_node[None, :] == jnp.where(do, slots, -1)[:, None]
+    two_w = 2 * jnp.arange(W, dtype=jnp.int32)[:, None]
+    # the one reduction: at most one candidate holds a row, so the sum over
+    # W of "code + 1 where held, else 0" is that candidate's
+    code = jnp.sum(jnp.where(holds, jnp.where(goleft, two_w + 1, two_w + 2),
+                             0), axis=0) - 1
+    # the candidate's child slot by a select over W scalars: ``lid`` follows
+    # ``do``, which is no prefix of a depthwise level's candidates
+    held_by = code >> 1
+    child = jnp.broadcast_to(lid[0], code.shape)
+    for w in range(1, W):
+        child = jnp.where(held_by == w, lid[w], child)
+    new_row_node = jnp.where(code >= 0, child + (code & 1), row_node)
+    if sibling_derived:
+        # w where the code is 2w; -1 where it is odd (2w + 1, or -1)
+        code = (code >> 1) | -(code & 1)
+    return new_row_node, code
 
 
 class Tree(NamedTuple):
@@ -951,23 +973,12 @@ def grow_tree(binned_t: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         bins_ = st["cb"][slots]
         bits_k = st["cbits"][slots]                  # [KB, BW]
 
-        new_row_node, move, goleft_k = _route_rows_to_children(
+        # rows to their children, and each row's position in the round's
+        # pass (both children summed: in [0, 2*KB); the sibling derived: in
+        # [0, KB), the left children's)
+        new_row_node, child_pos = _route_rows_to_children(
             binned_t, st["row_node"], slots, do, feats, bins_, bits_k, lid,
-            is_cat)
-        # a row's position in the round's pass. Both children summed: in
-        # [0, 2*KB), 2i = left child of candidate i. The sibling derived:
-        # in [0, KB), i = left child of candidate i, and a row that goes
-        # right rides the pass at no position, like one outside the frontier
-        with jax.named_scope("gbdt_route"):
-            if derive:
-                cpos = jnp.where(goleft_k, arange_kb[:, None], -1)
-            else:
-                cpos = jnp.where(goleft_k, 2 * arange_kb[:, None],
-                                 2 * arange_kb[:, None] + 1)
-            in_any = jnp.any(move, axis=0)
-            child_pos = jnp.where(
-                in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
-            ).astype(jnp.int32)
+            is_cat, sibling_derived=derive)
 
         # ``do`` is a prefix of the gain-sorted candidates, so every live
         # position is under n_split (2 * n_split with both children summed)
@@ -1231,7 +1242,7 @@ def grow_tree_depthwise(binned_t: jnp.ndarray, grad: jnp.ndarray,
             # update rows: rows in split nodes move to their child slot
             # (keyed on node slot ids — inactive frontier slots are -1 and
             # match no row since row_node >= 0)
-            row_node, _, _ = _route_rows_to_children(
+            row_node, _ = _route_rows_to_children(
                 binned_t, row_node, jnp.where(active, fr, -1), do, feats,
                 bins_, bits_w, lid, is_cat)
 

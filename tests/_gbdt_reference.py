@@ -1,13 +1,15 @@
 """Plain references that the validated-fit tests hold the program to: the
 scorer, the metric and the early-stopping harness as they stood before PR 35
-(per-row gathers; host NumPy in float64; the round staged twice). Not
-collected: no ``test_`` prefix."""
+(per-row gathers; host NumPy in float64; the round staged twice); and the
+``[W, n]`` row routing as it stood before PR 38. Not collected: no ``test_``
+prefix."""
 
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mmlspark_tpu.models.gbdt.growth import bit_test
+from mmlspark_tpu.models.gbdt.growth import (_bitset_words_of_rows,
+                                             bit_test)
 
 
 def walk_by_gathers(tree, binned, depth_cap, is_cat=None):
@@ -84,3 +86,36 @@ def es_scan_two_stage(one_iter, state0, num_iterations, early_stopping_rounds,
     it, _, _, best_it, _, buf, mbuf = lax.while_loop(
         cond, body, (jnp.int32(1), state, best, best_it, rni, buf, mbuf))
     return buf, mbuf, it, best_it
+
+
+def route_rows_wn(binned_t, row_node, slots, do, feats, bins_, bits_k, lid,
+                  is_cat, sibling_derived=False):
+    """The routing as it stood from PR 27 to PR 37: ``[W, n]`` elementwise
+    work and four reductions over ``W`` (``in_any``, ``go_left_row``,
+    ``lid_row``, and the leafwise caller's ``child_pos``), the feature rows
+    fetched by one gather."""
+    pos_oh = row_node[None, :] == slots[:, None]
+    move = pos_oh & do[:, None]
+    rows = binned_t[feats].astype(jnp.int32)         # [W, n]
+    goleft_k = rows <= bins_[:, None]
+    if is_cat is not None:
+        word = _bitset_words_of_rows(bits_k, rows)
+        member = ((word >> (rows.astype(jnp.uint32) & 31)) & 1).astype(bool)
+        cat_k = jnp.any((feats[:, None] == jnp.arange(is_cat.shape[0]))
+                        & is_cat[None, :], axis=1)
+        goleft_k = jnp.where(cat_k[:, None], member, goleft_k)
+    in_any = jnp.any(move, axis=0)
+    go_left_row = jnp.any(move & goleft_k, axis=0)
+    lid_row = jnp.sum(jnp.where(move, lid[:, None], 0), axis=0)
+    new_row_node = jnp.where(
+        in_any, jnp.where(go_left_row, lid_row, lid_row + 1), row_node)
+    arange_w = jnp.arange(slots.shape[0], dtype=jnp.int32)
+    if sibling_derived:
+        cpos = jnp.where(goleft_k, arange_w[:, None], -1)
+    else:
+        cpos = jnp.where(goleft_k, 2 * arange_w[:, None],
+                         2 * arange_w[:, None] + 1)
+    child_pos = jnp.where(
+        in_any, jnp.sum(jnp.where(move, cpos, 0), axis=0), -1
+    ).astype(jnp.int32)
+    return new_row_node, child_pos

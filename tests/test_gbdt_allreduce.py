@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _gbdt_reference import route_rows_wn
 from mmlspark_tpu.models.gbdt import growth
 from mmlspark_tpu.models.gbdt.booster import LightGBMDataset, train_booster
 from mmlspark_tpu.models.gbdt.growth import GrowConfig
@@ -202,15 +203,25 @@ def _one_device_text(policy, debug_info=False):
     return re.sub(r"module @\S+", "module @m", text, count=1)
 
 
-_PR28_LEAFWISE = (
-    "de6db80d6ad95970565e39f339e4460a5b83108f8ce754f66765e430c9e0febe")
+def _ops_outside_routing(jaxpr, found):
+    """Every equation that is not under ``gbdt_route``: its primitive and
+    the shapes it gives, in program order."""
+    for eqn in jaxpr.eqns:
+        if "gbdt_route" in str(eqn.source_info.name_stack):
+            continue
+        found.append((eqn.primitive.name,
+                      tuple(getattr(v.aval, "shape", None)
+                            for v in eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _ops_outside_routing(sub, found)
+    return found
 
 
 @pytest.mark.parametrize("policy,sha", [
     ("leafwise",
-     "c473b45926e6fe74b089db89028227d810ab277ce7ca0460a9f9ecf0d4fed164"),
+     "56eff214af13161833825f290b11734c2de229f294ed186abb77a0d7dae1e89f"),
     ("depthwise",
-     "d88f6387f517ba32c9630a366c6975b10feaf12ad3f4e1bb04f22de0670b94a0"),
+     "a83e9261e89c077a3ead4772f92e4e2932b91d91813f6b1d6d24ccff1ab63892"),
 ])
 def test_one_device_program_is_the_parent_s(policy, sha, monkeypatch):
     """At PR 28's parent (818dfa2) every reduction site read ``if axis_name
@@ -239,7 +250,17 @@ def test_one_device_program_is_the_parent_s(policy, sha, monkeypatch):
     (``growth._sibling_is_derived``), scaled to f32 afterwards. With the
     derivation held off as well as the rule, the text is still the one PRs
     28 to 33 pinned; the depthwise text did not move, and the float text is
-    held in ``tests/test_gbdt_pass_width.py``."""
+    held in ``tests/test_gbdt_pass_width.py``.
+
+    PR 38 changed both texts by design, and re-pinned them: row routing
+    (``growth._route_rows_to_children``) finds a row's candidate by one
+    reduction over ``W`` where it made four, and returns the pass position
+    that the leafwise caller used to reduce itself. The chain of texts back
+    to PR 28's ends here, since the caller's lines moved into the function;
+    what is held in its place: with the ``[W, n]`` formula patched back in
+    (``_gbdt_reference.route_rows_wn``) the program differs only inside
+    ``gbdt_route``, equation for equation, and
+    ``tests/test_route_rows.py`` holds whole fits to the formula's trees."""
     text = _one_device_text(policy)
     assert "gbdt_allreduce/" not in _one_device_text(policy, debug_info=True)
     found = _collectives(jax.make_jaxpr(_grow_fn(
@@ -250,8 +271,13 @@ def test_one_device_program_is_the_parent_s(policy, sha, monkeypatch):
     assert _one_device_text(policy) == text
     if jax.__version__ == _PARENT_JAX:
         assert hashlib.sha256(text.encode()).hexdigest() == sha
-        monkeypatch.setattr(growth, "_pass_widths", lambda W, B_, q: (W,))
-        monkeypatch.setattr(growth, "_sibling_is_derived", lambda q: False)
-        if policy == "leafwise":
-            assert hashlib.sha256(_one_device_text(
-                policy).encode()).hexdigest() == _PR28_LEAFWISE
+
+    def outside():
+        return _ops_outside_routing(jax.make_jaxpr(_grow_fn(
+            dict(growth_policy=policy, quant_renew_leaf=True), None))(
+                *_grow_args(2048)).jaxpr, [])
+
+    shipped = outside()
+    monkeypatch.setattr(growth, "_route_rows_to_children",
+                        jax.named_scope("gbdt_route")(route_rows_wn))
+    assert outside() == shipped and len(shipped) > 100

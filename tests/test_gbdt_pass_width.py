@@ -369,18 +369,25 @@ _PARENT_JAX = "0.9.0"      # the jax the parent's texts were hashed under
 
 
 @pytest.mark.parametrize("cfg,sha", [
-    (dict(), "d454b04d99b93fb038b79762d3dbd882026c8fe2755efdaf9c47095176605077"),
+    (dict(), "1f0da95a76ee0a9f50181b8d65901857ccf2227284a0137127affc3f89262fa8"),
     (dict(leaf_batch=1),
-     "87ea756ea434ce2a5ebe31f99df3ce654fed04dac08664f41d944b727349211e"),
+     "608dc54b76e97eb407e2b29eeb249bf4cdc6319951bc230d918ee9a0a8fb558c"),
     (dict(leaf_batch=5, num_bins=B),
-     "8f5c5803327f1a4f48952cc1ad142ded1c94b8a417632f8b94e63fb00f16c07a"),
+     "8d63264843d80721361572d676c73c73ab2de40ac340d15213145fa315fdd60a"),
 ], ids=["cells", "leaf_batch1", "63bins-leaf_batch5"])
 def test_the_float_program_is_the_parent_s(cfg, sha, monkeypatch):
-    """Float statistics sum both children, as they did: the lowered text of
-    the float build is the text of PR 36's parent (873e312), byte for byte,
-    by its hash under the jax it was lowered with; under any jax it is the
-    text of the build with the derivation held off, and it never reaches
-    ``_derive_siblings``."""
+    """Float statistics sum both children, as they did: under any jax the
+    lowered text of the float build is the text of the build with the
+    derivation held off, and it never reaches ``_derive_siblings``. From PR
+    36 to PR 37 it was also the text of PR 36's parent (873e312), byte for
+    byte, by its hash under the jax it was lowered with.
+
+    PR 38 changed the three texts by design, and re-pinned them: row routing
+    (``growth._route_rows_to_children``) finds a row's candidate by one
+    reduction over ``W`` where it made four, and returns the pass position
+    the leafwise caller used to reduce itself. The hashes are the texts of
+    that program; ``tests/test_route_rows.py`` holds float fits, tree for
+    tree to the bit, to the routing it replaced."""
     def text():
         return re.sub(r"module @\S+", "module @m", jax.jit(_grow_fn(
             quantized_grad=False, **cfg)).lower(*_lower_args()).as_text(),
