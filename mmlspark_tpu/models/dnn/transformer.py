@@ -10,7 +10,9 @@ whole mesh:
   * ``model`` — Megatron-style tensor parallelism (TP): QKV/MLP column-split,
     output projections row-split with one psum per block
   * ``seq``   — sequence/context parallelism (SP): activations sharded over
-    sequence; exact attention via ring ppermute (parallel/ring_attention.py)
+    sequence; exact attention via ring ppermute (parallel/ring_attention.py).
+    On one ``seq`` shard, with a sequence that is a multiple of 128, GPT-2's
+    block runs the splash kernel instead (``_attention_kernel``)
 
 Everything runs inside one ``shard_map``: collectives are explicit
 (psum/pmax/ppermute) and ride ICI. Params live sharded (TP dims) or
@@ -42,6 +44,7 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 from jax.sharding import Mesh
 
 from . import moe
+from ...observability import metrics as _metrics
 from ...parallel import placement
 from ...parallel.placement import pspec as P
 from ...parallel.ring_attention import (ring_attention,
@@ -59,12 +62,15 @@ class TransformerConfig(NamedTuple):
     d_ff: int = 2048
     max_len: int = 2048
     dtype: Any = jnp.bfloat16
-    # sequence-parallel attention strategy over the 'seq' mesh axis:
-    # "ring" (neighbor ppermute, O(S_local) memory, no head constraint),
-    # "ring_zigzag" (ring with the causally load-balanced zig-zag layout —
-    # ~2x causal speedup; feed tokens/targets through zigzag_permute), or
-    # "ulysses" (two all-to-alls reshard heads<->sequence, plain local
-    # attention; needs per-TP-rank heads divisible by the seq shard count)
+    # sequence-parallel attention schedule over a 'seq' mesh axis of more
+    # than one shard: "ring" (neighbor ppermute, O(S_local) memory, no head
+    # constraint), "ring_zigzag" (ring with the causally load-balanced
+    # zig-zag layout — ~2x causal speedup; feed tokens/targets through
+    # zigzag_permute), or "ulysses" (two all-to-alls reshard heads<->
+    # sequence, plain local attention; needs per-TP-rank heads divisible by
+    # the seq shard count). On one shard all three are plain local
+    # attention, and the splash kernel runs whatever this says
+    # (_attention_kernel)
     seq_attention: str = "ring"
     # Gradient rematerialization: recompute each block's activations in the
     # backward pass instead of storing them — activation memory drops from
@@ -148,6 +154,40 @@ def _layer_norm(x, scale, bias):
     return ((x32 - mu) * lax.rsqrt(var + 1e-5) * scale + bias).astype(x.dtype)
 
 
+_SEQ_SCHEDULES = {"ring": ring_attention,
+                  "ring_zigzag": zigzag_ring_attention,
+                  "ulysses": ulysses_attention}
+
+
+def _attention_kernel(cfg: TransformerConfig, S: int) -> str:
+    """What GPT-2's block computes attention with, from the ``seq`` shard
+    count and the local sequence ``S``: the splash kernel on one shard
+    where ``S`` is a multiple of 128, else ``cfg.seq_attention``'s
+    schedule. Counted in ``lm_attention_kernel_total{kernel}``, once a
+    build."""
+    if cfg.seq_attention not in _SEQ_SCHEDULES:
+        # all strategies are exact, so a typo would silently measure the
+        # wrong one — fail loudly instead
+        raise ValueError(
+            f"unknown seq_attention {cfg.seq_attention!r}: "
+            "use 'ring', 'ring_zigzag' or 'ulysses'")
+    one_shard = compat_axis_size("seq") == 1 and S % 128 == 0
+    kernel = "splash" if one_shard else cfg.seq_attention
+    _metrics.safe_counter("lm_attention_kernel_total", kernel=kernel).inc()
+    return kernel
+
+
+def _attend(q, k, v, kernel: str, causal: bool):
+    """Attention of ``[B, H, S, D]`` q, k, v by ``kernel`` (a name
+    :func:`_attention_kernel` gives); q comes scaled where it is
+    ``"splash"``."""
+    if kernel != "splash":
+        return _SEQ_SCHEDULES[kernel](q, k, v, axis_name="seq",
+                                      causal=causal)
+    splash_mha = _splash_kernel(q.shape[2], q.shape[1], 0, causal)
+    return jax.vmap(splash_mha)(q, k, v)
+
+
 def forward_local(params, tokens, cfg: TransformerConfig,
                   causal: bool = True):
     """Local-shard forward inside shard_map; returns vocab-sharded logits.
@@ -160,6 +200,7 @@ def forward_local(params, tokens, cfg: TransformerConfig,
     Hl = H // tp
     B, S = tokens.shape
     dt = cfg.dtype
+    kernel = _attention_kernel(cfg, S)
 
     # embedding: table is E-sharded; gather rows then all-gather E
     emb_local = jnp.take(params["embed"], tokens, axis=0)  # [B, S, E/tp]
@@ -182,29 +223,24 @@ def forward_local(params, tokens, cfg: TransformerConfig,
 
     def block(x, lp):
         h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-        qkv = jnp.einsum("bse,ehk->bshk", h, lp["wqkv"].astype(dt),
-                         preferred_element_type=jnp.float32)  # [B,S,Hl,3*Dh]
-        qkv = qkv.reshape(B, S, Hl, 3, Dh).astype(dt)
-        q = qkv[:, :, :, 0].transpose(0, 2, 1, 3)  # [B, Hl, S, Dh]
-        k = qkv[:, :, :, 1].transpose(0, 2, 1, 3)
-        v = qkv[:, :, :, 2].transpose(0, 2, 1, 3)
-        if cfg.seq_attention == "ulysses":
-            att = ulysses_attention(q, k, v, axis_name="seq", causal=causal)
-        elif cfg.seq_attention == "ring":
-            att = ring_attention(q, k, v, axis_name="seq", causal=causal)
-        elif cfg.seq_attention == "ring_zigzag":
-            att = zigzag_ring_attention(q, k, v, axis_name="seq",
-                                        causal=causal)
-        else:
-            # all strategies are exact, so a typo would silently measure
-            # the wrong one — fail loudly instead
-            raise ValueError(
-                f"unknown seq_attention {cfg.seq_attention!r}: "
-                "use 'ring', 'ring_zigzag' or 'ulysses'")
-        att = att.transpose(0, 2, 1, 3).reshape(B, S, Hl * Dh)
-        out = jnp.einsum("bsk,ke->bse", att, lp["wo"].astype(dt),
-                         preferred_element_type=jnp.float32)
-        out = lax.psum(out, "model").astype(dt)
+        with jax.named_scope("attn"):
+            qkv = jnp.einsum(                          # [B, S, Hl, 3*Dh]
+                "bse,ehk->bshk", h, lp["wqkv"].astype(dt),
+                preferred_element_type=jnp.float32)
+            qkv = qkv.reshape(B, S, Hl, 3, Dh)
+            if kernel == "splash":
+                # the kernel takes q scaled: scaled in f32, rounded once
+                qkv = qkv * jnp.asarray([Dh ** -0.5, 1.0, 1.0],
+                                        jnp.float32)[:, None]
+            qkv = qkv.astype(dt)
+            q = qkv[:, :, :, 0].transpose(0, 2, 1, 3)  # [B, Hl, S, Dh]
+            k = qkv[:, :, :, 1].transpose(0, 2, 1, 3)
+            v = qkv[:, :, :, 2].transpose(0, 2, 1, 3)
+            att = _attend(q, k, v, kernel, causal)
+            att = att.transpose(0, 2, 1, 3).reshape(B, S, Hl * Dh)
+            out = jnp.einsum("bsk,ke->bse", att, lp["wo"].astype(dt),
+                             preferred_element_type=jnp.float32)
+            out = lax.psum(out, "model").astype(dt)
         x = x + out
         h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
         m = jnp.einsum("bse,ef->bsf", h, lp["w1"].astype(dt),
@@ -330,16 +366,27 @@ def _rope(x, theta):
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(S: int, H: int, window: int, interpret: bool):
+def _splash_kernel(S: int, H: int, window: int = 0, causal: bool = True, *,
+                   block: int | None = None, interpret: bool | None = None):
     """Splash attention over ``H`` query heads (K/V heads grouped), causal
-    or, with ``window``, keys ``0 <= i - j < window``; whole blocks of
-    512 outside the mask are skipped."""
+    or, with ``window``, keys ``0 <= i - j < window``, or without
+    ``causal`` every key; whole square blocks outside the mask are
+    skipped. ``block`` defaults to ``min(512, S)`` where that divides
+    ``S``, else 256 or 128. ``interpret`` defaults to ``moe.interpret()``,
+    the one switch of every Pallas kernel the DNN path runs (set it before
+    the first build: the kernel is cached)."""
     if S % 128:
         raise ValueError(f"splash attention needs a sequence that is a "
                          f"multiple of 128, got {S}")
-    b = min(512, S)
-    mask = (splash_mask.LocalMask((S, S), (window - 1, 0), 0) if window
-            else splash_mask.CausalMask((S, S)))
+    b = block or next(b for b in (min(512, S), 256, 128) if S % b == 0)
+    if interpret is None:
+        interpret = moe.interpret()
+    if window:
+        mask = splash_mask.LocalMask((S, S), (window - 1, 0), 0)
+    elif causal:
+        mask = splash_mask.CausalMask((S, S))
+    else:
+        mask = splash_mask.FullMask((S, S))
     with jax.ensure_compile_time_eval():     # concrete tables: it is cached
         return splash.make_splash_mha(
             splash_mask.MultiHeadMask([mask] * H), head_shards=1,
@@ -360,7 +407,7 @@ def _attention(h, lp, cfg: TransformerConfig, window: int, rope: bool):
         q = q * cfg.d_head ** -0.5
         if rope:
             q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-        kernel = _splash_kernel(S, cfg.n_heads, window, moe.interpret())
+        kernel = _splash_kernel(S, cfg.n_heads, window)
         o = jax.vmap(kernel)(q.astype(dt), k.astype(dt), v.astype(dt))
         return jnp.einsum("bhsd,hde->bse", o, lp["wo"].astype(dt),
                           preferred_element_type=jnp.float32)

@@ -8,7 +8,9 @@ tests/test_histogram.py hold the results, chip_smoke.py both on the chip.
 Shapes are the benchmark cells' (BENCHMARK.json: 68,321,280 x 39 uint8 bins,
 W = 1 for a root and 4, 8, 16 for the widths a leafwise round is staged at
 with ``leaf_batch = 8``, 2 beside them) and the widths on both sides of the
-folded layout's gate.
+folded layout's gate. Beside them, GPT-2 medium's attention on one ``seq``
+shard (``models/dnn/transformer._attend``: the splash kernel at 4 x 16
+heads x 1,024 positions x 64, causal), forward and backward.
 
 All in one file, the topology described inside a fixture: one process at a
 time may load the TPU's library, and pytest-xdist gives a file to one worker.
@@ -90,3 +92,22 @@ def test_node_kernel_compiles_for_v5e_at_the_cells_shape(
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "gbdt_node_hist_kernel" in text
     assert block in text, layout
+
+
+def test_gpt2_attention_kernel_compiles_for_v5e(one_chip):
+    """The splash kernel at ``d_head`` 64, whose q/k/v tiles fill half of
+    the MXU's 128 contraction lanes: Mosaic takes the forward and both
+    backward kernels at the cell's shape, and no ``[S, S]`` score array
+    is left outside them."""
+    from mmlspark_tpu.models.dnn import transformer as tf
+    kernel = jax.vmap(tf._splash_kernel(1024, 16, 0, True,
+                                        interpret=False))
+    qkv = jax.ShapeDtypeStruct((4, 16, 1024, 64), jnp.bfloat16,
+                               sharding=one_chip)
+    step = jax.grad(lambda q, k, v: kernel(q, k, v).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2))
+    text = jax.jit(step).lower(qkv, qkv, qkv).compile().as_text()
+    for name in ("splash_mha_fwd_residuals", "splash_mha_dq_no_residuals",
+                 "splash_mha_dkv_no_residuals"):
+        assert name in text, name
+    assert "1024,1024]" not in text

@@ -21,7 +21,12 @@ of this file's seeds (sound / float8 control):
   output and gradient, and that moves every tensor upstream of it; in f32
   the same step reads 3e-7 (logits) and 9e-7 (gradient);
 - dense: logits 0.0056 / 0.065, loss 2e-5 / 5.4e-4, later 3e-5 / 1.3e-3,
-  gradient 0.014 / 0.145, change 0.0078 / 0.058.
+  gradient 0.014 / 0.145, change 0.0078 / 0.058. Since the one-shard case
+  (``dense-1x1x1``, 128 positions) runs the splash kernel, it reads logits
+  0.0058, loss 5.5e-5, later 6.0e-5, gradient 0.011, change 0.011; the
+  whole f32 square, ``ring_attention`` on that shard, read 0.0058, 6.2e-5,
+  2.8e-5, 0.0099 and 0.012 on the same machine. ``dense-2x2x2`` (two
+  ``seq`` shards, the ring) reads 0.0058, 6.2e-5, 7.5e-5, 0.010, 0.010.
 
 Each tolerance sits about twice over the sound reading, and the float8
 control (e4m3 forward, e5m2 gradients) in the program's place fails the
